@@ -119,6 +119,17 @@ _PORT_LINE = re.compile(r"on [\w.\-]+:(\d+)")
 _SERVICE_PORT_LINE = re.compile(r"service (\w+) on [\w.\-]+:(\d+)")
 _WORKER_READY_LINE = "spmd worker: waiting for jobs"
 
+# services/runner.py EXIT_NO_DEVICE: a device-using service found its
+# chip held by another process (model_builder, tsne, pca and every
+# fleet replica each want one). Restarting cannot help.
+EXIT_NO_DEVICE = 69
+
+
+class ChildExited(TimeoutError):
+    """The child exited before announcing its port. A TimeoutError so
+    callers that tolerate a stalled restart keep doing so — only the
+    wait ends at once instead of running out its clock."""
+
 
 class Child:
     """One supervised process with an on-failure restart policy."""
@@ -171,8 +182,18 @@ class Child:
             raise TimeoutError(f"{self.name}: not ready within {timeout}s")
 
     def wait_port(self, timeout: float) -> int:
-        if not self._port_event.wait(timeout):
-            raise TimeoutError(f"{self.name}: no port line within {timeout}s")
+        deadline = time.monotonic() + timeout
+        while not self._port_event.wait(0.2):
+            code = self.poll()
+            if code is not None:
+                raise ChildExited(
+                    f"{self.name}: exited rc={code} before announcing a "
+                    "port (its own log lines above say why)"
+                )
+            if time.monotonic() >= deadline:
+                raise TimeoutError(
+                    f"{self.name}: no port line within {timeout}s"
+                )
         return self.port
 
     def terminate(self) -> None:
@@ -644,7 +665,11 @@ def _supervise(
         children[name] = child
         child.start()
     for name in launch_names:
-        children[name].wait_port(120)
+        try:
+            children[name].wait_port(120)
+        except ChildExited as error:
+            log(f"[stack] bring-up failed: {error}")
+            return 1
     write_ports()
     if fleet_names:
         log(
@@ -668,6 +693,14 @@ def _supervise(
                 child.service_ports.clear()
                 write_ports()
                 continue
+            if code == EXIT_NO_DEVICE:
+                log(
+                    f"[stack] {name} found its device held by another "
+                    "process (one process per chip); not restarting"
+                )
+                stopping.set()
+                exit_code = 1
+                break
             if max_restarts is not None and child.restarts >= max_restarts:
                 log(
                     f"[stack] {name} failed (rc={code}) after "

@@ -139,17 +139,13 @@ def compile_spec(spec: ProgramSpec, source: str = "aot"):
     return compiled
 
 
-def serialize_compiled(compiled) -> Optional[bytes]:
+def serialize_compiled(compiled) -> bytes:
     """One self-contained payload for a ``Compiled`` handle (executable
-    bytes + arg/result pytree defs, pickled together), or None when the
-    installed jax lacks executable serialization — callers fall back to
-    persistent-cache warming, never half-serialize."""
-    try:
-        import pickle
+    bytes + arg/result pytree defs, pickled together)."""
+    import pickle
 
-        from jax.experimental import serialize_executable
-    except ImportError:
-        return None
+    from jax.experimental import serialize_executable
+
     payload, in_tree, out_tree = serialize_executable.serialize(compiled)
     return pickle.dumps((payload, in_tree, out_tree))
 

@@ -1,10 +1,10 @@
 """Device-resident data plane: a rev-keyed cache of prepared arrays.
 
-BENCH_r05 measured the five-classifier kernel suite at ~457k rows/s
-while the product path (store read → preprocess → fits → prediction
-write-back) delivered ~14.6k rows/s: the hardware is ~30× ahead of the
-host path, and most of the gap is the SAME dataset crossing the wire
-and the PCIe/ICI boundary once per job. The reference is worse still —
+The fit kernels on resident data run far ahead of the product path
+(store read → preprocess → fits → prediction write-back), and most of
+the gap is the SAME dataset crossing the wire and the host-to-device
+boundary once per job (the sizes are PERF.md's to state, from the
+ledger). The reference is worse still —
 every service re-reads its collection from Mongo per request
 (reference: microservices/model_builder_image/model_builder.py:96-116,
 pca_image/pca.py:74-88) and never times that tail.
@@ -460,8 +460,8 @@ def content_device_matrix(X: np.ndarray, mesh):
     ``preprocessor_code`` output can ride the cache safely: the second
     build over the same collection hashes the recomputed host matrix,
     hits, and skips the H2D. The digest costs one linear pass over host
-    bytes — microseconds per MB next to a PCIe (let alone tunneled)
-    transfer."""
+    bytes — microseconds per MB next to the host-to-device transfer it
+    saves."""
     from learningorchestra_tpu.ml.base import shard_matrix
     from learningorchestra_tpu.telemetry import span
 

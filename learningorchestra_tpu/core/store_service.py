@@ -833,9 +833,8 @@ class RemoteStore(DocumentStore):
         )
         # LO_STORE_COMPRESS=1: zlib the binary frames both ways (the
         # client advertises on reads, stamps its uploads) — worth it on
-        # narrow links (tunneled chips, cross-zone stores), off by
-        # default where the store is co-located and CPU is the scarcer
-        # resource.
+        # narrow links (cross-zone stores), off by default where the
+        # store is co-located and CPU is the scarcer resource.
         self.compress = (
             _flag_env("LO_STORE_COMPRESS")
             if compress is None

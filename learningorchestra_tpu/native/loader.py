@@ -1,15 +1,19 @@
 """ctypes bindings for the native CSV loader, with lazy build + fallback.
 
 The shared object is compiled on first use with g++ (``-O3 -shared
--fPIC``) into the package directory; hosts without a toolchain (or where
-the build fails) transparently fall back to the Python csv module with
-identical results — the native path is a performance feature, not a
-correctness dependency.
+-fPIC``) into the package directory, named after a digest of
+``csv_loader.cpp`` — so a library left on disk by another version of
+the source is never loaded. Hosts without a toolchain (or where the
+build fails) fall back to the Python csv module with identical results
+— the native path is a performance feature, not a correctness
+dependency — and :func:`parser_status` says which parser this process
+got and why (the runner prints it at boot).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,26 +23,34 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SOURCE = os.path.join(_HERE, "csv_loader.cpp")
-_LIBRARY = os.path.join(_HERE, "_csv_loader.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_build_failed = False
+_status: Optional[str] = None  # set once, by the first _get_lib()
 
 
-def _build() -> Optional[ctypes.CDLL]:
-    global _build_failed
+def _library_path() -> str:
+    with open(_SOURCE, "rb") as handle:
+        digest = hashlib.sha256(handle.read()).hexdigest()[:12]
+    return os.path.join(_HERE, f"_csv_loader.{digest}.so")
+
+
+def _build(library: str) -> None:
+    # compile beside the target, then rename: a concurrent process never
+    # loads a half-written library
+    scratch = f"{library}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SOURCE, "-o", _LIBRARY],
+            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SOURCE, "-o", scratch],
             check=True,
             capture_output=True,
+            text=True,
             timeout=120,
         )
-    except (OSError, subprocess.SubprocessError):
-        _build_failed = True
-        return None
-    return _load(_LIBRARY)
+        os.replace(scratch, library)
+    finally:
+        if os.path.exists(scratch):
+            os.remove(scratch)
 
 
 def _load(path: str) -> ctypes.CDLL:
@@ -75,22 +87,31 @@ def _load(path: str) -> ctypes.CDLL:
 
 
 def _get_lib() -> Optional[ctypes.CDLL]:
-    global _lib, _build_failed
+    global _lib, _status
     with _lock:
-        if _lib is None and not _build_failed:
-            have_library = os.path.exists(_LIBRARY)
-            have_source = os.path.exists(_SOURCE)
-            if have_library and (
-                not have_source
-                or os.path.getmtime(_LIBRARY) >= os.path.getmtime(_SOURCE)
-            ):
-                # Prebuilt .so shipped without source: load it directly.
-                _lib = _load(_LIBRARY)
-            elif have_source:
-                _lib = _build()
-            else:
-                _build_failed = True
+        if _status is None:
+            try:
+                library = _library_path()
+                if os.path.exists(library):
+                    _status = f"native ({os.path.basename(library)}, on disk)"
+                else:
+                    _build(library)
+                    _status = f"native ({os.path.basename(library)}, built now)"
+                _lib = _load(library)
+            except (OSError, subprocess.SubprocessError) as error:
+                detail = getattr(error, "stderr", None) or str(error)
+                _status = (
+                    "python fallback — native build failed: "
+                    f"{type(error).__name__}: {detail.strip()[-300:]}"
+                )
         return _lib
+
+
+def parser_status() -> str:
+    """Which CSV parser this process uses and how it got it (resolving
+    — and if need be building — the native library now)."""
+    _get_lib()
+    return _status
 
 
 def native_available() -> bool:

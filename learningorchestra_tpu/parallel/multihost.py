@@ -45,22 +45,6 @@ _ENV_NUM_PROCESSES = "LO_NUM_PROCESSES"
 _ENV_PROCESS_ID = "LO_PROCESS_ID"
 
 
-def _distributed_client_active() -> bool:
-    """Whether this process already joined a distributed runtime.
-    ``jax.distributed.is_initialized`` only exists from jax 0.5; on
-    older runtimes the client handle lives on the (internal)
-    global_state singleton."""
-    is_initialized = getattr(jax.distributed, "is_initialized", None)
-    if is_initialized is not None:
-        return bool(is_initialized())
-    try:
-        from jax._src import distributed as _distributed
-
-        return _distributed.global_state.client is not None
-    except (ImportError, AttributeError):
-        return False
-
-
 def initialize_from_env() -> bool:
     """Join the multi-host runtime if the environment asks for one.
 
@@ -73,7 +57,7 @@ def initialize_from_env() -> bool:
     the gloo transport, which must be configured before the backend
     initializes — done here, gated to the CPU platform.
     """
-    if _distributed_client_active():
+    if jax.distributed.is_initialized():
         return jax.process_count() > 1
     coordinator = os.environ.get(_ENV_COORDINATOR)
     num_processes = os.environ.get(_ENV_NUM_PROCESSES)

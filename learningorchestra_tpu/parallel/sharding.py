@@ -13,8 +13,8 @@ shapes). Padded counts are additionally BUCKETED to a quarter-octave
 geometric grid (1/1.25/1.5/1.75 × powers of two) so nearby dataset
 sizes share one padded shape: without the grid every distinct row count
 recompiles every estimator program, which at 10M rows made XLA
-compilation — not compute — the wall-clock (SCALE_r04: a 273 s NB fit
-whose kernel runs in 27 ms). Worst-case padding waste is 25% of rows on
+compilation — not compute — the wall-clock (a 273 s NB "fit" around a
+27 ms kernel, round 4). Worst-case padding waste is 25% of rows on
 kernels that are memory-bound anyway; masks keep the math exact.
 ``LO_SHAPE_BUCKETS=0`` restores minimal padding.
 """

@@ -1,8 +1,8 @@
 """Request micro-batching: many waiting clients, one forward dispatch.
 
 A flood of single-row predicts is the worst case for the batch
-pipeline: each would pay its own dispatch (and on a remote-attached
-chip, its own tunnel round-trip). The accelerator does not care whether
+pipeline: each would pay its own dispatch and its own device-to-host
+fetch. The accelerator does not care whether
 a forward pass carries 1 row or 64 — so the executor here collects
 requests that arrive within a short window (``LO_SERVE_BATCH_WINDOW_MS``)
 into ONE padded forward per model and scatters the outputs back to the

@@ -4,8 +4,8 @@
 host→device and live in HBM as float32. ``bf16`` halves both — the
 padded matrix is cast host-side before ``jax.device_put``
 (parallel/sharding.py), so the H2D transfer AND the HBM-resident
-working set drop 2×, which on a tunneled or PCIe-attached chip is most
-of a cold build's boundary cost. Parameters, reductions, and metrics
+working set drop 2×; the transfer is most of a cold build's boundary
+cost. Parameters, reductions, and metrics
 stay float32 (jnp type promotion lifts ``bf16 @ f32`` matmuls to f32
 accumulation), so fits remain numerically anchored; the policy trades
 feature-matrix mantissa bits for bandwidth, the same trade serving

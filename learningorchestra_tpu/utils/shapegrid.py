@@ -6,8 +6,8 @@ compiling one program per exact size:
 
 - ``parallel/sharding.py`` buckets dataset ROW counts before aligning
   them to the mesh's data axis (without it, every distinct row count
-  recompiled every estimator — SCALE_r04's 273 s NB "fit" whose kernel
-  runs in 27 ms).
+  recompiled every estimator — round 4's 273 s NB "fit" around a 27 ms
+  kernel).
 - ``serve/batcher.py`` pads micro-batched predict requests to a fixed
   ``LO_SERVE_MAX_BATCH`` floor so all small traffic shares ONE compiled
   forward per model.

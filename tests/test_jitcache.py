@@ -9,7 +9,13 @@ coverage — the listener callbacks, the rounding contract of
 
 from __future__ import annotations
 
+import os
+import subprocess
+import sys
+
 import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 from learningorchestra_tpu.utils import jitcache
 
@@ -102,3 +108,67 @@ class TestListenerRegistration:
         jitcache._register_listeners()
         jitcache._register_listeners()
         assert calls == {"event": 1, "duration": 1}
+
+
+class TestCachePlacement:
+    """The directory is placed from outside (JAX's own variable) or is
+    one fixed in-checkout path — never cwd- or data-dir-relative."""
+
+    @pytest.fixture()
+    def updates(self, monkeypatch):
+        import jax
+
+        recorded = []
+        monkeypatch.setattr(
+            jax.config, "update", lambda key, value: recorded.append((key, value))
+        )
+        monkeypatch.setattr(jitcache, "_ACTIVE_DIR", None)
+        monkeypatch.setattr(jitcache, "_LISTENERS_ON", True)
+        return recorded
+
+    def test_env_placed_cache_configures_no_directory(
+        self, updates, monkeypatch, tmp_path
+    ):
+        import jax
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert (
+            jitcache.enable_compile_cache()
+            == jax.config.jax_compilation_cache_dir
+        )
+        assert "jax_compilation_cache_dir" not in [key for key, _ in updates]
+        # the key-portability switch still applies
+        assert ("jax_persistent_cache_enable_xla_caches", "") in updates
+
+    def test_default_is_fixed_in_checkout_path_from_any_cwd(
+        self, updates, monkeypatch, tmp_path
+    ):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("LO_DATA_DIR", str(tmp_path / "data"))
+        monkeypatch.chdir(tmp_path)
+        expected = os.path.join(REPO, ".jit_cache")
+        assert jitcache.enable_compile_cache() == expected
+        assert ("jax_compilation_cache_dir", expected) in updates
+
+    def test_jax_itself_honours_the_variable(self, tmp_path):
+        proc = subprocess.run(
+            [
+                sys.executable,
+                "-c",
+                "from learningorchestra_tpu.utils.jitcache import "
+                "enable_compile_cache as e; import jax; "
+                "print(e()); print(jax.config.jax_compilation_cache_dir)",
+            ],
+            env=dict(
+                os.environ,
+                PYTHONPATH=REPO,
+                JAX_PLATFORMS="cpu",
+                JAX_COMPILATION_CACHE_DIR=str(tmp_path / "placed"),
+            ),
+            cwd=tmp_path,
+            capture_output=True,
+            text=True,
+            timeout=120,
+        )
+        assert proc.returncode == 0, proc.stderr[-800:]
+        assert proc.stdout.split() == [str(tmp_path / "placed")] * 2

@@ -4,6 +4,7 @@ byte-flow attribution, the sampling profiler under concurrency, the
 bench --compare regression gate."""
 
 import json
+import os
 import threading
 import time
 
@@ -487,7 +488,12 @@ class TestBenchCompare:
         assert bench.compare_benchmarks(prev, cur)["regressions"]
 
     def test_loads_archived_driver_capture(self):
-        record = bench.load_bench_record("BENCH_r05.json")
+        # the driver's {"tail": <stdout>} shape, synthetic values
+        record = bench.load_bench_record(
+            os.path.join(
+                os.path.dirname(__file__), "data", "bench_driver_capture.json"
+            )
+        )
         assert record["metric"] == "model_builder_5clf_rows_per_sec"
         flat = bench.flatten_metrics(record)
         assert "value" in flat

@@ -266,6 +266,50 @@ class TestLogisticResumeBitIdentity:
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+class TestDonatedSegmentPrograms:
+    """The chip runs the DONATED segment programs (selected by backend,
+    ml/logistic.py, ml/trees.py); tier-1 runs the undonated ones. The
+    CPU backend implements donation too, so force the chip's programs
+    here: a progress sink reads (params, opt_state) / margins right
+    after each donated call, and a read of a donated input would raise
+    "Array has been deleted"."""
+
+    def test_donated_fits_with_a_sink_match_the_undonated(
+        self, tmp_path, monkeypatch
+    ):
+        import jax
+
+        from learningorchestra_tpu.ml import logistic, trees
+
+        rng = np.random.default_rng(5)
+        X = rng.random((256, 6))
+        y = (X[:, 0] + X[:, 1] > 1.0).astype(np.int64)
+        makers = {
+            "lr": lambda: logistic.LogisticRegression(max_iter=50, tol=1e-12),
+            "gb": lambda: trees.GBTClassifier(rounds=4, max_depth=3),
+        }
+        control = {name: make().fit(X, y) for name, make in makers.items()}
+
+        monkeypatch.setattr(
+            logistic, "_fit_segment_runner", logistic._donated_fit_segment
+        )
+        monkeypatch.setattr(
+            trees, "_gbt_rounds_runner", trees._donated_gbt_rounds
+        )
+        for name, make in makers.items():
+            sink = ProgressSink(str(tmp_path / f"{name}.progress"), dict(META))
+            with bind_sink(sink):
+                donated = make().fit(X, y)
+            for got, want in zip(
+                jax.tree.leaves(vars(donated)),
+                jax.tree.leaves(vars(control[name])),
+            ):
+                if isinstance(want, jax.Array):
+                    np.testing.assert_array_equal(
+                        np.asarray(got), np.asarray(want)
+                    )
+
+
 class TestJournalProgress:
     def test_progress_folds_without_touching_state(self, store):
         journal = JobJournal(store)

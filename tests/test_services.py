@@ -571,3 +571,55 @@ class TestAsyncModelBuild:
             _time.sleep(0.2)
         assert record["state"] == "failed"
         assert record["error"]
+
+
+def test_runner_entry_point_boots_in_default_topology(tmp_path):
+    """``python -m learningorchestra_tpu.services.runner`` with no
+    ``LO_STORE_URL`` — the documented Quick start. Every other test
+    enters through ``start_all`` or the supervisor (which always sets
+    the URL), which is how a boot banner that crashed on the unset
+    variable shipped unnoticed."""
+    import os
+    import subprocess
+    import sys
+    import threading
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items() if not k.startswith("LO_")}
+    env.update(
+        PYTHONPATH=repo,
+        PYTHONUNBUFFERED="1",
+        JAX_PLATFORMS="cpu",
+        JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jit"),
+        LO_EPHEMERAL="1",  # the suite cannot assume 5000-5006 are free
+    )
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "learningorchestra_tpu.services.runner"],
+        cwd=tmp_path,
+        env=env,
+        stdout=subprocess.PIPE,
+        stderr=subprocess.STDOUT,
+        text=True,
+    )
+    lines: list = []
+    serving = threading.Event()
+
+    def pump():
+        for line in proc.stdout:
+            lines.append(line)
+            if "serving all services" in line:
+                serving.set()
+
+    thread = threading.Thread(target=pump, daemon=True)
+    thread.start()
+    try:
+        assert serving.wait(90), "".join(lines)[-2000:]
+    finally:
+        proc.kill()
+        proc.wait(timeout=10)
+        thread.join(timeout=10)
+    boot = "".join(lines)
+    # what JAX brought up, not just what the environment asked for
+    assert 'device: platform=cpu kind="cpu"' in boot
+    assert f"compile cache: dir={tmp_path / 'jit'}" in boot
+    assert "csv parser: " in boot

@@ -250,3 +250,31 @@ def test_stack_multihost_build_and_worker_death(tmp_path):
             os.killpg(supervisor.pid, signal.SIGKILL)
         except (ProcessLookupError, PermissionError):
             pass
+
+
+def test_child_that_lost_its_device_ends_the_wait_at_once():
+    """A second chip owner exits at boot with the runner's
+    EXIT_NO_DEVICE; the supervisor must say so immediately instead of
+    running out a 120 s port wait (and must not restart it)."""
+    import importlib.util
+
+    from learningorchestra_tpu.services import runner
+
+    spec = importlib.util.spec_from_file_location(
+        "lo_stack", os.path.join(_REPO_ROOT, "deploy", "stack.py")
+    )
+    stack = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(stack)
+    assert stack.EXIT_NO_DEVICE == runner.EXIT_NO_DEVICE
+
+    child = stack.Child(
+        "tsne",
+        [sys.executable, "-c", f"raise SystemExit({runner.EXIT_NO_DEVICE})"],
+        dict(os.environ),
+        lambda line: None,
+    )
+    child.start()
+    start = time.monotonic()
+    with pytest.raises(stack.ChildExited, match="exited rc=69"):
+        child.wait_port(60)
+    assert time.monotonic() - start < 30
