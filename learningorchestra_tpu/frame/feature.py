@@ -13,6 +13,7 @@ import numpy as np
 
 from learningorchestra_tpu.frame.dataframe import DataFrame
 from learningorchestra_tpu.frame.expressions import _is_null_array
+from learningorchestra_tpu.telemetry import tracing as _tracing
 
 ERROR = "error"
 SKIP = "skip"
@@ -105,6 +106,17 @@ class VectorAssembler:
         return self
 
     def transform(self, df: DataFrame) -> DataFrame:
+        with _tracing.span("frame:assemble"):
+            out = self._transform(df)
+            matrix = out._column(self.outputCol)
+            _tracing.annotate(
+                rows=matrix.shape[0],
+                features=matrix.shape[1],
+                bytes=int(matrix.nbytes),
+            )
+            return out
+
+    def _transform(self, df: DataFrame) -> DataFrame:
         stacked = []
         for name in self.inputCols:
             column = df._column(name)

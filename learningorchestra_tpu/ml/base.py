@@ -23,6 +23,7 @@ from jax.sharding import Mesh
 
 from learningorchestra_tpu.parallel.mesh import default_mesh
 from learningorchestra_tpu.parallel.sharding import shard_rows
+from learningorchestra_tpu.telemetry import tracing as _tracing
 
 # The model-builder request contract (reference:
 # microservices/model_builder_image/model_builder.py:151-157,287-291).
@@ -154,14 +155,18 @@ def prepare_xy(
         if X.mesh is mesh:
             y_dev = None
             if y is not None:
-                y_dev, _ = shard_rows(np.asarray(y), mesh, dtype=np.int32)
+                with _tracing.span("h2d:train", rows=len(y)):
+                    y_dev, _ = shard_rows(np.asarray(y), mesh, dtype=np.int32)
             return X.data, y_dev, X.mask
         # mesh mismatch: fall back through host memory
         X = np.asarray(jax.device_get(X.data))[: X.rows]
-    X_dev, mask = shard_rows(np.asarray(X), mesh, dtype=np.float32)
-    y_dev = None
-    if y is not None:
-        y_dev, _ = shard_rows(np.asarray(y), mesh, dtype=np.int32)
+    # one span where a fit's own copy of the matrix and labels crosses
+    # to the device: shard_rows' account_h2d stamps the bytes on it
+    with _tracing.span("h2d:train", rows=len(X)):
+        X_dev, mask = shard_rows(np.asarray(X), mesh, dtype=np.float32)
+        y_dev = None
+        if y is not None:
+            y_dev, _ = shard_rows(np.asarray(y), mesh, dtype=np.int32)
     return X_dev, y_dev, mask
 
 
@@ -200,14 +205,19 @@ class FittedModel:
         fetch is a synchronisation, so every entry point funnels
         through here. Labels are rebuilt host-side when they are argmax(probs)
         (``labels_from_probs``), so the label buffer never travels.
-        Multi-host arrays gather via ``fetch``. The blocking transfer is
-        a ``d2h`` span in the active trace (a no-op outside one), so the
-        device→host tail shows up in ``/jobs/<name>/trace`` next to the
-        ``h2d`` spans the data plane emits."""
+        Multi-host arrays gather via ``fetch``. In the active trace (a
+        no-op outside one) the wait for the forward is an
+        ``eval:device_wait`` span and the blocking transfer after it a
+        ``d2h`` span, so the device→host tail shows up in
+        ``/jobs/<name>/trace`` next to the ``h2d`` spans the data plane
+        emits."""
         from learningorchestra_tpu.telemetry import profile as _profile
-        from learningorchestra_tpu.telemetry import span as _span
 
-        with _span("d2h:predictions", rows=n):
+        # the forward and whatever is queued ahead of it on the device
+        # finish here, so that the d2h span below times the copy alone
+        wanted = (probs,) if self.labels_from_probs else (labels, probs)
+        _tracing.device_wait("eval:device_wait", wanted + tuple(scalars))
+        with _tracing.span("d2h:predictions", rows=n):
             if jax.process_count() > 1:
                 from learningorchestra_tpu.parallel.multihost import fetch
 
@@ -234,7 +244,8 @@ class FittedModel:
             )
 
     def _eval(self, X) -> tuple[np.ndarray, np.ndarray]:
-        labels, probs, _ = self._device_eval(X)
+        with _tracing.span("eval:enqueue"):
+            labels, probs, _ = self._device_eval(X)
         labels_np, probs_np, _ = self._transfer(labels, probs, len(X))
         return labels_np, probs_np
 
@@ -286,13 +297,16 @@ class FittedModel:
         the test frame, reference model_builder.py:205-224 runs its two
         evaluators AND collect() over that same frame) the forward pass
         itself runs once."""
-        accuracy, weighted_f1, labels_e, probs_e = self._device_metrics(
-            X_eval, y_eval
-        )
-        if X_test is X_eval:
-            labels_t, probs_t = labels_e, probs_e
-        else:
-            labels_t, probs_t, _ = self._device_eval(X_test)
+        # eval:enqueue: the forward and the metrics handed to the device;
+        # blocks while the device's queue is full (fit:enqueue, ml/trees.py)
+        with _tracing.span("eval:enqueue"):
+            accuracy, weighted_f1, labels_e, probs_e = self._device_metrics(
+                X_eval, y_eval
+            )
+            if X_test is X_eval:
+                labels_t, probs_t = labels_e, probs_e
+            else:
+                labels_t, probs_t, _ = self._device_eval(X_test)
         labels_np, probs_np, (accuracy, weighted_f1) = self._transfer(
             labels_t, probs_t, len(X_test), (accuracy, weighted_f1)
         )

@@ -49,6 +49,7 @@ def apply_bins(X: jax.Array, thresholds: jax.Array) -> jax.Array:
     def one_feature(column, feature_thresholds):
         return jnp.searchsorted(feature_thresholds, column, side="left")
 
-    bins = jax.vmap(one_feature, in_axes=(1, 0), out_axes=1)(X, thresholds)
+    with jax.named_scope("lo.bin"):
+        bins = jax.vmap(one_feature, in_axes=(1, 0), out_axes=1)(X, thresholds)
     max_bins = thresholds.shape[1] + 1
     return bins.astype(jnp.int8 if max_bins <= 127 else jnp.int32)

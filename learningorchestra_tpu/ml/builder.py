@@ -268,10 +268,9 @@ def train_one(
             # without this the device time lands on whichever later
             # call blocks first, and "evaluate"/"predict" report the
             # fit's tail (VERDICT r4 weak #5 — the phase numbers must
-            # mean something)
-            import jax
-
-            jax.block_until_ready(model.device_state())
+            # mean something). Its own span, so the trace tells the
+            # fit's host work from its wait for the device
+            _tracing.device_wait("fit:device_wait", model.device_state())
     metadata["fit_time"] = timer.timings["fit"]
     check_cancelled()  # phase boundary: fit done, before checkpoint/eval
 
@@ -566,12 +565,7 @@ def build_model(
     # past ~1M rows per classifier that can exceed one chip's HBM (the
     # fits are device-queue-serialized anyway, so capping costs little
     # wall-clock; the 10M-row scale proof runs with LO_BUILD_WORKERS=1).
-    # span(devices): the first jax.process_count() call of a process
-    # initializes the device backend — ~100 ms on CPU, whole seconds on
-    # a cold TPU runtime — a real, otherwise-invisible chunk of the
-    # first build's wall-clock that belongs in the trace.
-    with _tracing.span("devices"):
-        multi_process = jax.process_count() > 1
+    multi_process = jax.process_count() > 1
     if multi_process:
         max_workers = 1
     else:
@@ -628,13 +622,17 @@ def build_model(
         # restart, never a silently-wrong model. Content fingerprints,
         # not collection revs: revs reseed per boot, and the restarted
         # process is the one that needs the artifact to validate.
-        sink_meta = {
-            "training_fp": _progress.collection_fingerprint(
+        with _tracing.span(
+            "resume:fingerprint",
+            rows=training_df.count() + testing_df.count(),
+        ):
+            training_fp = _progress.collection_fingerprint(
                 store, training_filename
-            ),
-            "test_fp": _progress.collection_fingerprint(
-                store, test_filename
-            ),
+            )
+            test_fp = _progress.collection_fingerprint(store, test_filename)
+        sink_meta = {
+            "training_fp": training_fp,
+            "test_fp": test_fp,
             "dtype_policy": dtype_policy(),
             "mesh": _mesh_key(mesh),
         }

@@ -30,6 +30,7 @@ from learningorchestra_tpu.ml.base import (
     prepare_xy,
     resolve_mesh,
 )
+from learningorchestra_tpu.telemetry import tracing as _tracing
 
 
 def _loss_fn(params, X, y, mask, l2):
@@ -326,7 +327,10 @@ def _fit(params, X, y, mask, max_iter: int, l2, tol: float = _LR_TOL):
         capped = largest_divisor(max_iter, min(iters, _LR_CHECK_ITERS))
         if capped >= min(iters, 5):
             iters = capped
-    opt_state = _lbfgs_state(params)
+    # fit:enqueue (see ml/trees.py) here and after the loop: what this
+    # fit hands the device outside its segments
+    with _tracing.span("fit:enqueue"):
+        opt_state = _lbfgs_state(params)
     losses = []
     # Trailing pre-step losses across segment boundaries: convergence
     # requires EVERY delta in this window to be small, not just the
@@ -378,15 +382,20 @@ def _fit(params, X, y, mask, max_iter: int, l2, tol: float = _LR_TOL):
         # more segment here would break bit-identity
         if tol > 0 and _plateaued(history, tol, window):
             break
-        params, opt_state, segment_losses = segment(
-            params, opt_state, X, y, mask, iters, l2
-        )
-        losses.append(segment_losses)
-        if tol > 0:
-            # One host transfer either way: the losses come back as one
-            # array.
-            history.extend(float(v) for v in np.asarray(segment_losses))
-            del history[:-window]
+        # dispatch to the host sync that fetches the losses (with tol
+        # off nothing syncs, and the span holds the dispatch alone)
+        with _tracing.span("fit:segment", iters=iters):
+            params, opt_state, segment_losses = segment(
+                params, opt_state, X, y, mask, iters, l2
+            )
+            losses.append(segment_losses)
+            if tol > 0:
+                # One host transfer either way: the losses come back as
+                # one array.
+                history.extend(float(v) for v in np.asarray(segment_losses))
+                del history[:-window]
+        # on the span round the fit (the builder's phase:fit)
+        _tracing.add_attr("lbfgs_iterations", iters)
         if sink is not None:
             sink.save(
                 "logistic",
@@ -403,9 +412,9 @@ def _fit(params, X, y, mask, max_iter: int, l2, tol: float = _LR_TOL):
                     "history": list(history),
                 },
             )
-    return params, (
-        jnp.concatenate(losses) if len(losses) > 1 else losses[0]
-    )
+    with _tracing.span("fit:enqueue"):
+        joined = jnp.concatenate(losses) if len(losses) > 1 else losses[0]
+    return params, joined
 
 
 @jax.jit
@@ -474,8 +483,10 @@ class LogisticRegression:
         num_classes = infer_num_classes(y)
         # Standardize for conditioning (MLlib standardizes internally
         # too); the scaler is part of the fitted model.
-        mean, scale = scaler_stats(X)
-        X_std = (np.asarray(X) - mean) / scale
+        rows, features = np.shape(X)
+        with _tracing.span("fit:standardize", rows=rows, features=features):
+            mean, scale = scaler_stats(X)
+            X_std = (np.asarray(X) - mean) / scale
         X_dev, y_dev, mask = prepare_xy(X_std, y, self.mesh)
         return self._fit_prepared(
             X_dev,

@@ -29,6 +29,7 @@ from learningorchestra_tpu.ml.base import (
     prepare_xy,
     resolve_mesh,
 )
+from learningorchestra_tpu.telemetry import tracing as _tracing
 
 
 @partial(jax.jit, static_argnames=("num_classes",))
@@ -74,11 +75,12 @@ class NaiveBayes:
             )
         num_classes = infer_num_classes(y)
         X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
-        theta, prior = _fit(
-            X_dev,
-            y_dev,
-            mask.astype(jnp.float32),
-            num_classes=num_classes,
-            smoothing=jnp.float32(self.smoothing),
-        )
-        return NaiveBayesModel(theta, prior, self.mesh)
+        with _tracing.span("fit:enqueue"):  # see ml/trees.py
+            theta, prior = _fit(
+                X_dev,
+                y_dev,
+                mask.astype(jnp.float32),
+                num_classes=num_classes,
+                smoothing=jnp.float32(self.smoothing),
+            )
+            return NaiveBayesModel(theta, prior, self.mesh)

@@ -48,6 +48,7 @@ from learningorchestra_tpu.ml.base import (
 )
 from learningorchestra_tpu.ml.binning import MAX_BINS, apply_bins, make_thresholds
 from learningorchestra_tpu.parallel.mesh import MODEL_AXIS, model_size
+from learningorchestra_tpu.telemetry import tracing as _tracing
 
 MAX_DEPTH = 5          # MLlib default maxDepth
 NUM_TREES = 20         # MLlib default numTrees (RF)
@@ -257,16 +258,23 @@ def _grow(bins, channels, gain_fn, max_depth, max_bins, subset_key, subset_k):
     node = jnp.zeros(n_rows, jnp.int32)
     features_heap = []
     bins_heap = []
+    # the scopes name each stage's XLA ops (lo.leaf: the callers' leaf
+    # sums), so a profiler capture says which stage an op belongs to
     for level in range(max_depth):
-        hist = _level_histograms(bins, node, channels, 2**level, max_bins)
-        gain = gain_fn(hist)
-        level_key = (
-            jax.random.fold_in(subset_key, level) if subset_key is not None else None
-        )
-        feature, bin_index = _select_splits(gain, level_key, subset_k)
+        with jax.named_scope("lo.hist"):
+            hist = _level_histograms(bins, node, channels, 2**level, max_bins)
+        with jax.named_scope("lo.split"):
+            gain = gain_fn(hist)
+            level_key = (
+                jax.random.fold_in(subset_key, level)
+                if subset_key is not None
+                else None
+            )
+            feature, bin_index = _select_splits(gain, level_key, subset_k)
         features_heap.append(feature)
         bins_heap.append(bin_index)
-        node = _route(bins, node, feature, bin_index)
+        with jax.named_scope("lo.route"):
+            node = _route(bins, node, feature, bin_index)
     return (
         jnp.concatenate(features_heap),
         jnp.concatenate(bins_heap),
@@ -281,7 +289,8 @@ def _fit_classification_tree(
         bins, one_hot, _gini_gain, max_depth, max_bins, subset_key, subset_k
     )
     num_classes = one_hot.shape[1]
-    leaf_counts = _leaf_sums(leaf_of_row, one_hot, 2**max_depth)
+    with jax.named_scope("lo.leaf"):
+        leaf_counts = _leaf_sums(leaf_of_row, one_hot, 2**max_depth)
     leaf_probs = leaf_counts / jnp.maximum(leaf_counts.sum(1, keepdims=True), EPS)
     return features_heap, bins_heap, leaf_probs
 
@@ -291,7 +300,8 @@ def _fit_newton_tree(bins, g, h, max_depth, max_bins, lam=1.0):
     features_heap, bins_heap, leaf_of_row = _grow(
         bins, channels, _newton_gain, max_depth, max_bins, None, None
     )
-    sums = _leaf_sums(leaf_of_row, channels, 2**max_depth)
+    with jax.named_scope("lo.leaf"):
+        sums = _leaf_sums(leaf_of_row, channels, 2**max_depth)
     leaf_values = -sums[:, 0] / (sums[:, 1] + lam)
     return features_heap, bins_heap, leaf_values, leaf_of_row
 
@@ -666,6 +676,24 @@ def _gbt_forward(X, f0, features_heap, thresholds_heap, leaf_values, step, max_d
     return jnp.stack([1 - p, p], axis=1)
 
 
+# ``fit:enqueue`` in the three fits below: the host handing the fit's
+# programs to the device. Milliseconds when the device's queue has room;
+# the runtime keeps at most 32 programs in flight, and the 33rd dispatch
+# (every eager slice, gather and cast is one) blocks its thread until
+# the program at the head finishes, as does a readback of a fresh value.
+# With ``fit:device_wait`` it is the fit waiting its turn on the device.
+
+
+def _traced_thresholds(X, max_bins: int) -> np.ndarray:
+    """``make_thresholds`` under a ``fit:thresholds`` span: the host
+    quantile pass of a tree fit, told apart from its device time."""
+    rows, features = np.shape(X)
+    with _tracing.span(
+        "fit:thresholds", rows=rows, features=features, bins=max_bins
+    ):
+        return make_thresholds(X, max_bins)
+
+
 class DecisionTreeClassifier:
     def __init__(
         self,
@@ -679,27 +707,28 @@ class DecisionTreeClassifier:
 
     def fit(self, X: np.ndarray, y: np.ndarray) -> _TreeEnsembleModel:
         num_classes = infer_num_classes(y)
-        thresholds = make_thresholds(X, self.max_bins)
+        thresholds = _traced_thresholds(X, self.max_bins)
         X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
-        bins = apply_bins(X_dev, jnp.asarray(thresholds, jnp.float32))
-        features_heap, bins_heap, leaf_probs = _dt_fit(
-            bins,
-            y_dev,
-            mask.astype(jnp.float32),
-            num_classes,
-            self.max_depth,
-            self.max_bins,
-        )
-        thresholds_heap = _heap_thresholds(
-            features_heap, bins_heap, jnp.asarray(thresholds, jnp.float32)
-        )
-        return _TreeEnsembleModel(
-            features_heap[None],
-            thresholds_heap[None],
-            leaf_probs[None],
-            self.mesh,
-            self.max_depth,
-        )
+        with _tracing.span("fit:enqueue"):
+            bins = apply_bins(X_dev, jnp.asarray(thresholds, jnp.float32))
+            features_heap, bins_heap, leaf_probs = _dt_fit(
+                bins,
+                y_dev,
+                mask.astype(jnp.float32),
+                num_classes,
+                self.max_depth,
+                self.max_bins,
+            )
+            thresholds_heap = _heap_thresholds(
+                features_heap, bins_heap, jnp.asarray(thresholds, jnp.float32)
+            )
+            return _TreeEnsembleModel(
+                features_heap[None],
+                thresholds_heap[None],
+                leaf_probs[None],
+                self.mesh,
+                self.max_depth,
+            )
 
 
 class RandomForestClassifier:
@@ -721,27 +750,32 @@ class RandomForestClassifier:
         num_classes = infer_num_classes(y)
         num_features = np.asarray(X).shape[1]
         subset_k = max(1, int(np.ceil(np.sqrt(num_features))))
-        thresholds = make_thresholds(X, self.max_bins)
+        thresholds = _traced_thresholds(X, self.max_bins)
         X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
-        bins = apply_bins(X_dev, jnp.asarray(thresholds, jnp.float32))
-        features_heap, bins_heap, leaf_probs = _rf_fit(
-            bins,
-            y_dev,
-            mask.astype(jnp.float32),
-            jax.random.key(self.seed),
-            num_classes,
-            self.max_depth,
-            self.max_bins,
-            self.num_trees,
-            subset_k,
-            mesh=self.mesh,
-        )
-        thresholds_heap = _heap_thresholds(
-            features_heap, bins_heap, jnp.asarray(thresholds, jnp.float32)
-        )
-        return _TreeEnsembleModel(
-            features_heap, thresholds_heap, leaf_probs, self.mesh, self.max_depth
-        )
+        with _tracing.span("fit:enqueue"):
+            bins = apply_bins(X_dev, jnp.asarray(thresholds, jnp.float32))
+            features_heap, bins_heap, leaf_probs = _rf_fit(
+                bins,
+                y_dev,
+                mask.astype(jnp.float32),
+                jax.random.key(self.seed),
+                num_classes,
+                self.max_depth,
+                self.max_bins,
+                self.num_trees,
+                subset_k,
+                mesh=self.mesh,
+            )
+            thresholds_heap = _heap_thresholds(
+                features_heap, bins_heap, jnp.asarray(thresholds, jnp.float32)
+            )
+            return _TreeEnsembleModel(
+                features_heap,
+                thresholds_heap,
+                leaf_probs,
+                self.mesh,
+                self.max_depth,
+            )
 
 
 class GBTModel(FittedModel):
@@ -788,27 +822,28 @@ class GBTClassifier:
     def fit(self, X: np.ndarray, y: np.ndarray) -> GBTModel:
         if infer_num_classes(y) > 2:
             raise ValueError("GBTClassifier supports binary labels only (MLlib contract)")
-        thresholds = make_thresholds(X, self.max_bins)
+        thresholds = _traced_thresholds(X, self.max_bins)
         X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
-        bins = apply_bins(X_dev, jnp.asarray(thresholds, jnp.float32))
-        f0, features_heap, bins_heap, leaf_values = _gbt_fit(
-            bins,
-            y_dev,
-            mask.astype(jnp.float32),
-            self.max_depth,
-            self.max_bins,
-            self.rounds,
-            jnp.float32(self.step),
-        )
-        thresholds_heap = _heap_thresholds(
-            features_heap, bins_heap, jnp.asarray(thresholds, jnp.float32)
-        )
-        return GBTModel(
-            f0,
-            features_heap,
-            thresholds_heap,
-            leaf_values,
-            self.step,
-            self.mesh,
-            self.max_depth,
-        )
+        with _tracing.span("fit:enqueue"):
+            bins = apply_bins(X_dev, jnp.asarray(thresholds, jnp.float32))
+            f0, features_heap, bins_heap, leaf_values = _gbt_fit(
+                bins,
+                y_dev,
+                mask.astype(jnp.float32),
+                self.max_depth,
+                self.max_bins,
+                self.rounds,
+                jnp.float32(self.step),
+            )
+            thresholds_heap = _heap_thresholds(
+                features_heap, bins_heap, jnp.asarray(thresholds, jnp.float32)
+            )
+            return GBTModel(
+                f0,
+                features_heap,
+                thresholds_heap,
+                leaf_values,
+                self.step,
+                self.mesh,
+                self.max_depth,
+            )

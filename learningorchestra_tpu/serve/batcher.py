@@ -294,6 +294,7 @@ class MicroBatcher:
             # the span covers the registry lookup too, so its
             # hit/miss verdict (registry.get annotates the ambient
             # span) and a miss's serve:load_model child both land here
+            forward_started = time.monotonic()
             with span("serve:forward", requests=len(group)):
                 model = self.registry.get(group[0].path)
                 rows = np.concatenate([request.rows for request in group])
@@ -313,6 +314,11 @@ class MicroBatcher:
                     dtype=str(rows.dtype),
                 )
                 labels, probs = model.predict_both(rows)
+            # registry lookup to results on the host: the lane's service
+            # time, beside the queue wait observed at dispatch start
+            self._metrics["forward"].observe(
+                time.monotonic() - forward_started
+            )
         except BaseException as error:  # noqa: BLE001 — delivered to the
             # waiting request threads; the route maps it to an HTTP error
             for request in group:
@@ -395,6 +401,12 @@ def _serve_batch_metrics() -> dict:
                 "queue_wait": registry.histogram(
                     "lo_serve_queue_wait_seconds",
                     "Seconds between request admission and dispatch start",
+                    buckets=LATENCY_BUCKETS,
+                ),
+                "forward": registry.histogram(
+                    "lo_serve_forward_seconds",
+                    "Seconds from registry lookup to results on the host, "
+                    "per forward dispatch",
                     buckets=LATENCY_BUCKETS,
                 ),
                 "batches": registry.counter(

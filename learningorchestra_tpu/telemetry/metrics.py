@@ -12,6 +12,7 @@ programming error and raises.
 
 from __future__ import annotations
 
+import sys
 import threading
 from typing import Callable, Optional, Sequence
 
@@ -291,13 +292,15 @@ _GLOBAL_LOCK = threading.Lock()
 
 def global_registry() -> MetricsRegistry:
     """The process-wide registry every component reports into. First
-    call also wires the jitcache collector so ``/metrics`` includes
-    persistent-cache hit/miss and compile seconds on every service."""
+    call also wires the jitcache collector, so ``/metrics`` includes
+    persistent-cache hit/miss and compile seconds on every service, and
+    the memory collector (device and resident-set gauges)."""
     global _GLOBAL
     with _GLOBAL_LOCK:
         if _GLOBAL is None:
             _GLOBAL = MetricsRegistry()
             _register_jitcache(_GLOBAL)
+            _GLOBAL.register_collector(_collect_memory)
         return _GLOBAL
 
 
@@ -323,14 +326,89 @@ def _register_jitcache(registry: MetricsRegistry) -> None:
         "Cumulative jaxpr trace seconds this process",
     )
 
+    retrieval_s = registry.gauge(
+        "lo_jitcache_cache_retrieval_seconds",
+        "Cumulative seconds loading serialized executables on cache hits",
+    )
+
     def collect(_registry: MetricsRegistry) -> None:
         stats = jitcache.raw_stats()
         hits.set(stats["persistent_cache_hits"])
         misses.set(stats["persistent_cache_misses"])
         compile_s.set(stats["backend_compile_s"])
         trace_s.set(stats["trace_s"])
+        retrieval_s.set(stats["cache_retrieval_s"])
 
     registry.register_collector(collect)
+
+
+def _device_memory() -> dict:
+    """``memory_stats()`` of the fullest local device, or ``{}``. Reads
+    the device only when jax is already imported AND a backend is up:
+    a scrape must never be what starts one (a store-only process has
+    none, and a runtime initialised from here would hold the chip)."""
+    if "jax" not in sys.modules:
+        return {}
+    import jax
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return {}
+    stats = [device.memory_stats() or {} for device in jax.local_devices()]
+    return max(stats, key=lambda s: s.get("bytes_in_use", 0), default={})
+
+
+def _process_memory() -> dict:
+    """``VmRSS`` / ``VmHWM`` of this process in bytes, from
+    ``/proc/self/status``; ``{}`` where the platform has no such file.
+    A sandboxed kernel may list ``VmRSS`` and no ``VmHWM`` (the machine
+    with the chip does): the high-water mark is then ``getrusage``'s
+    ``ru_maxrss``, the same quantity from the other door."""
+    figures = {}
+    try:
+        with open("/proc/self/status") as handle:
+            for line in handle:
+                if line.startswith(("VmRSS:", "VmHWM:")):
+                    key, amount = line.split(":")
+                    figures[key] = int(amount.split()[0]) * 1024
+    except (OSError, ValueError):
+        return {}
+    if "VmHWM" not in figures:
+        import resource
+
+        peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+        if peak > 0:
+            figures["VmHWM"] = peak
+    return figures
+
+
+def _collect_memory(registry: MetricsRegistry) -> None:
+    """Device and host memory gauges, set at scrape time and never on a
+    request path. A gauge is declared only once its figure exists, so a
+    platform that gives none (``memory_stats()`` is None on CPU) leaves
+    the family out instead of reporting a 0 nobody measured."""
+    device = _device_memory()
+    if "bytes_in_use" in device:
+        registry.gauge(
+            "lo_device_bytes_in_use",
+            "Bytes in use on the fullest local device",
+        ).set(device["bytes_in_use"])
+    if "peak_bytes_in_use" in device:
+        registry.gauge(
+            "lo_device_peak_bytes_in_use",
+            "Peak bytes in use on the fullest local device",
+        ).set(device["peak_bytes_in_use"])
+    process = _process_memory()
+    if "VmRSS" in process:
+        registry.gauge(
+            "lo_process_resident_bytes",
+            "Resident set of this process (VmRSS)",
+        ).set(process["VmRSS"])
+    if "VmHWM" in process:
+        registry.gauge(
+            "lo_process_peak_resident_bytes",
+            "Peak resident set of this process (VmHWM, else ru_maxrss)",
+        ).set(process["VmHWM"])
 
 
 # store id() → its "store" label value. The collector closure keeps a

@@ -176,6 +176,17 @@ def span(name: str, **meta) -> Iterator[Optional[Span]]:
         _SPAN.reset(token)
 
 
+def device_wait(name: str, tree):
+    """Block until every array in ``tree`` is ready, under a span named
+    ``name``: the seconds the calling thread waited for the device
+    (queueing behind other threads' programs included) stand apart from
+    the host work and the transfers round them. Returns ``tree``."""
+    import jax
+
+    with span(name):
+        return jax.block_until_ready(tree)
+
+
 def annotate(**attrs) -> None:
     """Set typed attributes on the CURRENT span (no-op without one) —
     for instrumentation sites that learn a fact (registry hit/miss,

@@ -66,6 +66,9 @@ _STATS = {
     "persistent_cache_misses": 0,
     "backend_compile_s": 0.0,
     "trace_s": 0.0,
+    # seconds reading serialized executables back on cache hits: what a
+    # warm process still pays of backend_compile_s
+    "cache_retrieval_s": 0.0,
 }
 _LISTENERS_ON = False
 
@@ -81,15 +84,24 @@ def _on_event(name: str, **_kw) -> None:
         _account_compile(result="hit")
 
 
-def _on_duration(name: str, duration_secs: float, **_kw) -> None:
+def _on_duration(name: str, duration_secs: float, **kw) -> None:
     if name == "/jax/core/compile/backend_compile_duration":
         _STATS["backend_compile_s"] += duration_secs
-        _account_compile(seconds=duration_secs, span_name="compile:backend")
+        # jax 0.9 names the jitted function it compiled (or loaded)
+        _account_compile(
+            seconds=duration_secs,
+            span_name="compile:backend",
+            program=kw.get("fun_name"),
+        )
     elif name == "/jax/core/compile/jaxpr_trace_duration":
         _STATS["trace_s"] += duration_secs
+    elif name == "/jax/compilation_cache/cache_retrieval_time_sec":
+        _STATS["cache_retrieval_s"] += duration_secs
 
 
-def _account_compile(result=None, seconds=None, span_name=None) -> None:
+def _account_compile(
+    result=None, seconds=None, span_name=None, program=None
+) -> None:
     """Feed the flight recorder (telemetry/profile.py): compile events
     become ``lo_compile_*`` counters and — when a trace is active on
     the compiling thread, which it is for every scheduled job — an
@@ -108,13 +120,15 @@ def _account_compile(result=None, seconds=None, span_name=None) -> None:
             result=result, seconds=seconds, source=source
         )
         if span_name is not None and seconds is not None:
+            meta = {"compile": True}
+            if program is not None:
+                meta["program"] = str(program)
             if source != "jit":
-                meta = {"compile": True, "source": source}
+                meta["source"] = source
                 if manifest_key is not None:
                     meta["manifest_key"] = manifest_key
-                tracing.record_span("compile:aot", seconds, **meta)
-            else:
-                tracing.record_span(span_name, seconds, compile=True)
+                span_name = "compile:aot"
+            tracing.record_span(span_name, seconds, **meta)
         elif result is not None:
             # typed hit/miss counts on the enclosing span (fit, build…)
             tracing.add_attr(f"compile_{result}", 1)

@@ -21,30 +21,25 @@ class PhaseTimer:
     """Accumulates ``{phase: seconds}``; reentrant per phase.
 
     Each phase ENTRY lands as its own timestamped span in the active
-    trace (a no-op outside one) and as its own row in ``occurrences``:
-    a phase entered twice is two events with distinct start/end
-    boundaries on the timeline — summing them into one bucket would
-    smear ``GET /jobs/<name>/profile``'s Chrome trace. The summed
-    ``as_metadata()`` contract is unchanged: stored job metadata keeps
-    one total per phase name. ``**attrs`` become typed span attributes
-    (rows, bytes, dtype) on that occurrence's span."""
+    trace (a no-op outside one): a phase entered twice is two events
+    with distinct start/end boundaries on the timeline — summing them
+    into one bucket would smear ``GET /jobs/<name>/profile``'s Chrome
+    trace. The summed ``as_metadata()`` contract is unchanged: stored
+    job metadata keeps one total per phase name. ``**attrs`` become
+    typed span attributes (rows, bytes, dtype) on that entry's span."""
 
     def __init__(self):
         self.timings: dict[str, float] = {}
-        # one row per phase ENTRY: (name, epoch start, seconds)
-        self.occurrences: list[tuple[str, float, float]] = []
 
     @contextlib.contextmanager
     def phase(self, name: str, **attrs) -> Iterator[None]:
         start = time.perf_counter()
-        started_at = time.time()
         try:
             with _tracing.span(f"phase:{name}", **attrs):
                 yield
         finally:
             elapsed = time.perf_counter() - start
             self.timings[name] = self.timings.get(name, 0.0) + elapsed
-            self.occurrences.append((name, started_at, elapsed))
 
     def as_metadata(self) -> dict[str, float]:
         """Rounded copy for inclusion in stored job metadata."""
@@ -61,4 +56,9 @@ def trace(log_dir: Optional[str]) -> Iterator[None]:
     import jax
 
     with jax.profiler.trace(log_dir):
+        # the wall clock at one instant of the capture's own clock: with
+        # it, the spans of GET /jobs/<name>/trace (wall clock) lay over
+        # the capture's device events (docs/profiling.md)
+        with jax.profiler.TraceAnnotation("lo_clock", wall=time.time()):
+            pass
         yield

@@ -131,6 +131,72 @@ class TestBuildModel:
         assert "F1" not in results[0]
 
 
+class TestBuildTraceSpans:
+    """What a library-level build leaves in an active trace beside the
+    phases: the resume fingerprint's own span, and no ``devices``."""
+
+    def _build(self, store, classifiers, **kwargs):
+        from learningorchestra_tpu.telemetry import tracing
+
+        trace = tracing.Trace(name="build")
+        with tracing.activate(trace), tracing.span("job:build:test"):
+            build_model(
+                store,
+                "titanic_train",
+                "titanic_test",
+                DOCUMENTED_PREPROCESSOR,
+                classifiers,
+                **kwargs,
+            )
+        (root,) = trace.as_dict()["spans"]
+        return root
+
+    def test_fingerprint_span_when_progress_is_journaled(
+        self, titanic_store, tmp_path
+    ):
+        root = self._build(
+            titanic_store, ["nb"], models_dir=str(tmp_path / "models")
+        )
+        names = [child["name"] for child in root["children"]]
+        assert names.count("resume:fingerprint") == 1
+        assert names.index("preprocess") < names.index(
+            "resume:fingerprint"
+        ) < names.index("train:nb")
+        (span,) = [
+            c for c in root["children"] if c["name"] == "resume:fingerprint"
+        ]
+        assert span["meta"]["rows"] == 16  # both collections' rows
+
+    @pytest.mark.parametrize(
+        "resume, models_dir", [("0", "models"), ("1", "")]
+    )
+    def test_no_fingerprint_span_when_nothing_is_hashed(
+        self, titanic_store, tmp_path, monkeypatch, resume, models_dir
+    ):
+        monkeypatch.setenv("LO_RESUME", resume)
+        root = self._build(
+            titanic_store,
+            ["nb"],
+            models_dir=str(tmp_path / models_dir) if models_dir else "",
+        )
+        names = [child["name"] for child in root["children"]]
+        assert "resume:fingerprint" not in names
+        assert "devices" not in names
+        assert {"load_data", "preprocess", "train:nb"} <= set(names)
+
+    def test_nb_fit_has_a_transfer_and_a_wait_and_no_host_pass(
+        self, titanic_store
+    ):
+        root = self._build(titanic_store, ["nb"])
+        (train,) = [c for c in root["children"] if c["name"] == "train:nb"]
+        (fit,) = [c for c in train["children"] if c["name"] == "phase:fit"]
+        names = [
+            c["name"] for c in fit["children"]
+            if not c["name"].startswith("compile:")
+        ]
+        assert names == ["h2d:train", "fit:enqueue", "fit:device_wait"]
+
+
 class TestFusedEvaluatePredict:
     """ml/base.evaluate_predict: metrics + predictions in ONE device→host
     transfer, sharing the forward pass when eval and test frames alias

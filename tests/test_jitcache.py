@@ -27,6 +27,7 @@ def fresh_stats(monkeypatch):
         "persistent_cache_misses": 0,
         "backend_compile_s": 0.0,
         "trace_s": 0.0,
+        "cache_retrieval_s": 0.0,
     }
     monkeypatch.setattr(jitcache, "_STATS", stats)
     return stats
@@ -70,6 +71,95 @@ class TestDurationAccumulation:
         jitcache._on_duration("/jax/core/lowering_duration", 9.0)
         assert fresh_stats["backend_compile_s"] == 0.0
         assert fresh_stats["trace_s"] == 0.0
+        assert fresh_stats["cache_retrieval_s"] == 0.0
+
+    def test_cache_retrieval_seconds_accumulate(self, fresh_stats):
+        event = "/jax/compilation_cache/cache_retrieval_time_sec"
+        jitcache._on_duration(event, 0.75)
+        jitcache._on_duration(event, 0.5)
+        assert fresh_stats["cache_retrieval_s"] == pytest.approx(1.25)
+        # the saved-time event beside it is not the load time
+        jitcache._on_duration(
+            "/jax/compilation_cache/compile_time_saved_sec", 30.0
+        )
+        assert fresh_stats["cache_retrieval_s"] == pytest.approx(1.25)
+
+    def test_retrieval_gauge_is_fed_like_its_siblings(self, fresh_stats):
+        from learningorchestra_tpu.telemetry.metrics import (
+            MetricsRegistry,
+            _register_jitcache,
+        )
+
+        registry = MetricsRegistry()
+        _register_jitcache(registry)
+        fresh_stats["cache_retrieval_s"] = 9.5
+        fresh_stats["backend_compile_s"] = 11.5
+        text = registry.render()
+        assert "lo_jitcache_cache_retrieval_seconds 9.5" in text
+        assert "lo_jitcache_backend_compile_seconds 11.5" in text
+
+
+class TestCompileSpans:
+    """What a compile (or an executable load) leaves in the active
+    trace: when, how long, and for which program."""
+
+    def _compile(self, **kwargs):
+        from learningorchestra_tpu.telemetry import tracing
+
+        trace = tracing.Trace(name="compile")
+        with tracing.activate(trace), tracing.span("phase:fit"):
+            jitcache._on_duration(
+                "/jax/core/compile/backend_compile_duration", 0.4, **kwargs
+            )
+        (fit,) = trace.as_dict()["spans"]
+        (span,) = fit["children"]
+        return span
+
+    def test_backend_span_names_the_program(self, fresh_stats):
+        span = self._compile(fun_name="jit(_dt_fit)")
+        assert span["name"] == "compile:backend"
+        assert span["duration_s"] == pytest.approx(0.4)
+        assert span["meta"] == {"compile": True, "program": "jit(_dt_fit)"}
+
+    def test_no_fun_name_no_program(self, fresh_stats):
+        # an older jax passes no keyword: the span stays, unnamed
+        assert self._compile()["meta"] == {"compile": True}
+
+    def test_aot_span_keeps_its_source_and_gains_the_program(
+        self, fresh_stats
+    ):
+        with jitcache.compile_source("aot", key="lr/8192x16"):
+            span = self._compile(fun_name="jit(_fit_segment_impl)")
+        assert span["name"] == "compile:aot"
+        assert span["meta"] == {
+            "compile": True,
+            "program": "jit(_fit_segment_impl)",
+            "source": "aot",
+            "manifest_key": "lr/8192x16",
+        }
+
+    def test_a_real_compile_reports_its_function(self, fresh_stats):
+        import jax
+        import jax.numpy as jnp
+
+        from learningorchestra_tpu.telemetry import tracing
+
+        jitcache._register_listeners()
+
+        @jax.jit
+        def lo_probe_program(x):
+            return x * 3 + 1
+
+        trace = tracing.Trace(name="real")
+        with tracing.activate(trace), tracing.span("phase:fit"):
+            lo_probe_program(jnp.arange(7)).block_until_ready()
+        (fit,) = trace.as_dict()["spans"]
+        programs = [
+            child["meta"].get("program")
+            for child in fit["children"]
+            if child["name"] == "compile:backend"
+        ]
+        assert "jit(lo_probe_program)" in programs
 
 
 class TestCacheStats:

@@ -178,3 +178,65 @@ def test_deep_tree_wide_level_routing():
     model = DecisionTreeClassifier(max_depth=8).fit(X, y)
     accuracy, _ = model.evaluate(X, y)
     assert accuracy > 0.95
+
+
+class TestNamedScopes:
+    """The stages of a tree fit name their XLA ops (``lo.hist``,
+    ``lo.split``, ``lo.route``, ``lo.leaf``; ``lo.bin`` in binning), so
+    a profiler capture says which stage an op belongs to — and the
+    names change nothing of the fit."""
+
+    def _inputs(self, rng):
+        import jax.numpy as jnp
+
+        X = rng.normal(size=(256, 4)).astype(np.float32)
+        y = ((X[:, 0] > 0) ^ (X[:, 2] > 0.3)).astype(np.int32)
+        thresholds = make_thresholds(X, 32).astype(np.float32)
+        bins = apply_bins(X, thresholds)
+        return bins, jnp.asarray(y), jnp.ones(256, jnp.float32)
+
+    @pytest.mark.parametrize(
+        "scope", ["lo.hist", "lo.split", "lo.route", "lo.leaf"]
+    )
+    def test_stage_names_reach_the_lowered_program(self, rng, scope):
+        from learningorchestra_tpu.ml import trees
+
+        bins, y, weights = self._inputs(rng)
+        text = trees._dt_fit.lower(bins, y, weights, 2, 3, 32).as_text(
+            debug_info=True
+        )
+        assert scope in text
+
+    def test_binning_names_its_search(self, rng):
+        X = rng.normal(size=(64, 3)).astype(np.float32)
+        thresholds = make_thresholds(X, 32).astype(np.float32)
+        text = apply_bins.lower(X, thresholds).as_text(debug_info=True)
+        assert "lo.bin" in text
+
+    def test_fitted_tree_is_bit_identical_without_the_names(
+        self, rng, monkeypatch
+    ):
+        import contextlib
+
+        import jax
+
+        from learningorchestra_tpu.ml import trees
+
+        bins, y, weights = self._inputs(rng)
+        named = trees._dt_fit(bins, y, weights, 2, 4, 32)
+        monkeypatch.setattr(
+            jax, "named_scope", lambda name: contextlib.nullcontext()
+        )
+        # a function of its own: the same one would be served from
+        # jax's trace cache, names and all
+        unnamed_fit = jax.jit(
+            lambda *arrays: trees._dt_fit.__wrapped__(*arrays, 2, 4, 32)
+        )
+        assert "lo.hist" not in unnamed_fit.lower(bins, y, weights).as_text(
+            debug_info=True
+        )
+        unnamed = unnamed_fit(bins, y, weights)
+        for with_names, without in zip(named, unnamed):
+            assert np.asarray(with_names).tobytes() == (
+                np.asarray(without).tobytes()
+            )

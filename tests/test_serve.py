@@ -239,6 +239,50 @@ class TestMicroBatcher:
         finally:
             plane.close()
 
+    def test_forward_seconds_observed_once_per_dispatch(self, data, tmp_path):
+        """``lo_serve_forward_seconds`` is the lane's service time,
+        registry lookup to results on the host, beside the queue wait:
+        one observation per forward that ran, none for one that failed."""
+        from learningorchestra_tpu.telemetry import global_registry
+
+        def family(name):
+            text = global_registry().render()
+            assert f"# TYPE {name} histogram" in text
+            values = {}
+            for line in text.splitlines():
+                if line.startswith((f"{name}_count", f"{name}_sum")):
+                    key, value = line.split()
+                    values[key.rsplit("_", 1)[1]] = float(value)
+            return values
+
+        X, y = data
+        _, path, _ = fit_and_checkpoint("f_prediction_lr", X, y, tmp_path)
+        plane = ServePlane(
+            capacity=10**9, window_s=0.0, max_batch=8, inbox_cap=32
+        )
+        try:
+            rows = X.astype(np.float32)
+            first = plane.submit(path, rows[:1])
+            assert first.wait(30) and first.error is None
+            before = family("lo_serve_forward_seconds")
+            waits_before = family("lo_serve_queue_wait_seconds")
+            batches_before = plane.batcher.stats()["batches"]
+            for index in range(3):
+                request = plane.submit(path, rows[index : index + 1])
+                assert request.wait(30) and request.error is None
+            missing = plane.submit(str(tmp_path / "nowhere"), rows[:1])
+            assert missing.wait(30) and missing.error is not None
+            after = family("lo_serve_forward_seconds")
+            ran = plane.batcher.stats()["batches"] - batches_before
+            assert ran >= 1
+            assert after["count"] - before["count"] == ran
+            assert after["sum"] > before["sum"]
+            # the failed forward was dispatched (it waited) but not served
+            waits = family("lo_serve_queue_wait_seconds")
+            assert waits["count"] - waits_before["count"] == 4
+        finally:
+            plane.close()
+
     def test_width_mismatch_fails_alone(self, data, tmp_path):
         X, y = data
         model, path, _ = fit_and_checkpoint(

@@ -103,6 +103,125 @@ class TestRegistry:
         assert "t_ok 7" in registry.render()
 
 
+class TestMemoryGauges:
+    """The device and resident-set gauges: set by one collector at
+    scrape time, present only where the platform gives a figure."""
+
+    def test_process_gauges_on_the_global_registry(self):
+        text = metrics_mod.global_registry().render()
+        values = {}
+        for line in text.splitlines():
+            if line.startswith("lo_process_"):
+                name, value = line.split()
+                values[name] = float(value)
+        assert values["lo_process_resident_bytes"] > 2**20
+        assert values["lo_process_peak_resident_bytes"] >= (
+            values["lo_process_resident_bytes"]
+        )
+
+    def test_device_gauges_when_the_device_reports(self, monkeypatch):
+        monkeypatch.setattr(
+            metrics_mod,
+            "_device_memory",
+            lambda: {"bytes_in_use": 3 * 2**30, "peak_bytes_in_use": 5 * 2**30},
+        )
+        registry = MetricsRegistry()
+        registry.register_collector(metrics_mod._collect_memory)
+        text = registry.render()
+        assert f"lo_device_bytes_in_use {3 * 2**30}" in text
+        assert f"lo_device_peak_bytes_in_use {5 * 2**30}" in text
+
+    def test_no_figure_no_gauge(self, monkeypatch):
+        # a CPU backend's memory_stats() is None, a platform without
+        # /proc has no resident set: the families are left out, not 0
+        monkeypatch.setattr(metrics_mod, "_device_memory", lambda: {})
+        monkeypatch.setattr(metrics_mod, "_process_memory", lambda: {})
+        registry = MetricsRegistry()
+        registry.register_collector(metrics_mod._collect_memory)
+        text = registry.render()
+        assert "lo_device_" not in text and "lo_process_" not in text
+
+    def test_peak_falls_back_to_rusage_where_proc_has_no_high_water_mark(
+        self, monkeypatch
+    ):
+        # the machine with the chip lists VmRSS and no VmHWM
+        import builtins
+        import io
+        import resource
+
+        real_open = builtins.open
+
+        def fake_open(path, *args, **kwargs):
+            if path == "/proc/self/status":
+                return io.StringIO("Name:\tpython\nVmRSS:\t    2048 kB\n")
+            return real_open(path, *args, **kwargs)
+
+        monkeypatch.setattr(builtins, "open", fake_open)
+        figures = metrics_mod._process_memory()
+        assert figures["VmRSS"] == 2048 * 1024
+        assert figures["VmHWM"] == (
+            resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+        ) > 0
+
+    def test_fullest_local_device_is_the_one_reported(self, monkeypatch):
+        import jax
+
+        class Device:
+            def __init__(self, stats):
+                self._stats = stats
+
+            def memory_stats(self):
+                return self._stats
+
+        jax.devices()  # a backend is up, as in every serving process
+        monkeypatch.setattr(
+            jax,
+            "local_devices",
+            lambda: [
+                Device({"bytes_in_use": 10, "peak_bytes_in_use": 90}),
+                Device({"bytes_in_use": 40, "peak_bytes_in_use": 50}),
+                Device(None),
+            ],
+        )
+        assert metrics_mod._device_memory() == {
+            "bytes_in_use": 40, "peak_bytes_in_use": 50,
+        }
+
+    @pytest.mark.parametrize(
+        "prelude, check",
+        [
+            # a store-only process never imports jax: a scrape must not
+            ("", "assert 'jax' not in sys.modules"),
+            # jax imported, no backend yet: a scrape must not start one
+            (
+                "import jax",
+                "from jax._src import xla_bridge; "
+                "assert not xla_bridge.backends_are_initialized()",
+            ),
+        ],
+    )
+    def test_a_scrape_starts_no_backend(self, prelude, check):
+        import os
+        import subprocess
+        import sys
+
+        code = (
+            f"import sys; {prelude or 'pass'}\n"
+            "from learningorchestra_tpu.telemetry.metrics import "
+            "global_registry\n"
+            "text = global_registry().render()\n"
+            "assert 'lo_process_resident_bytes' in text\n"
+            "assert 'lo_device_' not in text\n"
+            f"{check}\n"
+        )
+        done = subprocess.run(
+            [sys.executable, "-c", code],
+            capture_output=True, text=True, timeout=120,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        )
+        assert done.returncode == 0, done.stderr[-2000:]
+
+
 class TestTracing:
     def test_span_noop_without_trace(self):
         with tracing.span("orphan") as s:
