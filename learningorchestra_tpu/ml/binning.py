@@ -9,6 +9,9 @@ binning keeps every tree op on the MXU/VPU.
 Bin semantics: ``bin b`` holds values ``thresholds[b-1] < x <=
 thresholds[b]``; a split "at bin b" sends ``x <= thresholds[b]`` left, so
 raw-feature prediction only needs the float threshold, never the bins.
+
+Assignment (``apply_bins``) counts, per value, its feature's thresholds
+below it: one fused elementwise program, no gather and no loop.
 """
 
 from __future__ import annotations
@@ -25,8 +28,8 @@ def make_thresholds(X: np.ndarray, max_bins: int = MAX_BINS) -> np.ndarray:
 
     Duplicate quantiles (constant-ish features) are harmless: empty bins
     simply never win a split. NaNs are ignored when computing quantiles
-    and land in the last bin at assignment (searchsorted sends NaN right),
-    a one-sided missing-value policy like LightGBM's default.
+    and land in the last bin at assignment (``apply_bins`` sends NaN
+    there), a one-sided missing-value policy like LightGBM's default.
     """
     quantiles = np.linspace(0, 1, max_bins + 1)[1:-1]
     with np.errstate(all="ignore"):
@@ -36,20 +39,32 @@ def make_thresholds(X: np.ndarray, max_bins: int = MAX_BINS) -> np.ndarray:
 
 @jax.jit
 def apply_bins(X: jax.Array, thresholds: jax.Array) -> jax.Array:
-    """Assign each value its bin index in ``[0, max_bins)``: one
-    vmapped ``searchsorted`` per feature, on device.
+    """Assign each value its bin index in ``[0, max_bins)``, on device:
+    the bin is the count of its feature's thresholds below the value,
+    ``#{k : thresholds[f, k] < X[r, f]}``, with NaN sent to the last
+    bin — the integer ``np.searchsorted(thresholds[f], X[:, f],
+    side="left")`` gives for sorted thresholds, ties and repeats
+    included.
+
+    Counted, not searched: ``max_bins - 1`` compares a value, each a
+    dense elementwise op with the threshold broadcast along the rows,
+    and XLA fuses the whole sum into one pass that reads the float32
+    matrix once and writes the bins once (no temporary of the matrix's
+    size). A binary search takes fewer steps, but every step is a
+    data-dependent gather per value, which the TPU's vector unit
+    serialises.
 
     int8 result (when the bin count fits): the binned matrix is the
-    tree fits' largest long-lived buffer, and TPU tiling pads the
-    feature-minor dimension to the 128-lane boundary — at 10M×16 an
-    int32 binned matrix occupies ~5 GB of HBM after padding, int8 ~1.3
-    GB. Index arithmetic downstream promotes to int32 as needed.
+    tree fits' largest long-lived buffer after the float32 matrix, a
+    quarter of its bytes. Index arithmetic downstream promotes to int32
+    as needed.
     """
-
-    def one_feature(column, feature_thresholds):
-        return jnp.searchsorted(feature_thresholds, column, side="left")
-
+    num_thresholds = thresholds.shape[1]
     with jax.named_scope("lo.bin"):
-        bins = jax.vmap(one_feature, in_axes=(1, 0), out_axes=1)(X, thresholds)
-    max_bins = thresholds.shape[1] + 1
+        bins = sum(
+            (thresholds[:, k] < X).astype(jnp.int32)
+            for k in range(num_thresholds)
+        )
+        bins = jnp.where(jnp.isnan(X), num_thresholds, bins)
+    max_bins = num_thresholds + 1
     return bins.astype(jnp.int8 if max_bins <= 127 else jnp.int32)

@@ -1,11 +1,14 @@
 """Tree estimators vs sklearn oracles and invariants."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 import sklearn.ensemble
 import sklearn.tree
 
-from learningorchestra_tpu.ml.base import make_classifier
+from learningorchestra_tpu.ml import trees
+from learningorchestra_tpu.ml.base import make_classifier, prepare_xy
 from learningorchestra_tpu.ml.binning import apply_bins, make_thresholds
 from learningorchestra_tpu.ml.evaluation import accuracy_score
 from learningorchestra_tpu.ml.trees import (
@@ -32,6 +35,52 @@ def three_class(rng):
     return X, y
 
 
+def _searchsorted_bins(X, thresholds):
+    """The reference ``apply_bins`` is held to, one column at a time."""
+    return np.stack(
+        [
+            np.searchsorted(thresholds[f], X[:, f], side="left")
+            for f in range(X.shape[1])
+        ],
+        axis=1,
+    )
+
+
+def _on_thresholds(rng):
+    X = rng.normal(size=(512, 4))
+    # a float32 copy of every threshold back among the values, for both
+    # bin counts the test runs
+    for max_bins in (32, 200):
+        ties = make_thresholds(X, max_bins).astype(np.float32).T
+        X = np.concatenate([X, ties])
+    return X
+
+
+def _odd_values(rng):
+    X = rng.normal(size=(640, 5))
+    for value in (np.nan, np.inf, -np.inf, -0.0, 0.0):
+        X[rng.integers(0, 640, 40), rng.integers(0, 5, 40)] = value
+    X[:, 4] = np.where(rng.random(640) < 0.5, -0.0, X[:, 4])
+    return X
+
+
+def _with_column(rng, column):
+    X = rng.normal(size=(400, 3))
+    X[:, 1] = column
+    return X
+
+
+_BINNING_CASES = {
+    "random_normal": lambda rng: rng.normal(size=(500, 6)),
+    "on_thresholds": _on_thresholds,
+    "three_levels": lambda rng: _with_column(rng, rng.integers(0, 3, 400)),
+    "constant_column": lambda rng: _with_column(rng, 2.5),
+    "all_nan_column": lambda rng: _with_column(rng, np.nan),
+    "nan_inf_negative_zero": _odd_values,
+    "rows_257": lambda rng: rng.normal(size=(257, 28)),
+}
+
+
 class TestBinning:
     def test_bins_are_monotone_with_values(self, rng):
         X = rng.normal(size=(500, 3))
@@ -48,6 +97,23 @@ class TestBinning:
         thresholds = np.array([[1.5, 2.5, 3.5]])
         bins = np.asarray(apply_bins(X.astype(np.float32), thresholds.astype(np.float32)))
         assert bins[:, 0].tolist() == [0, 1, 2, 3]
+
+    @pytest.mark.parametrize("max_bins", [32, 200])
+    @pytest.mark.parametrize("case", sorted(_BINNING_CASES))
+    def test_equals_searchsorted_left(self, rng, case, max_bins):
+        """Every bin is the integer ``np.searchsorted(side="left")``
+        gives per column: ties go left, repeats and ``+inf`` thresholds
+        are no special case, NaN lands in the last bin."""
+        X = _BINNING_CASES[case](rng).astype(np.float32)
+        # quantiles of the finite values: `make_thresholds` interpolates
+        # between two equal infinities to NaN -> +inf, out of order, and
+        # a search is defined on sorted thresholds only
+        finite = np.where(np.isinf(X), np.nan, X)
+        thresholds = make_thresholds(finite, max_bins).astype(np.float32)
+        assert (thresholds[:, 1:] >= thresholds[:, :-1]).all()
+        bins = np.asarray(apply_bins(X, thresholds))
+        assert bins.dtype == (np.int8 if max_bins <= 127 else np.int32)
+        np.testing.assert_array_equal(bins, _searchsorted_bins(X, thresholds))
 
 
 class TestDecisionTree:
@@ -187,8 +253,6 @@ class TestNamedScopes:
     names change nothing of the fit."""
 
     def _inputs(self, rng):
-        import jax.numpy as jnp
-
         X = rng.normal(size=(256, 4)).astype(np.float32)
         y = ((X[:, 0] > 0) ^ (X[:, 2] > 0.3)).astype(np.int32)
         thresholds = make_thresholds(X, 32).astype(np.float32)
@@ -199,8 +263,6 @@ class TestNamedScopes:
         "scope", ["lo.hist", "lo.split", "lo.route", "lo.leaf"]
     )
     def test_stage_names_reach_the_lowered_program(self, rng, scope):
-        from learningorchestra_tpu.ml import trees
-
         bins, y, weights = self._inputs(rng)
         text = trees._dt_fit.lower(bins, y, weights, 2, 3, 32).as_text(
             debug_info=True
@@ -213,14 +275,20 @@ class TestNamedScopes:
         text = apply_bins.lower(X, thresholds).as_text(debug_info=True)
         assert "lo.bin" in text
 
+    def test_binning_lowers_to_no_gather_and_no_loop(self, rng):
+        # a binary search lowers to a `while` of per-value gathers, which
+        # the TPU serialises: 35 of a build's 77 s (PERF.md §6, PR 27)
+        X = rng.normal(size=(64, 3)).astype(np.float32)
+        thresholds = make_thresholds(X, 32).astype(np.float32)
+        text = apply_bins.lower(X, thresholds).as_text()
+        assert "compare" in text
+        assert "gather" not in text
+        assert "while" not in text
+
     def test_fitted_tree_is_bit_identical_without_the_names(
         self, rng, monkeypatch
     ):
         import contextlib
-
-        import jax
-
-        from learningorchestra_tpu.ml import trees
 
         bins, y, weights = self._inputs(rng)
         named = trees._dt_fit(bins, y, weights, 2, 4, 32)
@@ -240,3 +308,81 @@ class TestNamedScopes:
             assert np.asarray(with_names).tobytes() == (
                 np.asarray(without).tobytes()
             )
+
+
+class TestFitsOnReferenceBins:
+    """The three tree classifiers publish, bit for bit, what the same
+    fit programs give on bins computed by ``np.searchsorted``."""
+
+    def _data(self, rng):
+        X = rng.normal(size=(700, 6))
+        X[rng.integers(0, 700, 30), rng.integers(0, 6, 30)] = np.nan
+        X[:, 5] = rng.integers(0, 3, 700)
+        y = ((X[:, 0] > 0) ^ (X[:, 5] > 0.5)).astype(int)
+        return X, y
+
+    def _reference_inputs(self, X, y, mesh):
+        thresholds = make_thresholds(X, 32).astype(np.float32)
+        X_dev, y_dev, mask = prepare_xy(X, y, mesh)
+        bins = _searchsorted_bins(np.asarray(X_dev), thresholds)
+        return (
+            jnp.asarray(bins, jnp.int8),
+            y_dev,
+            mask.astype(jnp.float32),
+            jnp.asarray(thresholds),
+        )
+
+    @staticmethod
+    def _same(published, replayed):
+        for ours, theirs in zip(published, replayed):
+            ours, theirs = np.asarray(ours), np.asarray(theirs)
+            assert ours.dtype == theirs.dtype and ours.shape == theirs.shape
+            assert ours.tobytes() == theirs.tobytes()
+
+    def test_decision_tree(self, rng):
+        X, y = self._data(rng)
+        model = DecisionTreeClassifier(max_depth=4).fit(X, y)
+        bins, y_dev, weights, thresholds = self._reference_inputs(X, y, model.mesh)
+        features, bin_heap, leaves = trees._dt_fit(bins, y_dev, weights, 2, 4, 32)
+        self._same(
+            (model.features_heap, model.thresholds_heap, model.leaf_probs),
+            (
+                features[None],
+                trees._heap_thresholds(features, bin_heap, thresholds)[None],
+                leaves[None],
+            ),
+        )
+
+    def test_random_forest(self, rng):
+        X, y = self._data(rng)
+        model = RandomForestClassifier(num_trees=5, max_depth=4, seed=7).fit(X, y)
+        bins, y_dev, weights, thresholds = self._reference_inputs(X, y, model.mesh)
+        features, bin_heap, leaves = trees._rf_fit(
+            bins, y_dev, weights, jax.random.key(7), 2, 4, 32, 5, 3,
+            mesh=model.mesh,
+        )
+        self._same(
+            (model.features_heap, model.thresholds_heap, model.leaf_probs),
+            (
+                features,
+                trees._heap_thresholds(features, bin_heap, thresholds),
+                leaves,
+            ),
+        )
+
+    def test_gbt(self, rng):
+        X, y = self._data(rng)
+        model = GBTClassifier(rounds=4, step=0.1, max_depth=3).fit(X, y)
+        bins, y_dev, weights, thresholds = self._reference_inputs(X, y, model.mesh)
+        f0, features, bin_heap, leaves = trees._gbt_fit(
+            bins, y_dev, weights, 3, 32, 4, jnp.float32(0.1)
+        )
+        self._same(
+            (model.f0, model.features_heap, model.thresholds_heap, model.leaf_values),
+            (
+                f0,
+                features,
+                trees._heap_thresholds(features, bin_heap, thresholds),
+                leaves,
+            ),
+        )
