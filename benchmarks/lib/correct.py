@@ -4,9 +4,10 @@ finished build wrote with the plain reference under
 
 Two steps. :func:`read_build` reads back what the build left in the
 store and under the models directory, and counts the exact guarantees
-it broke (``violations``, limit 0) - among them that every output was
-written inside the window. :class:`Comparison` then holds those outputs
-against answers the reference makes from the seed's rows alone. Which
+it broke (``violations``, limit 0) - among them that every checkpoint
+was written since that build was posted. :class:`Comparison` then holds
+those outputs against answers the reference makes from the seed's rows
+alone. Which
 numbers are compared follows from the configuration's ``classifiers``
 (``NUMBERS``); each has its limit in the configuration's ``limits``,
 set from readings that PERF.md gives.
@@ -30,7 +31,7 @@ NUMBERS = {
 }
 TREES = ("dt", "rf", "gb")
 # a file's time comes from the kernel's coarse clock, which may lag the
-# window's first instant by a tick
+# instant the build was posted at by a tick
 MTIME_SLACK_S = 0.05
 
 
@@ -57,10 +58,11 @@ def sample_rows(seed: int, n_test: int, size: int) -> np.ndarray:
 
 
 def read_build(system, ref, config: dict, names: dict, models_dir: str,
-               window_start: float, y_test: np.ndarray, sample: np.ndarray):
-    """What the build left behind, per classifier: the metadata row, the
-    published model, the stored probabilities on the sampled rows and
-    the stored answers' log-loss. Returns ``(outputs, violations)``."""
+               since: float, y_test: np.ndarray, sample: np.ndarray):
+    """What the build posted at ``since`` left behind, per classifier:
+    the metadata row, the published model, the stored probabilities on
+    the sampled rows and the stored answers' log-loss. Returns
+    ``(outputs, violations)``."""
     outputs, violations = {}, []
     for clf in config["classifiers"]:
         name = f"{names['test']}_prediction_{clf}"
@@ -72,8 +74,8 @@ def read_build(system, ref, config: dict, names: dict, models_dir: str,
         if not os.path.isfile(path) or os.path.dirname(path) != models_dir:
             violations.append(f"{clf}: no published checkpoint")
             continue
-        if os.path.getmtime(path) < window_start - MTIME_SLACK_S:
-            violations.append(f"{clf}: the checkpoint was not written in the window")
+        if os.path.getmtime(path) < since - MTIME_SLACK_S:
+            violations.append(f"{clf}: the checkpoint was not written by the window's last build")
         stored = system.stored(name, ["label", "prediction", "probability"])
         if len(stored["prediction"]) != len(y_test):
             violations.append(

@@ -199,7 +199,7 @@ def memory_peak_bytes() -> int:
 
 def run_window(system, cell, data, seconds, trace_dir):
     """The measured window; under the profiler where ``trace_dir``."""
-    window = traffic.Window(system, cell, data["names"], fetch_traces=bool(trace_dir))
+    window = traffic.Window(system, cell, data["names"], traced=bool(trace_dir))
     if not trace_dir:
         window.run(seconds)
         return window
@@ -241,11 +241,15 @@ def judge(system, cell, data, window, seed):
         ref, cell.config, seed,
         data["X_train"], data["y_train"], data["X_test"], data["y_test"],
     )
-    if not any(b["status"] == 201 for b in window.builds):
+    finished = [b for b in window.builds if b["status"] == 201]
+    if not finished:
         comparison.violations.append("no build finished in the window")
+    # every build of a window writes to the same names: what is found was
+    # the last one's only if it was written since that one was posted
     outputs, violations = correct_lib.read_build(
         system, ref, cell.config, data["names"], system.models_dir,
-        window.start, data["y_test"], comparison.sample,
+        finished[-1]["start"] if finished else window.start,
+        data["y_test"], comparison.sample,
     )
     comparison.violations += violations
     comparison.compare(outputs)
@@ -302,9 +306,11 @@ def main(argv=None) -> int:
         counters["window_end"] = system.counters()
         peak = memory_peak_bytes()
         attempted, failed = window.attempted_failed()
+        harness["build_s"] = window.build_seconds()
         log(
             f"window closed after {window.length_s:.1f} s: {len(window.builds)} "
-            f"build(s), {failed} failed"
+            f"build(s), {failed} failed; finished in "
+            + ", ".join(f"{s:.2f}" for s in harness["build_s"]) + " s"
         )
 
         prefix = "rehearsal." if rehearsal else ""

@@ -51,20 +51,24 @@ def answer_altered():
     builder._prediction_columns = altered
 
 
-def state_unchanged():
-    """Every build after the first answers 201 and leaves the store and
-    the checkpoints as they were."""
+def builds_after(real, then):
+    """The first ``real`` calls of the service's ``build_model`` (the
+    warm-up's is the first) go through; every later one does ``then``
+    in its place."""
     from learningorchestra_tpu.services import model_builder
 
     build = model_builder.build_model
     calls = []
 
-    def once(*args, **kwargs):
-        if not calls:
-            calls.append(1)
-            build(*args, **kwargs)
+    def counted(*args, **kwargs):
+        calls.append(1)
+        return build(*args, **kwargs) if len(calls) <= real else then()
 
-    model_builder.build_model = once
+    model_builder.build_model = counted
+
+
+def refuse():
+    raise RuntimeError("planted: this build fails")
 
 
 def poor_splits():
@@ -85,7 +89,14 @@ FAULTS = {
     "none": lambda: None,
     "half_batch": half_batch,
     "answer_altered": answer_altered,
-    "state_unchanged": state_unchanged,
+    # every build of the window answers 201 and leaves the store and the
+    # checkpoints as they were
+    "state_unchanged": lambda: builds_after(1, lambda: None),
+    # the same of every build but the window's first: what is found after
+    # the window is sound, and is not the last build's
+    "later_builds_unchanged": lambda: builds_after(2, lambda: None),
+    # the window's second build answers 500
+    "second_build_fails": lambda: builds_after(2, refuse),
     "poor_splits": poor_splits,
     # the program's own path in the precision below the stated float32
     "bf16": lambda: os.environ.__setitem__("LO_DTYPE_POLICY", "bf16"),

@@ -11,6 +11,8 @@ import sys
 
 import pytest
 
+from lib import cells
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 BUILD = "higgs-11m.build5"
@@ -49,6 +51,9 @@ def test_a_sound_run_is_correct():
     assert over(result) == set()
     assert list(result)[-1] == "compared"  # the numbers come last in the line
     assert all(name.startswith("rehearsal.") for name in result["metrics"])
+    # the window held the builds its mix asks for, each with its seconds
+    builds = cells.Cell(BUILD).mix["build"]["builds"]
+    assert result["attempted"] == builds == len(result["harness"]["build_s"])
 
 
 def readings(*arguments):
@@ -88,6 +93,7 @@ def test_the_control_and_the_planted_faults_read_over_their_limits(tmp_path):
     ("bf16", {"nb_pred_gap", "lr_prob_gap", "dt_split_gap", "gb_leaf_gap"}),
     ("half_batch", {"dt_leaf_gap", "rf_leaf_z", "gb_leaf_gap", "nb_prior_gap"}),
     ("state_unchanged", {"violations"}),
+    ("later_builds_unchanged", {"violations"}),
     ("poor_splits", {"dt_split_gap", "gb_split_gap", "ensemble_loss_gap"}),
     ("answer_altered", {"tree_pred_gap", "nb_pred_gap", "lr_prob_gap"}),
 ])
@@ -95,3 +101,10 @@ def test_a_broken_timed_path_is_not_correct(fault, caught_by):
     result = drive(fault)
     assert result["correct"] is False
     assert caught_by <= over(result)
+
+
+def test_a_build_that_fails_ends_the_window_and_the_run_is_not_correct():
+    result = drive("second_build_fails")
+    assert result["correct"] is False
+    assert (result["attempted"], result["failed"]) == (2, 1)
+    assert len(result["harness"]["build_s"]) == 1
