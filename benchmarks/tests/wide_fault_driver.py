@@ -1,0 +1,45 @@
+"""``fault_driver.py`` with one more fault, which only a wide table can
+show: a fit that loses every feature block of its histograms but the
+first. Same use and same result line.
+
+    python3 wide_fault_driver.py <fault> <workload> <rows>
+"""
+
+import os
+import sys
+
+import fault_driver
+
+
+def first_block_only():
+    """Every fit sees the first block of 80 columns only, as if the
+    blocked histogram contraction had lost every block after its first:
+    the other columns' histograms read zero, so no node can split there."""
+    import jax.numpy as jnp
+
+    from learningorchestra_tpu.ml import trees
+
+    whole = trees._level_histograms
+
+    def first(bins, node, channels, n_nodes, max_bins):
+        hist = whole(bins, node, channels, n_nodes, max_bins)
+        seen = jnp.arange(hist.shape[1]) < 80
+        return jnp.where(seen[None, :, None, None], hist, 0.0)
+
+    trees._level_histograms = first
+
+
+FAULTS = dict(fault_driver.FAULTS, first_block_only=first_block_only)
+
+
+if __name__ == "__main__":
+    fault, workload, rows = sys.argv[1:4]
+    FAULTS[fault]()
+    import run as bench
+
+    code = bench.main([
+        "--workload", workload, "--seed", "2147483777", "--seconds", "1",
+        "--trace", "0", "--rehearsal-rows", rows,
+    ])
+    sys.stdout.flush()
+    os._exit(code)

@@ -416,8 +416,12 @@ def _predict_and_write(
         metadata["timings"] = timer.as_metadata()
         return metadata
 
-    columns = _prediction_columns(predicted_df)
     write_rows = predicted_df.count()
+    # the prediction frame carries every input field: on a wide table
+    # the typed-column copies and the write follow ``fields``, not rows
+    with _tracing.span("write:columns", rows=write_rows):
+        columns = _prediction_columns(predicted_df)
+        _tracing.annotate(fields=len(columns))
     write_bytes = sum(
         int(column.resident_nbytes()) for column in columns.values()
     )
@@ -428,11 +432,15 @@ def _predict_and_write(
             phase="write",
             classificator=metadata.get("classificator"),
         )
-        store.drop(output_name)
-        with timer.phase("write", rows=write_rows, bytes=write_bytes):
+        with _tracing.span("write:drop"):
+            store.drop(output_name)
+        with timer.phase(
+            "write", rows=write_rows, bytes=write_bytes, fields=len(columns)
+        ):
             insert_columns_batched(store, output_name, columns)
         metadata["timings"] = timer.as_metadata()
-        store.insert_one(output_name, metadata)
+        with _tracing.span("write:metadata"):
+            store.insert_one(output_name, metadata)
         # the metadata document is the durability proof (it lands
         # strictly after the rows): only now is this classifier's
         # completion journal-worthy

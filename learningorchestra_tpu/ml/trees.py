@@ -60,6 +60,22 @@ EPS = 1e-12
 _HIST_BLOCK_ROW_FEATURES = 16e6
 
 
+def hist_block_plan(rows: int, num_features: int, max_bins: int) -> dict:
+    """How :func:`_level_histograms` cuts the features of a level into
+    blocks, from the static shapes alone (host arithmetic, no device
+    work): features a block, blocks a level, and the bytes of one
+    block's float32 bin indicator. The fits stamp it on ``fit:enqueue``
+    and the contraction takes its block from here, so the two cannot
+    drift apart."""
+    cap = max(1, int(_HIST_BLOCK_ROW_FEATURES // max(rows, 1)))
+    block = largest_divisor(num_features, cap)
+    return {
+        "hist_block_features": block,
+        "hist_blocks": num_features // block,
+        "hist_indicator_bytes": rows * block * max_bins * 4,
+    }
+
+
 # --------------------------------------------------------------------------
 # Level primitives
 # --------------------------------------------------------------------------
@@ -100,8 +116,9 @@ def _level_histograms(bins, node, channels, n_nodes: int, max_bins: int):
         # contracted over rows directly (no transpose materializes).
         # Block size is HBM-capped: the indicator transient is
         # rows*block*max_bins*4 bytes (~2 GB cap).
-        cap = max(1, int(_HIST_BLOCK_ROW_FEATURES // max(rows, 1)))
-        block = largest_divisor(num_features, cap)
+        block = hist_block_plan(rows, num_features, max_bins)[
+            "hist_block_features"
+        ]
         blocked = bins.T.reshape(num_features // block, block, rows)
         iota = jnp.arange(max_bins, dtype=jnp.int32)
 
@@ -709,7 +726,9 @@ class DecisionTreeClassifier:
         num_classes = infer_num_classes(y)
         thresholds = _traced_thresholds(X, self.max_bins)
         X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
-        with _tracing.span("fit:enqueue"):
+        with _tracing.span(
+            "fit:enqueue", **hist_block_plan(*X_dev.shape, self.max_bins)
+        ):
             bins = apply_bins(X_dev, jnp.asarray(thresholds, jnp.float32))
             features_heap, bins_heap, leaf_probs = _dt_fit(
                 bins,
@@ -752,7 +771,11 @@ class RandomForestClassifier:
         subset_k = max(1, int(np.ceil(np.sqrt(num_features))))
         thresholds = _traced_thresholds(X, self.max_bins)
         X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
-        with _tracing.span("fit:enqueue"):
+        with _tracing.span(
+            "fit:enqueue",
+            subset_k=subset_k,
+            **hist_block_plan(*X_dev.shape, self.max_bins),
+        ):
             bins = apply_bins(X_dev, jnp.asarray(thresholds, jnp.float32))
             features_heap, bins_heap, leaf_probs = _rf_fit(
                 bins,
@@ -824,7 +847,9 @@ class GBTClassifier:
             raise ValueError("GBTClassifier supports binary labels only (MLlib contract)")
         thresholds = _traced_thresholds(X, self.max_bins)
         X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
-        with _tracing.span("fit:enqueue"):
+        with _tracing.span(
+            "fit:enqueue", **hist_block_plan(*X_dev.shape, self.max_bins)
+        ):
             bins = apply_bins(X_dev, jnp.asarray(thresholds, jnp.float32))
             f0, features_heap, bins_heap, leaf_values = _gbt_fit(
                 bins,

@@ -1,5 +1,9 @@
 """Tree estimators vs sklearn oracles and invariants."""
 
+import json
+import os
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -386,3 +390,258 @@ class TestFitsOnReferenceBins:
                 leaves,
             ),
         )
+
+
+def _scatter_histogram(bins, node, channels, n_nodes, max_bins):
+    """``(node, feature, bin, channel)`` sums by a plain numpy
+    scatter-add, in float64."""
+    rows, features = bins.shape
+    hist = np.zeros((n_nodes, features, max_bins, channels.shape[1]))
+    for f in range(features):
+        np.add.at(hist, (node, f, bins[:, f]), channels)
+    return hist
+
+
+class TestBlockedHistograms:
+    """``_level_histograms`` with more than one feature a block and more
+    than one block a level - the path a wide table takes (PERF.md §4:
+    163,840 x 2,000 runs 25 blocks of 80): the same sums as a plain
+    scatter-add and as the one-block contraction."""
+
+    ROWS, FEATURES, BLOCK, BINS = 2048, 400, 80, 32
+
+    def _inputs(self, rng, kind, n_nodes):
+        bins = rng.integers(0, self.BINS, (self.ROWS, self.FEATURES)).astype(np.int8)
+        node = rng.integers(0, n_nodes, self.ROWS).astype(np.int32)
+        if kind == "counts":  # class one-hots times a bootstrap weight
+            label = rng.integers(0, 2, self.ROWS)
+            weight = rng.poisson(1.0, self.ROWS)
+            channels = np.eye(2)[label] * weight[:, None]
+        else:  # Newton (g, h) pairs
+            p = rng.random(self.ROWS)
+            channels = np.stack([p - rng.integers(0, 2, self.ROWS), p * (1 - p)], 1)
+        return bins, node, channels.astype(np.float32)
+
+    @pytest.mark.parametrize("n_nodes", [1, 8])
+    @pytest.mark.parametrize("kind", ["counts", "gradients"])
+    def test_equals_scatter_add_and_the_one_block_result(
+        self, rng, monkeypatch, kind, n_nodes
+    ):
+        bins, node, channels = self._inputs(rng, kind, n_nodes)
+        want = _scatter_histogram(bins, node, channels, n_nodes, self.BINS)
+
+        def histograms(row_features):
+            monkeypatch.setattr(trees, "_HIST_BLOCK_ROW_FEATURES", row_features)
+            return np.asarray(trees._level_histograms(
+                jnp.asarray(bins), jnp.asarray(node), jnp.asarray(channels),
+                n_nodes, self.BINS,
+            ))
+
+        monkeypatch.setattr(trees, "_HIST_BLOCK_ROW_FEATURES", self.ROWS * self.BLOCK)
+        plan = trees.hist_block_plan(self.ROWS, self.FEATURES, self.BINS)
+        assert (plan["hist_block_features"], plan["hist_blocks"]) == (self.BLOCK, 5)
+        blocked = histograms(self.ROWS * self.BLOCK)
+        whole = histograms(1e12)
+        assert blocked.shape == want.shape == whole.shape
+        if kind == "counts":  # small integers: float32 sums are exact
+            assert np.array_equal(blocked, want)
+            assert np.array_equal(blocked, whole)
+        else:
+            np.testing.assert_allclose(blocked, want, atol=1e-4, rtol=0)
+            np.testing.assert_allclose(blocked, whole, atol=1e-4, rtol=0)
+
+
+def _enqueue_spans(work):
+    """The ``fit:enqueue`` spans ``work`` (a fit, a whole build) leaves
+    in an active trace, at any depth."""
+    from learningorchestra_tpu.telemetry import tracing
+
+    trace = tracing.Trace(name="fit")
+    with tracing.activate(trace):
+        work()
+    found, pending = [], list(trace.as_dict()["spans"])
+    while pending:
+        span = pending.pop()
+        pending += span["children"]
+        if span["name"] == "fit:enqueue":
+            found.append(span)
+    return found
+
+
+class TestHistBlockPlan:
+    """The block plan the fits stamp on ``fit:enqueue`` is the one the
+    contraction runs by, at the two benchmark cells' padded shapes."""
+
+    @pytest.mark.parametrize(
+        "rows, features, block, blocks, indicator_bytes",
+        [
+            (163840, 2000, 80, 25, 1677721600),    # epsilon-500k
+            (8388608, 28, 1, 28, 1073741824),      # higgs-11m
+        ],
+    )
+    def test_figures_at_the_cells_shapes(
+        self, rows, features, block, blocks, indicator_bytes
+    ):
+        assert trees.hist_block_plan(rows, features, 32) == {
+            "hist_block_features": block,
+            "hist_blocks": blocks,
+            "hist_indicator_bytes": indicator_bytes,
+        }
+
+    @pytest.mark.parametrize(
+        "make",
+        [
+            lambda: DecisionTreeClassifier(max_depth=2),
+            lambda: RandomForestClassifier(num_trees=2, max_depth=2),
+            lambda: GBTClassifier(rounds=2, max_depth=2),
+        ],
+        ids=["dt", "rf", "gb"],
+    )
+    def test_the_fits_stamp_it_on_their_enqueue_span(self, rng, monkeypatch, make):
+        X = rng.normal(size=(300, 40)).astype(np.float32)
+        y = (X[:, 3] + X[:, 31] > 0).astype(np.int32)
+        classifier = make()
+        padded = prepare_xy(X, y, classifier.mesh)[0].shape[0]
+        # 10 features a block, 4 blocks a level, at this small size
+        monkeypatch.setattr(trees, "_HIST_BLOCK_ROW_FEATURES", padded * 10)
+        (span,) = _enqueue_spans(lambda: classifier.fit(X, y))
+        plan = trees.hist_block_plan(padded, 40, 32)
+        assert plan["hist_block_features"] == 10 and plan["hist_blocks"] == 4
+        assert {k: span["meta"][k] for k in plan} == plan
+        assert ("subset_k" in span["meta"]) == isinstance(
+            classifier, RandomForestClassifier
+        )
+
+
+class TestForestFeatureSubsets:
+    @pytest.mark.parametrize(
+        "features, subset_k", [(2000, 45), (400, 20), (28, 6)]
+    )
+    def test_subset_k_is_the_ceiling_of_the_square_root(
+        self, rng, features, subset_k
+    ):
+        X = rng.normal(size=(64, features)).astype(np.float32)
+        y = (X[:, 0] > 0).astype(np.int32)
+        (span,) = _enqueue_spans(
+            lambda: RandomForestClassifier(num_trees=1, max_depth=1).fit(X, y)
+        )
+        assert span["meta"]["subset_k"] == subset_k
+
+    def test_no_node_splits_outside_its_drawn_subset(self, rng):
+        nodes, features, max_bins, subset_k = 16, 2000, 32, 45
+        gain = jnp.asarray(rng.random((nodes, features, max_bins)), jnp.float32)
+        key = jax.random.key(11)
+        feature, bin_index = trees._select_splits(gain, key, subset_k)
+        # the draw, again: the subset_k smallest of a uniform score a column
+        scores = np.asarray(jax.random.uniform(key, (nodes, features)))
+        allowed = scores <= np.sort(scores, axis=1)[:, subset_k - 1 : subset_k]
+        assert (allowed.sum(axis=1) == subset_k).all()
+        feature, bin_index = np.asarray(feature), np.asarray(bin_index)
+        assert allowed[np.arange(nodes), feature].all()
+        # and inside the subset it is the best candidate
+        inside = np.where(allowed[:, :, None], np.asarray(gain), -np.inf)
+        assert np.array_equal(
+            inside.reshape(nodes, -1).argmax(axis=1), feature * max_bins + bin_index
+        )
+        # without a subset the best of all 64,000 candidates wins
+        free, _ = trees._select_splits(gain, None, None)
+        assert not allowed[np.arange(nodes), np.asarray(free)].all()
+
+
+class TestWideBuildAgainstThePlainReference:
+    """``dt`` + ``rf`` + ``gb`` built through ``build_model`` - store,
+    documented preprocessor, write-back, checkpoints - on a wide dense
+    table from the benchmark's second maker at a small size (3,000 +
+    1,000 rows x 400 columns: five blocks of 80 once the cap is shrunk),
+    and what was published and stored held against the benchmark's plain
+    float64 reference inside the limits of ``epsilon-500k`` (PERF.md §4;
+    the cell itself runs 163,840 + 100,000 x 2,000 on the chip)."""
+
+    TRAIN, TEST, FEATURES, SEED = 3000, 1000, 400, 2147483777
+    BENCH = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks"
+    )
+
+    @pytest.fixture(scope="class")
+    def audit(self, tmp_path_factory):
+        patch = pytest.MonkeyPatch()
+        patch.syspath_prepend(self.BENCH)
+        patch.setenv("LO_RESUME", "0")
+        # the wide path: several blocks of several columns each
+        # (3,000 rows pad to the 3,072 bucket)
+        patch.setattr(trees, "_HIST_BLOCK_ROW_FEATURES", 3072 * 80)
+        try:
+            from lib import cells, correct, system
+
+            from learningorchestra_tpu.core.store import InMemoryStore
+            from learningorchestra_tpu.ml.builder import build_model
+
+            config = json.loads(json.dumps(cells.Cell("epsilon-500k.build3").config))
+            config["dataset"]["features"] = self.FEATURES
+            ref = cells.load_module("reference", "classifiers")
+            maker = cells.load_module("datasets", config["dataset"]["maker"])
+            columns, labels, fields = maker.make(
+                config["dataset"], self.SEED, self.TRAIN + self.TEST
+            )
+
+            class Harness:  # the two calls the comparison makes on a system
+                store = InMemoryStore()
+                stored = system.System.stored
+                write_dataset = system.System.write_dataset
+
+            harness, n = Harness(), self.TRAIN
+            harness.write_dataset("wide_train", [c[:n] for c in columns], labels[:n], fields)
+            harness.write_dataset("wide_test", [c[n:] for c in columns], labels[n:], fields)
+            models_dir = str(tmp_path_factory.mktemp("models"))
+            posted = time.time()
+            spans = _enqueue_spans(lambda: build_model(
+                harness.store, "wide_train", "wide_test", system.PREPROCESSOR,
+                config["classifiers"], models_dir=models_dir,
+            ))
+            X = ref.as_matrix(columns, dtype=np.float32)
+            comparison = correct.Comparison(
+                ref, config, self.SEED, X[:n], labels[:n], X[n:], labels[n:]
+            )
+            outputs, violations = correct.read_build(
+                harness, ref, config, {"test": "wide_test"}, models_dir, posted,
+                labels[n:], comparison.sample,
+            )
+            comparison.compare(outputs)
+            # the forest's two sampling numbers, for a plain float64
+            # forest grown on the same rows by the reference itself
+            plain = comparison.audit_rf(ref.grow_forest(
+                comparison.bins, comparison.thresholds, labels[:n],
+                int(config["hyper"]["max_bins"]), int(config["hyper"]["max_depth"]),
+                trees=int(config["correct"]["rf_trees_checked"]), seed=self.SEED,
+            ))
+            return {
+                "numbers": comparison.numbers, "limits": config["limits"],
+                "violations": violations, "plain": plain, "spans": spans,
+            }
+        finally:
+            patch.undo()
+
+    def test_nothing_is_missing_and_the_wide_path_ran(self, audit):
+        assert audit["violations"] == []
+        assert len(audit["spans"]) == 3
+        for span in audit["spans"]:
+            assert span["meta"]["hist_block_features"] == 80
+            assert span["meta"]["hist_blocks"] == 5
+
+    @pytest.mark.parametrize("number", [
+        "tree_pred_gap", "dt_leaf_gap", "dt_split_gap", "dt_loss_gap",
+        "gb_leaf_gap", "gb_split_gap", "ensemble_loss_gap",
+    ])
+    def test_inside_the_configurations_limit(self, audit, number):
+        assert audit["numbers"][number] <= audit["limits"][number]
+
+    @pytest.mark.parametrize("number", ["rf_leaf_z", "rf_split_gap"])
+    def test_the_forest_reads_as_a_plain_float64_forest_on_these_rows(
+        self, audit, number
+    ):
+        """A forest's leaves and thresholds carry its own bootstrap and
+        its own choice among a node's candidates, which at 3,000 rows
+        reads several times what it reads at the cell's 163,840 (the
+        limits are the chip's): here the two numbers are held to what
+        the reference's own forest reads on the same rows."""
+        assert audit["numbers"][number] <= 1.25 * audit["plain"][number]
