@@ -46,7 +46,7 @@ from learningorchestra_tpu.ml.base import (
     prepare_xy,
     resolve_mesh,
 )
-from learningorchestra_tpu.ml.binning import MAX_BINS, apply_bins, make_thresholds
+from learningorchestra_tpu.ml.binning import MAX_BINS, apply_bins, shared_thresholds
 from learningorchestra_tpu.parallel.mesh import MODEL_AXIS, model_size
 from learningorchestra_tpu.telemetry import tracing as _tracing
 
@@ -701,14 +701,17 @@ def _gbt_forward(X, f0, features_heap, thresholds_heap, leaf_values, step, max_d
 # With ``fit:device_wait`` it is the fit waiting its turn on the device.
 
 
-def _traced_thresholds(X, max_bins: int) -> np.ndarray:
-    """``make_thresholds`` under a ``fit:thresholds`` span: the host
-    quantile pass of a tree fit, told apart from its device time."""
-    rows, features = np.shape(X)
+def _traced_thresholds(X, X_dev, mask, max_bins: int, mesh) -> np.ndarray:
+    """The fit's bin thresholds under a ``fit:thresholds`` span: the
+    device quantile pass over the matrix the fit has just put there
+    (``passes`` 1), or the wait for the pass another fit of the same
+    build runs on the same host matrix (``passes`` 0)."""
     with _tracing.span(
-        "fit:thresholds", rows=rows, features=features, bins=max_bins
+        "fit:thresholds", rows=len(X), features=X_dev.shape[1], bins=max_bins
     ):
-        return make_thresholds(X, max_bins)
+        thresholds, passes = shared_thresholds(X, X_dev, mask, max_bins, mesh)
+        _tracing.annotate(passes=passes)
+        return thresholds
 
 
 class DecisionTreeClassifier:
@@ -724,8 +727,10 @@ class DecisionTreeClassifier:
 
     def fit(self, X: np.ndarray, y: np.ndarray) -> _TreeEnsembleModel:
         num_classes = infer_num_classes(y)
-        thresholds = _traced_thresholds(X, self.max_bins)
         X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
+        thresholds = _traced_thresholds(
+            X, X_dev, mask, self.max_bins, self.mesh
+        )
         with _tracing.span(
             "fit:enqueue", **hist_block_plan(*X_dev.shape, self.max_bins)
         ):
@@ -769,8 +774,10 @@ class RandomForestClassifier:
         num_classes = infer_num_classes(y)
         num_features = np.asarray(X).shape[1]
         subset_k = max(1, int(np.ceil(np.sqrt(num_features))))
-        thresholds = _traced_thresholds(X, self.max_bins)
         X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
+        thresholds = _traced_thresholds(
+            X, X_dev, mask, self.max_bins, self.mesh
+        )
         with _tracing.span(
             "fit:enqueue",
             subset_k=subset_k,
@@ -845,8 +852,10 @@ class GBTClassifier:
     def fit(self, X: np.ndarray, y: np.ndarray) -> GBTModel:
         if infer_num_classes(y) > 2:
             raise ValueError("GBTClassifier supports binary labels only (MLlib contract)")
-        thresholds = _traced_thresholds(X, self.max_bins)
         X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
+        thresholds = _traced_thresholds(
+            X, X_dev, mask, self.max_bins, self.mesh
+        )
         with _tracing.span(
             "fit:enqueue", **hist_block_plan(*X_dev.shape, self.max_bins)
         ):

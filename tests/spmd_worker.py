@@ -46,7 +46,7 @@ def main() -> None:
                 "training_filename": "spmd_train",
                 "test_filename": "spmd_train",
                 "preprocessor_code": PREPROCESSOR,
-                "classificators_list": ["lr"],
+                "classificators_list": ["lr", "dt", "gb"],
             },
         )
         dispatcher.shutdown_workers()

@@ -265,3 +265,130 @@ class TestFusedEvaluatePredict:
         assert _alias_if_equal(equal, testing) is testing
         assert _alias_if_equal(different, testing) is different
         assert _alias_if_equal(None, testing) is None
+
+
+def _threshold_spans(work):
+    """``[(span, path)]`` of the ``fit:thresholds`` spans ``work``
+    leaves in an active trace."""
+    from learningorchestra_tpu.telemetry import tracing
+
+    def walk(span, path):
+        here = path + (span["name"],)
+        yield span, here
+        for child in span["children"]:
+            yield from walk(child, here)
+
+    trace = tracing.Trace(name="build")
+    with tracing.activate(trace):
+        work()
+    return [
+        pair
+        for root in trace.as_dict()["spans"]
+        for pair in walk(root, ())
+        if pair[0]["name"] == "fit:thresholds"
+    ]
+
+
+class TestThresholdsOnceABuild:
+    """The bin thresholds of a build are made once: the first tree fit
+    to have its matrix on the device runs the pass, the other two take
+    its result (``passes`` on each fit's ``fit:thresholds`` span)."""
+
+    TREES = ["dt", "rf", "gb"]
+
+    def _build(self, store, train="titanic_train"):
+        return build_model(
+            store, train, "titanic_test", DOCUMENTED_PREPROCESSOR, self.TREES
+        )
+
+    def test_three_spans_one_pass(self, titanic_store):
+        found = _threshold_spans(lambda: self._build(titanic_store))
+        assert sorted(path[-3:] for _, path in found) == [
+            (f"train:{name}", "phase:fit", "fit:thresholds")
+            for name in sorted(self.TREES)
+        ]
+        assert sum(span["meta"]["passes"] for span, _ in found) == 1
+
+    @pytest.mark.parametrize("second", ["titanic_train", "titanic_test"])
+    def test_every_build_makes_its_own(self, titanic_store, second):
+        def two_builds():
+            self._build(titanic_store)
+            self._build(titanic_store, train=second)
+
+        found = _threshold_spans(two_builds)
+        assert len(found) == 6
+        assert sum(span["meta"]["passes"] for span, _ in found) == 2
+
+    @pytest.mark.parametrize("name", TREES)
+    def test_a_lone_fit_makes_its_own(self, rng, name):
+        from learningorchestra_tpu.ml import binning
+        from learningorchestra_tpu.ml.base import make_classifier
+
+        X = rng.normal(size=(200, 4))
+        y = (X[:, 0] > 0).astype(np.int32)
+        (first,), (again,) = (
+            _threshold_spans(lambda: make_classifier(name).fit(X, y))
+            for _ in range(2)
+        )
+        assert first[0]["meta"]["passes"] == 1
+        # the same array object, still alive: its thresholds are kept,
+        # and go when it goes
+        assert again[0]["meta"]["passes"] == 0
+        assert len(binning._shared) == 1
+        del X
+        assert not binning._shared
+
+    def test_a_failed_pass_fails_every_waiting_fit_and_is_forgotten(
+        self, rng, monkeypatch
+    ):
+        import threading
+
+        from learningorchestra_tpu.ml import binning
+        from learningorchestra_tpu.ml.base import make_classifier
+
+        X = rng.normal(size=(200, 4))
+        y = (X[:, 0] > 0).astype(np.int32)
+        waiting = threading.Semaphore(0)
+
+        class Watched(binning.Future):
+            def result(self, timeout=None):
+                waiting.release()
+                return super().result(timeout)
+
+        passes = []
+
+        def failing_pass(*args):
+            passes.append(args)
+            # not before the other two fits wait for this one
+            assert waiting.acquire(timeout=60) and waiting.acquire(timeout=60)
+            raise RuntimeError("the pass broke")
+
+        errors = {}
+
+        def fit(name):
+            try:
+                make_classifier(name).fit(X, y)
+            except RuntimeError as error:
+                errors[name] = error
+
+        with monkeypatch.context() as patch:
+            patch.setattr(binning, "Future", Watched)
+            patch.setattr(binning, "device_thresholds", failing_pass)
+            threads = [
+                threading.Thread(target=fit, args=(name,)) for name in self.TREES
+            ]
+            for thread in threads:
+                thread.start()
+            for thread in threads:
+                thread.join(timeout=120)
+            assert not any(thread.is_alive() for thread in threads)
+        assert len(passes) == 1
+        assert sorted(errors) == sorted(self.TREES)
+        assert len({id(error) for error in errors.values()}) == 1
+        assert str(errors["dt"]) == "the pass broke"
+        assert not binning._shared
+        # the next build on the same array starts afresh
+        ((span, _),) = _threshold_spans(
+            lambda: make_classifier("dt").fit(X, y)
+        )
+        assert span["meta"]["passes"] == 1

@@ -3,6 +3,7 @@
 import json
 import os
 import time
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +14,7 @@ import sklearn.tree
 
 from learningorchestra_tpu.ml import trees
 from learningorchestra_tpu.ml.base import make_classifier, prepare_xy
+from learningorchestra_tpu.ml import binning
 from learningorchestra_tpu.ml.binning import apply_bins, make_thresholds
 from learningorchestra_tpu.ml.evaluation import accuracy_score
 from learningorchestra_tpu.ml.trees import (
@@ -118,6 +120,130 @@ class TestBinning:
         bins = np.asarray(apply_bins(X, thresholds))
         assert bins.dtype == (np.int8 if max_bins <= 127 else np.int32)
         np.testing.assert_array_equal(bins, _searchsorted_bins(X, thresholds))
+
+
+def _numpy_thresholds(X, max_bins):
+    """What the device pass is held to: numpy's own quantiles of the
+    finite float32 values, in float64, rounded once to float32; a
+    column with none all ``+inf``."""
+    values = np.asarray(X, np.float32).astype(np.float64)
+    values[np.isinf(values)] = np.nan
+    quantiles = np.linspace(0, 1, max_bins + 1)[1:-1]
+    with np.errstate(all="ignore"), warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        thresholds = np.nanquantile(values, quantiles, axis=0).T
+    return np.nan_to_num(thresholds, nan=np.inf).astype(np.float32)
+
+
+def _some_nans(rng):
+    X = rng.normal(size=(257, 5))
+    X[rng.integers(0, 257, 60), rng.integers(0, 5, 60)] = np.nan
+    return X
+
+
+def _zeros_of_both_signs(rng):
+    X = rng.normal(size=(257, 5))
+    X[:, 0] = np.where(rng.random(257) < 0.5, -0.0, 0.0)
+    X[:, 1] = np.where(rng.random(257) < 0.7, -0.0, X[:, 1])
+    X[:, 2] = np.where(rng.random(257) < 0.7, 0.0, -np.abs(X[:, 2]))
+    return X
+
+
+def _denormals(rng):
+    X = rng.normal(size=(257, 5))
+    X[:, :3] *= 1e-42  # float32 holds these as denormals only
+    X[:, 3] = np.where(rng.random(257) < 0.5, 1e-45, -1e-45)
+    return X
+
+
+_THRESHOLD_CASES = {
+    "heavy_ties": lambda rng: rng.integers(0, 4, size=(257, 5)) * 0.7,
+    "constant_column": lambda rng: _with_column(rng, 2.5)[:257],
+    "some_nans": _some_nans,
+    "all_nan_column": lambda rng: _with_column(rng, np.nan)[:257],
+    "one_row": lambda rng: rng.normal(size=(1, 5)),
+    "two_rows": lambda rng: rng.normal(size=(2, 5)),
+    "zeros_of_both_signs": _zeros_of_both_signs,
+    "denormals": _denormals,
+    "float64_values": lambda rng: rng.normal(size=(257, 5)) * 1e3 + 1e-9,
+    "block_of_400_columns": lambda rng: rng.normal(size=(96, 400)),
+}
+
+
+class TestThresholdPass:
+    """``make_thresholds`` / ``device_thresholds``: exact order
+    statistics selected on the device, interpolated as numpy does."""
+
+    @pytest.mark.parametrize("max_bins", [2, 32, 200])
+    @pytest.mark.parametrize("case", sorted(_THRESHOLD_CASES))
+    def test_equals_numpys_quantiles_of_the_float32_values(
+        self, rng, case, max_bins
+    ):
+        X = _THRESHOLD_CASES[case](rng)
+        got = make_thresholds(X, max_bins)
+        assert got.dtype == np.float32
+        assert got.shape == (X.shape[1], max_bins - 1)
+        want = _numpy_thresholds(X, max_bins)
+        # to the bit but for the sign of a zero: where a column holds
+        # both, numpy's answer follows its partition's order
+        assert np.array_equal(got, want)
+        nonzero = want != 0
+        assert np.array_equal(
+            got.view(np.uint32)[nonzero], want.view(np.uint32)[nonzero]
+        )
+        assert not np.signbit(got[~nonzero]).any()
+
+    @pytest.mark.parametrize("max_bins", [32, 200])
+    def test_infinities_are_left_out_and_thresholds_stay_sorted(
+        self, rng, max_bins
+    ):
+        X = rng.normal(size=(640, 4))
+        X[rng.integers(0, 640, 8), 0] = -np.inf
+        X[rng.integers(0, 640, 8), 1] = np.inf
+        X[rng.integers(0, 640, 8), 2] = np.inf
+        X[rng.integers(0, 640, 8), 2] = -np.inf
+        X[:, 3] = np.where(rng.random(640) < 0.5, np.inf, -np.inf)
+        got = make_thresholds(X, max_bins)
+        assert (got[:, 1:] >= got[:, :-1]).all()
+        assert np.isfinite(got[:3]).all() and np.isposinf(got[3]).all()
+        assert np.array_equal(got, _numpy_thresholds(X, max_bins))
+
+    def test_padding_rows_do_not_count(self, rng):
+        X = rng.normal(size=(300, 6)).astype(np.float32)
+        X[rng.integers(0, 300, 20), rng.integers(0, 6, 20)] = np.nan
+        padded = np.concatenate(
+            [X, rng.normal(size=(84, 6)).astype(np.float32) * 1e6]
+        )
+        padded[-3:] = [np.nan, np.inf, -np.inf, 0.0, -1e30, 1e30]
+        mask = np.arange(384) < 300
+        got = binning.device_thresholds(
+            jnp.asarray(padded), jnp.asarray(mask), 32
+        )
+        assert np.array_equal(got, _numpy_thresholds(X, 32))
+
+    @pytest.mark.parametrize("rows", [1000, 1021])
+    def test_same_from_a_host_array_and_a_matrix_sharded_on_the_mesh(
+        self, rng, rows
+    ):
+        X = rng.normal(size=(rows, 7))
+        X[rng.integers(0, rows, 50), rng.integers(0, 7, 50)] = np.nan
+        mesh = DecisionTreeClassifier().mesh
+        assert mesh.devices.size == 8
+        X_dev, _, mask = prepare_xy(X, None, mesh)
+        assert len(X_dev.sharding.device_set) == 8
+        on_mesh = binning.device_thresholds(X_dev, mask, 32)
+        assert np.array_equal(on_mesh, make_thresholds(X, 32))
+        assert np.array_equal(on_mesh, _numpy_thresholds(X, 32))
+
+    def test_the_pass_lowers_to_no_sort_and_no_gather(self, rng):
+        X = jnp.asarray(rng.normal(size=(64, 3)), jnp.float32)
+        ranks = jnp.zeros((3, 62), jnp.int32)
+        text = binning._bin_order_statistics.lower(
+            X, jnp.ones(64, bool), ranks
+        )
+        assert "lo.quantile" in text.as_text(debug_info=True)
+        assert "sort" not in text.as_text()
+        assert "gather" not in text.as_text()
 
 
 class TestDecisionTree:

@@ -185,12 +185,15 @@ def test_spmd_dispatch_through_store_stack(tmp_path):
             assert proc.returncode == 0, f"spmd proc {pid} failed:\n{log}"
 
         # The coordinator (and ONLY the coordinator) wrote predictions.
-        name = "spmd_train_prediction_lr"
-        assert name in remote.list_collections()
-        meta = remote.find_one(name, {"classificator": "lr"})
-        assert meta is not None and float(meta["accuracy"]) > 0.8
-        rows = remote.count(name)
-        assert rows == 121  # 120 predictions + 1 metadata, written once
+        # (dt and gb besides lr: their threshold pass is a reduction
+        # over the row shards of both hosts, read back on each)
+        for classifier in ("lr", "dt", "gb"):
+            name = f"spmd_train_prediction_{classifier}"
+            assert name in remote.list_collections()
+            meta = remote.find_one(name, {"classificator": classifier})
+            assert meta is not None and float(meta["accuracy"]) > 0.8
+            rows = remote.count(name)
+            assert rows == 121  # 120 predictions + 1 metadata, written once
     finally:
         server.stop()
 

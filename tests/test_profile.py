@@ -274,6 +274,18 @@ class TestBuildSpanTree:
         assert span["meta"]["rows"] >= 7
         assert span["meta"]["features"] >= 1
         assert span["meta"]["bins"] == 32
+        assert span["meta"]["passes"] in (0, 1)
+        # the pass reads the matrix the fit has put on the device
+        (fit, _), = _named(build_spans, "phase:fit", f"train:{classifier}")
+        names = [child["name"] for child in fit["children"]]
+        assert names.index("h2d:train") < names.index("fit:thresholds") < (
+            names.index("fit:enqueue")
+        )
+
+    def test_one_threshold_pass_serves_the_three_tree_fits(self, build_spans):
+        found = _named(build_spans, "fit:thresholds")
+        assert len(found) == 3
+        assert sum(span["meta"]["passes"] for span, _ in found) == 1
 
     @pytest.mark.parametrize("classifier", ["lr", "nb"])
     def test_no_threshold_pass_outside_the_trees(
