@@ -18,8 +18,8 @@ the calls a user makes:
 
 at the full width the repo supports: 16 float features, binary label,
 ``lr dt rf gb nb`` at their MLlib defaults, 100,000 train and 100,000
-test rows generated from a seed by bench.py's ``_synthetic`` rule,
-written to CSV and ingested by path (no network).
+test rows generated from a seed (:func:`synthetic`), written to CSV and
+ingested by path (no network).
 
 It FAILS (non-zero, no result line) unless the runner reports the
 expected platform, every request returns its documented body, every
@@ -101,8 +101,10 @@ def log(message: str) -> None:
 
 
 def synthetic(rows: int, seed: int):
-    """bench.py ``_synthetic``, restated (importing bench would be fine
-    today, but nothing guarantees it stays off JAX)."""
+    """The smoke's rows: 16 uniform features in [0, 20), and a label
+    that two of them and uniform noise decide, so every classifier has
+    something to learn and none can be perfect (``ACCURACY_FLOOR`` is
+    set for this rule). Plain numpy: this process must stay off JAX."""
     rng = np.random.default_rng(seed)
     X = rng.random((rows, FEATURES), dtype=np.float32) * 20.0
     y = (

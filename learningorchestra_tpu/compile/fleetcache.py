@@ -7,7 +7,7 @@ the store so the whole fleet shares one warm cache: a runner finishing
 an AOT pass (or any request-path compile, once published) uploads its
 fresh entries; a fresh runner joining the fleet — or restarting after
 the kill -9 chaos drill — pulls them into its local cache dir before
-its first dispatch and replays the bench suite with near-zero compile
+its first dispatch and replays its programs with near-zero compile
 misses. Cache misses fall through to local compile-then-publish, so
 the plane is never load-bearing: an empty or unreachable collection
 just means a cold boot.

@@ -218,7 +218,7 @@ def create_store_app(
     mutations with 503 until ``POST /promote`` flips it — the failover
     the reference delegates to Mongo's replica-set election
     (docker-compose.yml:27-91). ``shm`` overrides the env-derived
-    shared-memory-transport enablement (tests, bench)."""
+    shared-memory-transport enablement (tests)."""
     app = WebApp("store")
     # Shared-memory ring transport (core/shmring.py): enabled when this
     # server's LO_SHM_BYTES > 0 — the runner/stack exports one value to

@@ -359,8 +359,7 @@ def _tsne_landmark(
     # Phase spans: the landmark path is (exact fit on m rows) +
     # (interpolate n rows); each phase ends in a blocking fetch, so
     # these wall-clocks are honest — they are the attribution that
-    # localizes a landmark-path regression to the phase that moved
-    # (bench.py reports them per run; --compare diffs them).
+    # localizes a landmark-path regression to the phase that moved.
     with span("tsne:landmark_fit", rows=m):
         Y_L = _tsne_exact(L, mesh, perplexity, iterations, learning_rate, seed)
     if m == n:

@@ -162,9 +162,9 @@ def account_shm(collection: str, nbytes: int) -> None:
 
 
 def flow_totals() -> dict:
-    """Current byte-flow totals summed over label sets — the snapshot
-    bench.py diffs around a measured section (wire/decode/H2D deltas
-    for the warm product build, per-transport wire benchmarks)."""
+    """Current byte-flow totals summed over label sets — a snapshot
+    to difference around a section of work (wire, decode, H2D and D2H
+    deltas)."""
     metrics = _flow_metrics()
     out = {
         "wire_read_bytes": 0.0,
@@ -352,8 +352,8 @@ def chrome_trace(trace) -> dict:
 def trace_summary(trace) -> dict:
     """Per-phase rollup of a trace: for every span name, occurrence
     count, total seconds, bytes by flow, rows, and rows/second — the
-    plain-JSON answer to "which phase moved" that ``bench.py
-    --compare`` diffs across runs."""
+    plain-JSON answer to "which phase moved" between two runs of a
+    job."""
     if hasattr(trace, "as_dict"):
         trace = trace.as_dict()
     phases: dict[str, dict] = {}

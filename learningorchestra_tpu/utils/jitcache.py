@@ -10,8 +10,8 @@ directory between processes and across restarts is safe.
 
 The directory is placed from OUTSIDE the program: JAX reads
 ``JAX_COMPILATION_CACHE_DIR`` itself, and when it is set this module
-configures no directory at all. Unset, every entry point (runner,
-bench.py, scale.py) uses :data:`DEFAULT_CACHE_DIR` — one fixed path
+configures no directory at all. Unset, every process that calls
+:func:`enable_compile_cache` uses :data:`DEFAULT_CACHE_DIR` — one fixed path
 under the checkout, derived from the package location, never from the
 working directory or the data dir, so two processes started from
 different places still share one cache.

@@ -305,8 +305,8 @@ class WebApp:
             """The job's merged timeline as Chrome trace-event JSON
             (load in Perfetto: one row per thread, byte counter
             tracks); ``?format=summary`` returns the per-phase
-            seconds/bytes/rows-per-s rollup instead — the shape
-            ``bench.py --compare`` diffs (docs/profiling.md)."""
+            seconds/bytes/rows-per-s rollup instead, for an operator
+            to compare two runs by phase (docs/profiling.md)."""
             from learningorchestra_tpu.telemetry import profile as _profile
 
             record = jobs.get(job_name)

@@ -1,15 +1,15 @@
-"""Closed-loop load generator for the serving path.
+"""Closed-loop load generator, a helper of the serving tests.
 
-Closed-loop (each client issues its next request only after the
-previous one answered) is the honest shape for latency measurement: an
-open-loop generator overruns a saturated server and measures its own
-queue. ``bench.py``'s ``serve`` section drives this at 1 / 8 / 64
-concurrent clients and reports p50/p99 latency, predictions/s, and the
-achieved mean batch size — the number that proves micro-batching
-actually coalesced concurrent singles into shared dispatches. The
-``fleet`` section reuses the same loop against real sockets through
-:func:`http_predict_sender` — either spread across replica targets or
-aimed at the router (one target).
+Closed-loop: each client issues its next request only after the
+previous one answered, so exactly ``clients`` requests are in flight —
+the shape a test needs to show that micro-batching coalesced concurrent
+singles into shared dispatches (the achieved mean batch size), and that
+every session is closed on every path out of the loop. The same loop
+runs against real sockets through :func:`http_predict_sender`, either
+spread across replica targets or aimed at the router (one target).
+
+Not part of the package, and no yardstick: the benchmark has its own
+generator (``benchmarks/lib/traffic.py``).
 """
 
 from __future__ import annotations

@@ -37,13 +37,10 @@ from learningorchestra_tpu.ml.checkpoint import (
 from learningorchestra_tpu.serve import ServePlane
 from learningorchestra_tpu.serve import fleet
 from learningorchestra_tpu.serve import router as fleet_router
-from learningorchestra_tpu.serve.loadgen import (
-    http_predict_sender,
-    run_closed_loop,
-)
 from learningorchestra_tpu.services import model_builder
 from learningorchestra_tpu.testing import faults
 from learningorchestra_tpu.utils.web import ServerThread
+from loadgen import http_predict_sender, run_closed_loop
 
 _TESTS_DIR = os.path.dirname(os.path.abspath(__file__))
 _REPO_ROOT = os.path.dirname(_TESTS_DIR)

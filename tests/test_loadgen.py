@@ -1,4 +1,4 @@
-"""The closed-loop load generator (serve/loadgen.py): per-client
+"""The closed-loop load generator (tests/loadgen.py): per-client
 sessions must be closed on EVERY path out of the client loop — error
 paths included — targets parameterize (router mode is one target,
 replica mode several), and a client that dies during setup aborts the
@@ -9,12 +9,8 @@ import threading
 
 import pytest
 
-from learningorchestra_tpu.serve.loadgen import (
-    HttpSession,
-    http_predict_sender,
-    run_closed_loop,
-)
 from learningorchestra_tpu.utils.web import ServerThread, WebApp
+from loadgen import HttpSession, http_predict_sender, run_closed_loop
 
 
 class _TrackingSession:
@@ -45,8 +41,8 @@ class TestRunClosedLoop:
             assert stats[key] >= 0
 
     def test_sessions_closed_when_a_client_errors(self):
-        """The leak the fleet bench would hit: one failing client must
-        not strand ANY session — its own included — half open."""
+        """One failing client must not strand ANY session — its own
+        included — half open."""
         sessions = []
 
         def session_factory(index):

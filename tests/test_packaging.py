@@ -1,8 +1,13 @@
 """Packaging: `pip install .` must provide the reference client's exact
 import surface (reference learning_orchestra_client/setup.py:1-22) —
-the "change only the cluster IP" compatibility contract."""
+the "change only the cluster IP" compatibility contract. And what ships
+beside the code: README and the pages under docs/ cite only files that
+are in the tree."""
 
+import fnmatch
+import functools
 import os
+import re
 import subprocess
 import sys
 
@@ -83,3 +88,69 @@ def test_pip_install_provides_reference_client_surface(tmp_path):
     )
     assert run.returncode == 0, run.stderr
     assert "client surface ok" in run.stdout
+
+
+# --- the documents cite files that exist ---------------------------------
+
+_DOC_PAGES = ["README.md", "deploy/README.md"] + sorted(
+    f"docs/{name}"
+    for name in os.listdir(os.path.join(_REPO_ROOT, "docs"))
+    if name.endswith(".md")
+)
+# a back-quoted token that names a repository file: a path with one of
+# these extensions, optionally `:line`, `:from-to` or `::test` after it
+_FILE_TOKEN = re.compile(
+    r"`(?:\./)?([\w./-]+\.(?:py|sh|json|jsonl|md|yml|toml))"
+    r"(?::\d+(?:-\d+)?|::[\w:\[\]-]+)?`"
+)
+# files of the REFERENCE (hiperbolt/learningOrchestra) the pages cite for
+# parity; they are not in this tree and never were
+_REFERENCE_FILES = {
+    "docker-compose.yml",
+    "learning_orchestra_client/readme.md",
+    "microservices/data_type_handler_image/server.py",
+    "microservices/database_api_image/server.py",
+    "microservices/histogram_image/server.py",
+    "microservices/model_builder_image/server.py",
+    "microservices/pca_image/server.py",
+    "microservices/projection_image/server.py",
+    "microservices/tsne_image/server.py",
+}
+
+
+@functools.cache
+def _tree_files():
+    """Every file of the checkout that git would commit: the walk skips
+    `.git` and the directories `.gitignore` names (a driver's checkout
+    has no `.git` to ask)."""
+    with open(os.path.join(_REPO_ROOT, ".gitignore")) as handle:
+        lines = [line.strip() for line in handle]
+    ignored = [line.rstrip("/") for line in lines if line.endswith("/")]
+    files = set()
+    for folder, dirs, names in os.walk(_REPO_ROOT):
+        dirs[:] = [
+            d
+            for d in dirs
+            if d != ".git"
+            and not any(fnmatch.fnmatch(d, pattern) for pattern in ignored)
+        ]
+        for name in names:
+            path = os.path.relpath(os.path.join(folder, name), _REPO_ROOT)
+            files.add(path.replace(os.sep, "/"))
+    return files
+
+
+@pytest.mark.parametrize("page", _DOC_PAGES)
+def test_documents_cite_files_that_exist(page):
+    files = _tree_files()
+    with open(os.path.join(_REPO_ROOT, page)) as handle:
+        cited = sorted(set(_FILE_TOKEN.findall(handle.read())))
+    stale = [
+        token
+        for token in cited
+        if token not in files
+        # the tail of a path: `ml/trees.py`, `stack.py`
+        and not any(path.endswith("/" + token) for path in files)
+        and token not in _REFERENCE_FILES
+    ]
+    assert not stale, f"{page} cites files that are not in the tree: {stale}"

@@ -1,7 +1,6 @@
 """Flight recorder (telemetry/profile.py): Chrome trace export golden,
 byte-flow attribution, the sampling profiler under concurrency, the
-/profile REST surface, PhaseTimer per-occurrence boundaries, and the
-bench --compare regression gate."""
+/profile REST surface and PhaseTimer per-occurrence boundaries."""
 
 import json
 import os
@@ -10,7 +9,6 @@ import time
 
 import pytest
 
-import bench
 from learningorchestra_tpu.core.devcache import reset_global_devcache
 from learningorchestra_tpu.core.ingest import ingest_csv, write_ingest_metadata
 from learningorchestra_tpu.core.jobs import JobManager
@@ -650,105 +648,3 @@ class TestServeForwardSpans:
         assert meta["bytes"] > 0
         total_rows = sum(s["meta"]["rows"] for s in forwards)
         assert total_rows == 3
-
-
-class TestBenchCompare:
-    PREV = {
-        "metric": "model_builder_5clf_rows_per_sec",
-        "value": 100000.0,
-        "summary": {"suite_s": 2.0},
-        "extra": {
-            "kernels": {"rows_per_sec": 100000.0, "suite_s": 2.0, "rows": 10},
-            "product_path": {
-                "warm_attribution_s": {"phase:fit": 1.0, "store:read": 0.4},
-            },
-            "embeddings": {
-                "scaling": {
-                    "100000": {
-                        "tsne_landmark_s": 1.1,
-                        "tsne_phases_s": {
-                            "landmark_fit": 0.6,
-                            "interpolate": 0.5,
-                        },
-                    }
-                }
-            },
-        },
-    }
-
-    def _current(self, **overrides):
-        import copy
-
-        current = copy.deepcopy(self.PREV)
-        scaling = current["extra"]["embeddings"]["scaling"]["100000"]
-        scaling.update(overrides)
-        return current
-
-    def test_no_regression_exits_zero(self, tmp_path, capsys):
-        prev = tmp_path / "prev.json"
-        cur = tmp_path / "cur.json"
-        prev.write_text(json.dumps(self.PREV))
-        cur.write_text(json.dumps(self._current(tsne_landmark_s=1.05)))
-        rc = bench.cli(["--compare", str(prev), "--current", str(cur)])
-        assert rc == 0
-        assert "no regressions" in capsys.readouterr().out
-
-    def test_regression_flags_the_phase_that_moved_and_exits_nonzero(
-        self, tmp_path, capsys
-    ):
-        prev = tmp_path / "prev.json"
-        cur = tmp_path / "cur.json"
-        prev.write_text(json.dumps(self.PREV))
-        cur.write_text(
-            json.dumps(
-                self._current(
-                    tsne_landmark_s=9.4,
-                    tsne_phases_s={"landmark_fit": 0.6, "interpolate": 8.8},
-                )
-            )
-        )
-        rc = bench.cli(["--compare", str(prev), "--current", str(cur)])
-        assert rc == 1
-        out = capsys.readouterr().out
-        assert "REGRESSIONS" in out
-        # the gate names the PHASE that moved, not just the headline
-        assert "tsne_phases_s.interpolate" in out
-        assert "tsne_phases_s.landmark_fit" not in out.split(
-            "REGRESSIONS"
-        )[1]
-
-    def test_throughput_drop_is_a_regression(self):
-        current = self._current()
-        current["extra"]["kernels"]["rows_per_sec"] = 60000.0
-        result = bench.compare_benchmarks(self.PREV, current)
-        assert any(
-            r["metric"] == "extra.kernels.rows_per_sec"
-            for r in result["regressions"]
-        )
-
-    def test_seconds_noise_floor_and_fact_keys_never_gate(self):
-        # 11ms -> 20ms "doubles" but is under the absolute floor
-        prev = {"extra": {"kernels": {"suite_s": 0.011, "rows": 10}}}
-        cur = {"extra": {"kernels": {"suite_s": 0.020, "rows": 99}}}
-        assert not bench.compare_benchmarks(prev, cur)["regressions"]
-
-    def test_noise_floor_scales_with_ms_unit(self):
-        # the same physical jitter expressed in ms must not gate either
-        prev = {"serve": {"c64": {"p50_ms": 11.0}}}
-        cur = {"serve": {"c64": {"p50_ms": 22.0}}}
-        assert not bench.compare_benchmarks(prev, cur)["regressions"]
-        # a real latency regression past the 50ms floor still fails
-        prev = {"serve": {"c64": {"p99_ms": 40.0}}}
-        cur = {"serve": {"c64": {"p99_ms": 120.0}}}
-        assert bench.compare_benchmarks(prev, cur)["regressions"]
-
-    def test_loads_archived_driver_capture(self):
-        # the driver's {"tail": <stdout>} shape, synthetic values
-        record = bench.load_bench_record(
-            os.path.join(
-                os.path.dirname(__file__), "data", "bench_driver_capture.json"
-            )
-        )
-        assert record["metric"] == "model_builder_5clf_rows_per_sec"
-        flat = bench.flatten_metrics(record)
-        assert "value" in flat

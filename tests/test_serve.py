@@ -695,7 +695,7 @@ class TestLoadGenerator:
         return X, plane, app
 
     def _run(self, X, plane, app, clients, requests_per_client):
-        from learningorchestra_tpu.serve.loadgen import run_closed_loop
+        from loadgen import run_closed_loop
 
         handles = [app.test_client() for _ in range(clients)]
         row = X[:1].tolist()
@@ -725,8 +725,8 @@ class TestLoadGenerator:
 
     @pytest.mark.slow
     def test_concurrency_sweep_batches(self, data, tmp_path):
-        """The bench section's shape at full size: 64 concurrent
-        closed-loop clients must achieve mean batch size > 1."""
+        """At full size: 64 concurrent closed-loop clients must achieve
+        mean batch size > 1."""
         X, plane, app = self._serve_app(
             data, tmp_path, window_s=0.001, max_batch=64, inbox_cap=1024
         )
@@ -745,7 +745,7 @@ class TestLoadGenerator:
         """Per-client sessions are handed to send and closed in
         ``finally`` — including when a send raises mid-loop (the leak
         path: a failed client used to abandon its connection)."""
-        from learningorchestra_tpu.serve.loadgen import run_closed_loop
+        from loadgen import run_closed_loop
 
         class Session:
             def __init__(self, index):
@@ -788,7 +788,7 @@ class TestLoadGenerator:
         """A client dying BEFORE the start barrier must abort it (no
         deadlock) and surface the root cause, not the collateral
         BrokenBarrierError the other clients see."""
-        from learningorchestra_tpu.serve.loadgen import run_closed_loop
+        from loadgen import run_closed_loop
 
         opened = []
 
@@ -818,7 +818,7 @@ class TestLoadGenerator:
         """Client i's session targets targets[i % len(targets)] — one
         target is router mode, several spread clients across replicas.
         No hardcoded single target anywhere."""
-        from learningorchestra_tpu.serve.loadgen import (
+        from loadgen import (
             http_predict_sender,
         )
 
