@@ -14,7 +14,9 @@ emulation is explicitly out of scope.
 Design: eager numpy columns (numeric → float64 with NaN, strings →
 object with None, assembled vectors → 2-D float64), expression trees
 evaluated per-frame. Preprocessing is host work; the device path starts
-when the assembled ``features`` matrix reaches an estimator.
+when the assembled ``features`` matrix reaches an estimator. A frame's
+assembly is remembered on the frame (frames are immutable, so the same
+assembler settings on the same frame can only give the same matrix).
 """
 
 from learningorchestra_tpu.frame.dataframe import DataFrame

@@ -7,6 +7,7 @@ feature assembly feeding the classifiers.
 
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 import numpy as np
@@ -85,9 +86,73 @@ class StringIndexer:
         )
 
 
+# One tile of the matrix and the staging block it is filled from are
+# each at most _TILE_BYTES, so both stay in a core's cache while a tile
+# is written. A tile is at most _TILE_MAX_COLUMNS wide and as tall as
+# the bytes allow: every copy into the staging block is then one long
+# contiguous run, made by numpy outside the interpreter lock, which is
+# what lets _FILL_THREADS fill side by side.
+_TILE_BYTES = 1 << 20
+_TILE_MAX_COLUMNS = 32
+_FILL_THREADS = 4
+
+
+def _tile_shape(rows: int, columns: int) -> tuple[int, int]:
+    """``(tile_rows, tile_columns)`` for a ``(rows, columns)`` float64
+    matrix, from the shape alone: a narrow matrix is filled in row
+    tiles that span every column, a wide one in groups of
+    ``_TILE_MAX_COLUMNS`` columns with as many rows as the byte budget
+    leaves."""
+    tile_columns = max(1, min(columns, _TILE_MAX_COLUMNS))
+    tile_rows = max(1, min(rows, _TILE_BYTES // (8 * tile_columns)))
+    return tile_rows, tile_columns
+
+
+def _fill(sources: list[np.ndarray], rows: int) -> np.ndarray:
+    """The C-ordered float64 ``(rows, len(sources))`` matrix whose
+    column ``j`` is ``sources[j]``, written one tile at a time: each
+    source is read in contiguous runs into a small staging block, and
+    the block is written out transposed, so no store crosses the whole
+    matrix at a stride of a row. A matrix of many row tiles is shared
+    out among ``_FILL_THREADS`` threads by rows: most of what is left
+    of the time is the first touch of the matrix's fresh pages, which
+    threads take side by side."""
+    matrix = np.empty((rows, len(sources)), dtype=np.float64)
+    tile_rows, tile_columns = _tile_shape(*matrix.shape)
+
+    def fill_rows(first_row: int, last_row: int) -> None:
+        staging = np.empty((tile_columns, tile_rows), dtype=np.float64)
+        for start in range(first_row, last_row, tile_rows):
+            stop = min(last_row, start + tile_rows)
+            for first in range(0, len(sources), tile_columns):
+                group = sources[first:first + tile_columns]
+                block = staging[:len(group), :stop - start]
+                for i, source in enumerate(group):
+                    block[i] = source[start:stop]
+                matrix[start:stop, first:first + len(group)] = block.T
+
+    tiles = -(-rows // tile_rows)
+    threads = min(_FILL_THREADS, tiles // _FILL_THREADS)  # several tiles each
+    if threads <= 1:
+        fill_rows(0, rows)
+        return matrix
+    share = -(-tiles // threads) * tile_rows
+    with ThreadPoolExecutor(threads) as pool:
+        shares = [
+            pool.submit(fill_rows, first_row, min(rows, first_row + share))
+            for first_row in range(0, rows, share)
+        ]
+        for done in shares:
+            done.result()
+    return matrix
+
+
 class VectorAssembler:
-    """Stacks numeric columns into one 2-D ``outputCol`` matrix — the
-    bridge from the host dataframe to the device design matrix."""
+    """Assembles numeric columns into one 2-D ``outputCol`` matrix — the
+    bridge from the host dataframe to the device design matrix. A
+    frame's assembly is remembered on the frame, which is sound because
+    frames are immutable: the same assembler settings on the same frame
+    return the same result frame."""
 
     def __init__(
         self,
@@ -107,47 +172,57 @@ class VectorAssembler:
 
     def transform(self, df: DataFrame) -> DataFrame:
         with _tracing.span("frame:assemble"):
-            out = self._transform(df)
+            memo = df.__dict__.setdefault("_assembled", {})
+            key = (tuple(self.inputCols), self.outputCol, self.handleInvalid)
+            out = memo.get(key)
+            passes = 0
+            if out is None:
+                out = memo[key] = self._transform(df)
+                passes = 1
             matrix = out._column(self.outputCol)
+            tile_rows, tile_columns = _tile_shape(*matrix.shape)
             _tracing.annotate(
                 rows=matrix.shape[0],
                 features=matrix.shape[1],
                 bytes=int(matrix.nbytes),
+                passes=passes,
+                tile_rows=tile_rows,
+                tile_columns=tile_columns,
             )
             return out
 
     def _transform(self, df: DataFrame) -> DataFrame:
-        stacked = []
+        sources = []
         for name in self.inputCols:
             column = df._column(name)
             if column.ndim == 2:
-                stacked.append(column)
-                continue
-            if column.dtype == object:
+                sources.extend(column.T)
+            elif column.dtype == object:
                 nulls = _is_null_array(column)
-                numeric = np.array(
-                    [np.nan if null else float(v) for v, null in zip(column, nulls)],
-                    dtype=np.float64,
+                sources.append(
+                    np.array(
+                        [
+                            np.nan if null else float(v)
+                            for v, null in zip(column, nulls)
+                        ],
+                        dtype=np.float64,
+                    )
                 )
             else:
-                numeric = column.astype(np.float64)
-            stacked.append(numeric[:, None])
-        matrix = (
-            np.concatenate(stacked, axis=1)
-            if stacked
-            else np.zeros((df.count(), 0))
-        )
-        invalid = np.isnan(matrix).any(axis=1)
-        if invalid.any():
-            if self.handleInvalid == ERROR:
-                raise ValueError(
-                    "VectorAssembler: null/NaN in input columns "
-                    "(handleInvalid='error')"
-                )
-            if self.handleInvalid == SKIP:
-                keep = ~invalid
-                return df._take(keep).withColumn(self.outputCol, matrix[keep])
-        return df.withColumn(self.outputCol, matrix)
+                sources.append(column)
+        with_nan = [source for source in sources if np.isnan(source).any()]
+        if with_nan and self.handleInvalid == ERROR:
+            raise ValueError(
+                "VectorAssembler: null/NaN in input columns "
+                "(handleInvalid='error')"
+            )
+        if with_nan and self.handleInvalid == SKIP:
+            keep = np.ones(df.count(), dtype=bool)
+            for source in with_nan:
+                keep &= ~np.isnan(source)
+            df = df._take(keep)
+            sources = [source[keep] for source in sources]
+        return df.withColumn(self.outputCol, _fill(sources, df.count()))
 
 
 class Pipeline:

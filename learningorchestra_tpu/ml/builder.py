@@ -459,12 +459,12 @@ def _alias_if_equal(
 ) -> Optional[DataFrame]:
     """The documented preprocessor evaluates on the test frame
     (reference docs/model_builder.md: ``features_evaluation =
-    assembler.transform(testing_df)``) but builds it as a SEPARATE
-    transform, so the frames are distinct objects with identical
-    content. Aliasing them lets the per-frame device cache share one
-    host→device transfer and evaluate_predict share one forward pass.
-    The content check is a host-side array compare — microseconds next
-    to a transfer."""
+    assembler.transform(testing_df)``). The assembler remembers a
+    frame's assembly, so that second transform returns the test frame
+    itself and the identity test answers. A preprocessor that reaches
+    an equal evaluation frame another way gets the content compare:
+    aliasing the two lets the per-frame device cache share one
+    host→device transfer and evaluate_predict share one forward pass."""
     if features_evaluation is None or features_evaluation is features_testing:
         return features_evaluation
     try:

@@ -122,6 +122,224 @@ class TestFeatureStages:
             assembler.transform(df)
 
 
+def _reference_assembly(columns):
+    """The plain reference of ISSUE 33: every input a float64 column
+    block, the blocks joined side by side."""
+    blocks = []
+    for column in columns:
+        if column.dtype == object:
+            column = np.array(
+                [np.nan if v is None else float(v) for v in column]
+            )
+        if column.ndim == 1:
+            column = column.astype(np.float64)[:, None]
+        blocks.append(column)
+    return np.concatenate(blocks, axis=1)
+
+
+def _numeric_columns(rows, columns, seed=0, read_only=False):
+    table = np.random.default_rng(seed).standard_normal((columns, rows))
+    if read_only:
+        # as Column.to_float64 hands a column over: a contiguous view
+        # of a buffer it does not own, not writeable
+        table.flags.writeable = False
+    return {f"c{j}": table[j] for j in range(columns)}
+
+
+def _object_columns():
+    return {
+        "a": np.array([1.0, 2.0, 3.0, 4.0]),
+        "o": np.array(["1.5", None, "3", "4e2"], dtype=object),
+        "b": np.array([5.0, 6.0, 7.0, 8.0]),
+    }
+
+
+def _block_columns():
+    rng = np.random.default_rng(3)
+    return {
+        "a": rng.standard_normal(7),
+        "block": rng.standard_normal((7, 3)),
+        "b": rng.standard_normal(7),
+    }
+
+
+def _nan_columns():
+    columns = _numeric_columns(11, 4, seed=5)
+    columns["c2"] = columns["c2"].copy()
+    columns["c2"][[3, 8]] = np.nan
+    return columns
+
+
+# name: (the input columns, handleInvalid)
+ASSEMBLY_CASES = {
+    "1x1": (lambda: _numeric_columns(1, 1), "error"),
+    "5x3": (lambda: _numeric_columns(5, 3), "error"),
+    "tall_200003x28": (lambda: _numeric_columns(200_003, 28), "error"),
+    "wide_257x2000": (lambda: _numeric_columns(257, 2000), "error"),
+    "read_only_views": (
+        lambda: _numeric_columns(5_000, 7, read_only=True),
+        "error",
+    ),
+    "object_none_and_numeric_strings": (_object_columns, "keep"),
+    "block_between_vectors": (_block_columns, "error"),
+    "nan_keep": (_nan_columns, "keep"),
+    "nan_skip": (_nan_columns, "skip"),
+}
+
+
+class TestAssemblyFill:
+    """The tiled fill against the plain reference, bit for bit."""
+
+    @pytest.mark.parametrize("case", list(ASSEMBLY_CASES))
+    def test_matrix_is_the_reference_matrix(self, case):
+        make, handle_invalid = ASSEMBLY_CASES[case]
+        columns = make()
+        expected = _reference_assembly(columns.values())
+        rows = len(expected)
+        kept = np.ones(rows, dtype=bool)
+        if handle_invalid == "skip":
+            kept = ~np.isnan(expected).any(axis=1)
+            assert 0 < kept.sum() < rows
+        frame = DataFrame({"row": np.arange(rows, dtype=np.float64), **columns})
+        out = VectorAssembler(
+            inputCols=list(columns), handleInvalid=handle_invalid
+        ).transform(frame)
+        matrix = out.feature_matrix()
+        assert matrix.dtype == np.float64
+        assert matrix.flags.c_contiguous
+        assert matrix.shape == expected[kept].shape
+        assert np.array_equal(matrix, expected[kept], equal_nan=True)
+        # the frame's other columns are taken alike
+        assert np.array_equal(out._column("row"), np.flatnonzero(kept))
+        for name, column in columns.items():
+            taken = out._column(name)
+            if column.dtype == object:
+                assert taken.tolist() == column[kept].tolist()
+            else:
+                assert np.array_equal(taken, column[kept], equal_nan=True)
+
+    def test_zero_input_columns(self):
+        frame = DataFrame({"row": np.arange(3.0)})
+        matrix = VectorAssembler(inputCols=[]).transform(frame).feature_matrix()
+        assert matrix.dtype == np.float64 and matrix.shape == (3, 0)
+
+    def test_nan_under_error_raises_the_same_message(self):
+        frame = DataFrame(_nan_columns())
+        with pytest.raises(ValueError) as raised:
+            VectorAssembler(inputCols=list(frame.columns)).transform(frame)
+        assert str(raised.value) == (
+            "VectorAssembler: null/NaN in input columns "
+            "(handleInvalid='error')"
+        )
+
+    def test_tile_follows_the_shape(self):
+        from learningorchestra_tpu.frame.feature import _tile_shape
+
+        # few long columns: row tiles across every column; the tall
+        # case above takes several and a ragged last one
+        tile_rows, tile_columns = _tile_shape(8_388_608, 28)
+        assert tile_columns == 28 and 1_024 <= tile_rows <= 8_192
+        assert 200_003 > 3 * tile_rows and 200_003 % tile_rows
+        # many columns: groups of them; the wide case above takes
+        # several and a ragged last one
+        tile_rows, tile_columns = _tile_shape(163_840, 2_000)
+        assert tile_rows * tile_columns * 8 <= 1 << 20
+        assert 2_000 > 3 * tile_columns and 2_000 % tile_columns
+        assert _tile_shape(257, 2_000) == (257, tile_columns)
+        assert _tile_shape(300, 4) == (300, 4)  # a small frame: one tile
+        assert _tile_shape(0, 0) == (1, 1)
+
+
+def _assemble_spans(trace):
+    return [
+        s["meta"] for s in trace.as_dict()["spans"] if s["name"] == "frame:assemble"
+    ]
+
+
+class TestAssemblyMemo:
+    """A frame's assembly is remembered on the frame (frames are
+    immutable), under the assembler's settings."""
+
+    def _frame(self):
+        return DataFrame(_nan_columns())
+
+    def test_same_settings_same_frame_answer_from_the_memo(self):
+        from learningorchestra_tpu.telemetry import tracing
+
+        frame = self._frame()
+        trace = tracing.Trace(name="memo")
+        with tracing.activate(trace):
+            first = VectorAssembler(inputCols=["c0", "c1"]).transform(frame)
+            second = VectorAssembler(inputCols=["c0", "c1"]).transform(frame)
+        assert second is first
+        one, two = _assemble_spans(trace)
+        assert (one["passes"], two["passes"]) == (1, 0)
+        for meta in (one, two):
+            assert (meta["rows"], meta["features"], meta["bytes"]) == (11, 2, 176)
+            assert (meta["tile_rows"], meta["tile_columns"]) == (11, 2)
+
+    @pytest.mark.parametrize(
+        "other",
+        ["inputCols", "outputCol", "handleInvalid", "frame"],
+    )
+    def test_anything_else_fills_anew(self, other):
+        from learningorchestra_tpu.telemetry import tracing
+
+        frame = self._frame()
+        settings = dict(
+            inputCols=["c0", "c2"], outputCol="features", handleInvalid="keep"
+        )
+        changed = dict(settings)
+        target = frame
+        if other == "inputCols":
+            changed["inputCols"] = ["c2", "c0"]
+        elif other == "outputCol":
+            changed["outputCol"] = "vector"
+        elif other == "handleInvalid":
+            changed["handleInvalid"] = "skip"
+        else:
+            target = self._frame()
+        trace = tracing.Trace(name="anew")
+        with tracing.activate(trace):
+            first = VectorAssembler(**settings).transform(frame)
+            second = VectorAssembler(**changed).transform(target)
+        assert second is not first
+        assert [meta["passes"] for meta in _assemble_spans(trace)] == [1, 1]
+        assert second.count() == (9 if other == "handleInvalid" else 11)
+
+    def test_documented_preprocessor_evaluates_on_the_test_frame_itself(
+        self, monkeypatch
+    ):
+        from learningorchestra_tpu.ml.builder import _alias_if_equal
+
+        code = (
+            "from pyspark.ml.feature import VectorAssembler\n"
+            "feature_cols = [c for c in training_df.schema.names if c != 'label']\n"
+            "assembler = VectorAssembler(inputCols=feature_cols, outputCol='features')\n"
+            "features_training = assembler.transform(training_df)\n"
+            "features_testing = assembler.transform(testing_df)\n"
+            "features_evaluation = assembler.transform(testing_df)\n"
+        )
+        training = DataFrame(
+            {"label": np.array([0.0, 1.0, 1.0]), **_numeric_columns(3, 2)}
+        )
+        testing = DataFrame(
+            {"label": np.array([1.0, 0.0]), **_numeric_columns(2, 2, seed=1)}
+        )
+        out = run_preprocessor(code, training, testing)
+        assert out["features_evaluation"] is out["features_testing"]
+        assert out["features_training"] is not out["features_testing"]
+
+        def unread(self, features_col="features"):
+            raise AssertionError("the identity test must answer first")
+
+        monkeypatch.setattr(DataFrame, "feature_matrix", unread)
+        assert (
+            _alias_if_equal(out["features_evaluation"], out["features_testing"])
+            is out["features_testing"]
+        )
+
+
 # The documented preprocessor example, verbatim from the reference's
 # docs/model_builder.md (the compatibility contract for user code).
 DOCUMENTED_PREPROCESSOR = r"""
