@@ -140,6 +140,18 @@ def _key_values(keys: np.ndarray) -> np.ndarray:
     return np.where(keys >= _SIGN, keys ^ _SIGN, ~keys).view(np.float32)
 
 
+def distinct_thresholds(thresholds: np.ndarray) -> int:
+    """Over the columns, how many distinct finite thresholds a
+    ``(features, max_bins - 1)`` table holds: a column's quantiles rise
+    along its row, so a threshold counts where it differs from the one
+    before. ``features x (max_bins - 1)`` on continuous columns, one or
+    two a column on 0/1 indicators: with it a column's values reach at
+    most that many bins and one more."""
+    new = np.ones(thresholds.shape, bool)
+    new[:, 1:] = thresholds[:, 1:] != thresholds[:, :-1]
+    return int((new & np.isfinite(thresholds)).sum())
+
+
 def make_thresholds(X: np.ndarray, max_bins: int = MAX_BINS) -> np.ndarray:
     """Per-feature quantile thresholds of a host matrix, float32, shape
     ``(features, max_bins - 1)``: :func:`device_thresholds` of its

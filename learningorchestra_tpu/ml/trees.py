@@ -46,7 +46,12 @@ from learningorchestra_tpu.ml.base import (
     prepare_xy,
     resolve_mesh,
 )
-from learningorchestra_tpu.ml.binning import MAX_BINS, apply_bins, shared_thresholds
+from learningorchestra_tpu.ml.binning import (
+    MAX_BINS,
+    apply_bins,
+    distinct_thresholds,
+    shared_thresholds,
+)
 from learningorchestra_tpu.parallel.mesh import MODEL_AXIS, model_size
 from learningorchestra_tpu.telemetry import tracing as _tracing
 
@@ -705,12 +710,16 @@ def _traced_thresholds(X, X_dev, mask, max_bins: int, mesh) -> np.ndarray:
     """The fit's bin thresholds under a ``fit:thresholds`` span: the
     device quantile pass over the matrix the fit has just put there
     (``passes`` 1), or the wait for the pass another fit of the same
-    build runs on the same host matrix (``passes`` 0)."""
+    build runs on the same host matrix (``passes`` 0). The span of the
+    fit that ran the pass also says how many of its ``features x
+    (max_bins - 1)`` thresholds differ (``distinct_thresholds``)."""
     with _tracing.span(
         "fit:thresholds", rows=len(X), features=X_dev.shape[1], bins=max_bins
     ):
         thresholds, passes = shared_thresholds(X, X_dev, mask, max_bins, mesh)
         _tracing.annotate(passes=passes)
+        if passes:
+            _tracing.annotate(distinct_thresholds=distinct_thresholds(thresholds))
         return thresholds
 
 

@@ -156,7 +156,26 @@ def _denormals(rng):
     return X
 
 
+def _indicator_columns(rng=None):
+    """What a one-hot coded table holds: 0/1 columns whose level has
+    1/1000, exactly 1/32, 1/2 and 31/32 of 32,000 rows, a constant
+    column and a three-valued one, the rows in an order of their own a
+    column. At 1/32 and 31/32 one quantile's rank falls between the
+    last 0 and the first 1 (0.03125 and 0.96875), at 1/2 the median
+    does (0.5): a third threshold, and still two bins."""
+    rng = np.random.default_rng(700)
+    rows = 32000
+    columns = [
+        rng.permutation((np.arange(rows) < ones).astype(np.float64))
+        for ones in (rows // 1000, rows // 32, rows // 2, rows * 31 // 32)
+    ]
+    columns.append(np.ones(rows))
+    columns.append(rng.permutation(np.arange(rows) % 3).astype(np.float64))
+    return np.stack(columns, axis=1)
+
+
 _THRESHOLD_CASES = {
+    "indicator_columns": _indicator_columns,
     "heavy_ties": lambda rng: rng.integers(0, 4, size=(257, 5)) * 0.7,
     "constant_column": lambda rng: _with_column(rng, 2.5)[:257],
     "some_nans": _some_nans,
@@ -234,6 +253,38 @@ class TestThresholdPass:
         on_mesh = binning.device_thresholds(X_dev, mask, 32)
         assert np.array_equal(on_mesh, make_thresholds(X, 32))
         assert np.array_equal(on_mesh, _numpy_thresholds(X, 32))
+
+    @pytest.mark.parametrize(
+        "column, share, distinct, values",
+        [
+            (0, "1/1000", 1, 2), (1, "1/32", 2, 2), (2, "1/2", 3, 2),
+            (3, "31/32", 2, 2), (4, "constant", 1, 1), (5, "three-valued", 3, 3),
+        ],
+    )
+    def test_indicator_columns_take_few_thresholds_and_few_bins(
+        self, column, share, distinct, values
+    ):
+        X = _indicator_columns()
+        thresholds = make_thresholds(X, 32)
+        assert np.array_equal(thresholds, _numpy_thresholds(X, 32))
+        mine = thresholds[column : column + 1]
+        assert binning.distinct_thresholds(mine) == len(np.unique(mine)) == distinct
+        # the bins against a count in numpy: thresholds under the value
+        bins = np.asarray(apply_bins(jnp.asarray(X, jnp.float32), jnp.asarray(thresholds)))
+        want = (mine < X[:, column : column + 1].astype(np.float32)).sum(axis=1)
+        assert np.array_equal(bins[:, column], want)
+        assert len(np.unique(bins[:, column])) == values
+        # the whole table's count is the columns' summed
+        assert binning.distinct_thresholds(thresholds) == sum(
+            len(np.unique(row)) for row in thresholds
+        )
+
+    def test_distinct_thresholds_leaves_out_what_is_not_finite(self):
+        table = np.array(
+            [[0.0, 0.0, 1.0], [np.inf, np.inf, np.inf], [1.0, 2.0, 3.0], [5.0, 5.0, 5.0]],
+            np.float32,
+        )
+        assert binning.distinct_thresholds(table) == 2 + 0 + 3 + 1
 
     def test_the_pass_lowers_to_no_sort_and_no_gather(self, rng):
         X = jnp.asarray(rng.normal(size=(64, 3)), jnp.float32)
@@ -577,9 +628,9 @@ class TestBlockedHistograms:
             np.testing.assert_allclose(blocked, whole, atol=1e-4, rtol=0)
 
 
-def _enqueue_spans(work):
-    """The ``fit:enqueue`` spans ``work`` (a fit, a whole build) leaves
-    in an active trace, at any depth."""
+def _spans_of(work):
+    """Every span ``work`` (a fit, a whole build) leaves in an active
+    trace, at any depth."""
     from learningorchestra_tpu.telemetry import tracing
 
     trace = tracing.Trace(name="fit")
@@ -589,9 +640,12 @@ def _enqueue_spans(work):
     while pending:
         span = pending.pop()
         pending += span["children"]
-        if span["name"] == "fit:enqueue":
-            found.append(span)
+        found.append(span)
     return found
+
+
+def _enqueue_spans(work):
+    return [span for span in _spans_of(work) if span["name"] == "fit:enqueue"]
 
 
 class TestHistBlockPlan:
@@ -603,6 +657,7 @@ class TestHistBlockPlan:
         [
             (163840, 2000, 80, 25, 1677721600),    # epsilon-500k
             (8388608, 28, 1, 28, 1073741824),      # higgs-11m
+            (524288, 700, 28, 25, 1879048192),     # expo-onehot-700
         ],
     )
     def test_figures_at_the_cells_shapes(
@@ -674,6 +729,87 @@ class TestForestFeatureSubsets:
         assert not allowed[np.arange(nodes), np.asarray(free)].all()
 
 
+BENCH = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks"
+)
+
+
+class _Harness:
+    """The two calls the benchmark's comparison makes on a system, on a
+    store of this test's own."""
+
+    def __init__(self):
+        from lib import system
+
+        from learningorchestra_tpu.core.store import InMemoryStore
+
+        self.store = InMemoryStore()
+        self._system = system.System
+
+    def stored(self, collection, fields):
+        return self._system.stored(self, collection, fields)
+
+    def write_dataset(self, name, columns, labels, fields):
+        self._system.write_dataset(self, name, columns, labels, fields)
+
+
+def _audited_build(cell, dataset, train, test, seed, block_cap, models_dir):
+    """One build of ``cell``'s classifiers through ``build_model`` -
+    store, documented preprocessor, write-back, checkpoints - on
+    ``train`` + ``test`` rows from the cell's maker with ``dataset``'s
+    keys changed, the level histograms cut into blocks by ``block_cap``
+    (padded rows times the columns of a block), and what was published
+    and stored held against the benchmark's plain float64 reference."""
+    patch = pytest.MonkeyPatch()
+    patch.syspath_prepend(BENCH)
+    patch.setenv("LO_RESUME", "0")
+    patch.setattr(trees, "_HIST_BLOCK_ROW_FEATURES", block_cap)
+    try:
+        from lib import cells, correct, system
+
+        from learningorchestra_tpu.ml.builder import build_model
+
+        config = json.loads(json.dumps(cells.Cell(cell).config))
+        config["dataset"].update(dataset)
+        ref = cells.load_module("reference", "classifiers")
+        maker = cells.load_module("datasets", config["dataset"]["maker"])
+        columns, labels, fields = maker.make(config["dataset"], seed, train + test)
+        harness, n = _Harness(), train
+        harness.write_dataset("cell_train", [c[:n] for c in columns], labels[:n], fields)
+        harness.write_dataset("cell_test", [c[n:] for c in columns], labels[n:], fields)
+        posted = time.time()
+        spans = _spans_of(lambda: build_model(
+            harness.store, "cell_train", "cell_test", system.PREPROCESSOR,
+            config["classifiers"], models_dir=models_dir,
+        ))
+        X = ref.as_matrix(columns, dtype=np.float32)
+        comparison = correct.Comparison(
+            ref, config, seed, X[:n], labels[:n], X[n:], labels[n:]
+        )
+        outputs, violations = correct.read_build(
+            harness, ref, config, {"test": "cell_test"}, models_dir, posted,
+            labels[n:], comparison.sample,
+        )
+        comparison.compare(outputs)
+        # the forest's two sampling numbers, for a plain float64
+        # forest grown on the same rows by the reference itself
+        plain = comparison.audit_rf(ref.grow_forest(
+            comparison.bins, comparison.thresholds, labels[:n],
+            int(config["hyper"]["max_bins"]), int(config["hyper"]["max_depth"]),
+            trees=int(config["correct"]["rf_trees_checked"]), seed=seed,
+        ))
+        return {
+            "numbers": comparison.numbers, "limits": config["limits"],
+            "violations": violations, "plain": plain, "train": X[:n],
+            "spans": {
+                kind: [span for span in spans if span["name"] == f"fit:{kind}"]
+                for kind in ("enqueue", "thresholds")
+            },
+        }
+    finally:
+        patch.undo()
+
+
 class TestWideBuildAgainstThePlainReference:
     """``dt`` + ``rf`` + ``gb`` built through ``build_model`` - store,
     documented preprocessor, write-back, checkpoints - on a wide dense
@@ -683,74 +819,19 @@ class TestWideBuildAgainstThePlainReference:
     float64 reference inside the limits of ``epsilon-500k`` (PERF.md §4;
     the cell itself runs 163,840 + 100,000 x 2,000 on the chip)."""
 
-    TRAIN, TEST, FEATURES, SEED = 3000, 1000, 400, 2147483777
-    BENCH = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks"
-    )
-
     @pytest.fixture(scope="class")
     def audit(self, tmp_path_factory):
-        patch = pytest.MonkeyPatch()
-        patch.syspath_prepend(self.BENCH)
-        patch.setenv("LO_RESUME", "0")
         # the wide path: several blocks of several columns each
         # (3,000 rows pad to the 3,072 bucket)
-        patch.setattr(trees, "_HIST_BLOCK_ROW_FEATURES", 3072 * 80)
-        try:
-            from lib import cells, correct, system
-
-            from learningorchestra_tpu.core.store import InMemoryStore
-            from learningorchestra_tpu.ml.builder import build_model
-
-            config = json.loads(json.dumps(cells.Cell("epsilon-500k.build3").config))
-            config["dataset"]["features"] = self.FEATURES
-            ref = cells.load_module("reference", "classifiers")
-            maker = cells.load_module("datasets", config["dataset"]["maker"])
-            columns, labels, fields = maker.make(
-                config["dataset"], self.SEED, self.TRAIN + self.TEST
-            )
-
-            class Harness:  # the two calls the comparison makes on a system
-                store = InMemoryStore()
-                stored = system.System.stored
-                write_dataset = system.System.write_dataset
-
-            harness, n = Harness(), self.TRAIN
-            harness.write_dataset("wide_train", [c[:n] for c in columns], labels[:n], fields)
-            harness.write_dataset("wide_test", [c[n:] for c in columns], labels[n:], fields)
-            models_dir = str(tmp_path_factory.mktemp("models"))
-            posted = time.time()
-            spans = _enqueue_spans(lambda: build_model(
-                harness.store, "wide_train", "wide_test", system.PREPROCESSOR,
-                config["classifiers"], models_dir=models_dir,
-            ))
-            X = ref.as_matrix(columns, dtype=np.float32)
-            comparison = correct.Comparison(
-                ref, config, self.SEED, X[:n], labels[:n], X[n:], labels[n:]
-            )
-            outputs, violations = correct.read_build(
-                harness, ref, config, {"test": "wide_test"}, models_dir, posted,
-                labels[n:], comparison.sample,
-            )
-            comparison.compare(outputs)
-            # the forest's two sampling numbers, for a plain float64
-            # forest grown on the same rows by the reference itself
-            plain = comparison.audit_rf(ref.grow_forest(
-                comparison.bins, comparison.thresholds, labels[:n],
-                int(config["hyper"]["max_bins"]), int(config["hyper"]["max_depth"]),
-                trees=int(config["correct"]["rf_trees_checked"]), seed=self.SEED,
-            ))
-            return {
-                "numbers": comparison.numbers, "limits": config["limits"],
-                "violations": violations, "plain": plain, "spans": spans,
-            }
-        finally:
-            patch.undo()
+        return _audited_build(
+            "epsilon-500k.build3", {"features": 400}, 3000, 1000, 2147483777,
+            3072 * 80, str(tmp_path_factory.mktemp("models")),
+        )
 
     def test_nothing_is_missing_and_the_wide_path_ran(self, audit):
         assert audit["violations"] == []
-        assert len(audit["spans"]) == 3
-        for span in audit["spans"]:
+        assert len(audit["spans"]["enqueue"]) == 3
+        for span in audit["spans"]["enqueue"]:
             assert span["meta"]["hist_block_features"] == 80
             assert span["meta"]["hist_blocks"] == 5
 
@@ -771,3 +852,148 @@ class TestWideBuildAgainstThePlainReference:
         limits are the chip's): here the two numbers are held to what
         the reference's own forest reads on the same rows."""
         assert audit["numbers"][number] <= 1.25 * audit["plain"][number]
+
+
+class TestOneHotBuildAgainstThePlainReference:
+    """``dt`` + ``rf`` + ``gb`` + ``nb`` built through ``build_model`` -
+    store, documented preprocessor, write-back, checkpoints - on a
+    one-hot coded table from the benchmark's third maker at a small size
+    (6,000 + 600 rows x 280 columns: 278 indicators of the six groups
+    with 8 carriers and 110 airports, ten blocks of 28 once the cap is
+    shrunk), and what was published and stored held against the
+    benchmark's plain float64 reference inside the limits of
+    ``expo-onehot-700`` (PERF.md §4; the cell itself runs 524,288 +
+    52,429 x 700 on the chip)."""
+
+    FEATURES = 280
+
+    @pytest.fixture(scope="class")
+    def audit(self, tmp_path_factory):
+        # the cell's block geometry: 28 columns a block (6,000 rows pad
+        # to the 6,144 bucket)
+        found = _audited_build(
+            "expo-onehot-700.build4", {"carriers": 8, "airports": 110}, 6000, 600,
+            2147483999, 6144 * 28, str(tmp_path_factory.mktemp("models")),
+        )
+        found["thresholds"] = make_thresholds(np.ascontiguousarray(found["train"]), 32)
+        return found
+
+    def test_nothing_is_missing_and_the_blocked_path_ran(self, audit):
+        assert audit["violations"] == []
+        # naive Bayes' fit has an enqueue span too, with no block plan
+        plans = [s["meta"] for s in audit["spans"]["enqueue"] if s.get("meta")]
+        assert len(audit["spans"]["enqueue"]) == 4 and len(plans) == 3
+        for plan in plans:
+            assert plan["hist_block_features"] == 28 and plan["hist_blocks"] == 10
+        assert sum("subset_k" in plan for plan in plans) == 1
+        assert next(p["subset_k"] for p in plans if "subset_k" in p) == 17
+
+    def test_the_table_is_what_the_cell_is_there_for(self, audit):
+        """Indicator columns whose level is rare (all 31 thresholds 0),
+        common (0 and 1) or absent from the training rows (a constant
+        column), and two integer columns with thresholds of their own."""
+        X, thresholds = audit["train"], audit["thresholds"]
+        share = X[:, :-2].mean(axis=0)
+        assert set(np.unique(X[:, :-2])) == {0.0, 1.0}
+        assert (share == 0).sum() >= 5 and (share >= 1 / 32).sum() >= 20
+        assert ((share > 0) & (share < 1 / 32)).sum() >= 100
+        assert (thresholds[:-2][share < 1 / 32] == 0).all()
+        assert min(len(np.unique(row)) for row in thresholds[-2:]) > 25
+
+    def test_the_span_of_the_pass_counts_the_distinct_thresholds(self, audit):
+        spans = audit["spans"]["thresholds"]
+        assert sorted(s["meta"]["passes"] for s in spans) == [0, 0, 1]
+        (ran,) = [s for s in spans if s["meta"]["passes"] == 1]
+        counted = sum(len(np.unique(row)) for row in audit["thresholds"])
+        assert ran["meta"]["distinct_thresholds"] == counted
+        assert ran["meta"]["features"] == self.FEATURES and ran["meta"]["bins"] == 32
+        # a sliver of the 280 x 31 slots: most indicators have one
+        assert self.FEATURES <= counted < 2 * self.FEATURES + 62
+        assert all(
+            "distinct_thresholds" not in s["meta"] for s in spans if s["meta"]["passes"] == 0
+        )
+
+    @pytest.mark.parametrize("number", [
+        "tree_pred_gap", "dt_leaf_gap", "dt_split_gap", "dt_loss_gap",
+        "gb_leaf_gap", "gb_split_gap", "ensemble_loss_gap",
+        "nb_pred_gap", "nb_theta_gap", "nb_prior_gap",
+    ])
+    def test_inside_the_configurations_limit(self, audit, number):
+        assert audit["numbers"][number] <= audit["limits"][number]
+
+    @pytest.mark.parametrize("number", ["rf_leaf_z", "rf_split_gap"])
+    def test_the_forest_reads_as_a_plain_float64_forest_on_these_rows(
+        self, audit, number
+    ):
+        """As in the wide build: the forest's two sampling numbers fall
+        with the rows, so here they are held to what the reference's own
+        forest reads on the same 6,000 rows."""
+        assert audit["numbers"][number] <= 1.25 * audit["plain"][number]
+
+
+class TestNoPositivePredicted:
+    """A table whose every feature column is an indicator of a level
+    that no row holds - all zeros, as the rarest levels of a one-hot
+    coded table are - with 19 % positive labels: no tree can split, the
+    booster keeps its prior and naive Bayes its class shares, so every
+    classifier predicts the negative class on every row. The stored
+    accuracy is the negative share and the stored F1 the weighted F1 of
+    those labels, with a class nobody predicted counted as 0."""
+
+    CLASSIFIERS = ["dt", "rf", "gb", "nb"]
+
+    @pytest.fixture(scope="class")
+    def built(self, tmp_path_factory):
+        patch = pytest.MonkeyPatch()
+        patch.syspath_prepend(BENCH)
+        patch.setenv("LO_RESUME", "0")
+        try:
+            from lib import cells, correct, system
+
+            from learningorchestra_tpu.ml.builder import build_model
+
+            ref = cells.load_module("reference", "classifiers")
+            rng = np.random.default_rng(19)
+            labels = (rng.random(1100) < 0.19).astype(np.int64)
+            columns = [np.zeros(1100, np.float32) for _ in range(5)]
+            fields = [f"Origin_{j}" for j in range(5)]
+            harness = _Harness()
+            harness.write_dataset("flat_train", [c[:1000] for c in columns], labels[:1000], fields)
+            harness.write_dataset("flat_test", [c[1000:] for c in columns], labels[1000:], fields)
+            models_dir = str(tmp_path_factory.mktemp("models"))
+            posted = time.time()
+            build_model(
+                harness.store, "flat_train", "flat_test", system.PREPROCESSOR,
+                self.CLASSIFIERS, models_dir=models_dir,
+            )
+            outputs, violations = correct.read_build(
+                harness, ref, {"classifiers": self.CLASSIFIERS}, {"test": "flat_test"},
+                models_dir, posted, labels[1000:], np.arange(100),
+            )
+            rows = {
+                clf: (
+                    harness.store.find_one(f"flat_test_prediction_{clf}", {"_id": 0}),
+                    harness.stored(f"flat_test_prediction_{clf}", ["prediction"])["prediction"],
+                )
+                for clf in self.CLASSIFIERS
+            }
+            return {"violations": violations, "rows": rows, "truth": labels[1000:], "ref": ref}
+        finally:
+            patch.undo()
+
+    @pytest.mark.parametrize("clf", CLASSIFIERS)
+    def test_stored_accuracy_and_f1_are_the_stored_labels(self, built, clf):
+        assert not [v for v in built["violations"] if v.startswith(clf)]
+        meta, predicted = built["rows"][clf]
+        truth = built["truth"]
+        assert len(predicted) == 100 and not predicted.any()
+        negative = float((truth == 0).mean())
+        assert 0.7 < negative < 0.9
+        assert float(meta["accuracy"]) == pytest.approx(negative, abs=1e-6)
+        # weighted F1: the negative class's F1 by its share, the positive
+        # class's (precision 0 / 0, recall 0) counted as 0
+        f1 = negative * (2 * negative / (1 + negative))
+        assert float(meta["F1"]) == pytest.approx(f1, abs=1e-6)
+        accuracy, f1_ref = built["ref"].accuracy_f1(predicted.astype(np.int64), truth)
+        assert float(meta["accuracy"]) == pytest.approx(accuracy, abs=1e-6)
+        assert float(meta["F1"]) == pytest.approx(f1_ref, abs=1e-6)
