@@ -10,10 +10,11 @@ TPU-first design — no recursive node objects, no data-dependent control
 flow:
 
 - Features are quantile-binned once (``ml/binning.py``); a tree level is
-  then ONE dense program: scatter-add per-row stat vectors into a
-  ``(node, feature, bin, channel)`` histogram, cumulative-sum over bins,
-  and an argmax — the classic LightGBM/XGBoost histogram method, which
-  is exactly the shape of computation XLA tiles well.
+  then ONE dense program: accumulate per-row stat vectors into a
+  ``(node, feature, bin, channel)`` histogram (one wide bfloat16-exact
+  contraction per row tile, :func:`_level_histograms`), cumulative-sum
+  over bins, and an argmax — the classic LightGBM/XGBoost histogram
+  method, which is exactly the shape of computation XLA tiles well.
 - The tree is a static heap (arrays of size ``2^depth - 1``); rows carry
   an int32 node index and each level doubles it. Nodes that stop
   splitting get ``feature = -1`` and route everything left, so shapes
@@ -24,8 +25,9 @@ flow:
 - Random forest is ``vmap`` over per-tree RNG keys — all 20 trees grow
   simultaneously on device, with Poisson(1) bootstrap weights and
   per-node feature subsets. Boosting is ``lax.scan`` over rounds.
-- Row-sharded inputs: the scatter-adds reduce over the ``data`` mesh
-  axis; XLA inserts the cross-chip psum from the sharding annotations.
+- Row-sharded inputs: the histograms are accumulated shard-locally and
+  reduce over the ``data`` mesh axis once a feature block; XLA inserts
+  the cross-chip all-reduce from the sharding annotations.
 """
 
 from __future__ import annotations
@@ -60,24 +62,40 @@ NUM_TREES = 20         # MLlib default numTrees (RF)
 GBT_ROUNDS = 20        # MLlib default maxIter (GBT)
 GBT_STEP = 0.1         # MLlib default stepSize
 EPS = 1e-12
-# rows*features cap per histogram feature-block: bounds the f32 bin
-# indicator transient at ~2 GB (rows*block*max_bins*4 with 32 bins)
-_HIST_BLOCK_ROW_FEATURES = 16e6
+# How the level histograms walk a table (hist_block_plan): the rows a
+# step of the tile loop contracts, summed over the row groups; the
+# contiguous row groups that accumulate side by side (a batch dimension
+# of the contraction: on a mesh it keeps the loop shard-local, and on
+# one chip the TPU compiler takes minutes over the unbatched form); and
+# the bytes the one bfloat16 bin indicator alive at a time may take,
+# which caps the columns of a feature block. PERF.md §6 (PR 35) has the
+# chip readings that chose them.
+_HIST_TILE_ROWS = 16384
+_HIST_ROW_GROUPS = 8
+_HIST_INDICATOR_BYTES = 2**27
 
 
 def hist_block_plan(rows: int, num_features: int, max_bins: int) -> dict:
-    """How :func:`_level_histograms` cuts the features of a level into
-    blocks, from the static shapes alone (host arithmetic, no device
-    work): features a block, blocks a level, and the bytes of one
-    block's float32 bin indicator. The fits stamp it on ``fit:enqueue``
-    and the contraction takes its block from here, so the two cannot
-    drift apart."""
-    cap = max(1, int(_HIST_BLOCK_ROW_FEATURES // max(rows, 1)))
+    """The geometry of a level's histogram accumulate, from the static
+    shapes alone (host arithmetic, no device work): the rows of one
+    tile (``hist_tile_rows``: every row group's share of a step, so a
+    tile is small whatever the table's height), the columns of a
+    feature block and the blocks a level, and the bytes of the one
+    bfloat16 bin indicator alive at a time, ``tile x block x bins x 2``.
+    The fits stamp it on ``fit:enqueue`` and :func:`_level_histograms`
+    takes its tiles and blocks from here, so the two cannot drift
+    apart. A table whose rows the tiles do not divide is padded up to
+    them with weightless rows; the bucketed row counts of
+    ``parallel/sharding.py`` divide evenly."""
+    steps = max(1, -(-rows // _HIST_TILE_ROWS))
+    tile = -(-max(rows, 1) // (_HIST_ROW_GROUPS * steps)) * _HIST_ROW_GROUPS
+    cap = max(1, int(_HIST_INDICATOR_BYTES // (tile * max_bins * 2)))
     block = largest_divisor(num_features, cap)
     return {
+        "hist_tile_rows": tile,
         "hist_block_features": block,
         "hist_blocks": num_features // block,
-        "hist_indicator_bytes": rows * block * max_bins * 4,
+        "hist_indicator_bytes": tile * block * max_bins * 2,
     }
 
 
@@ -85,63 +103,132 @@ def hist_block_plan(rows: int, num_features: int, max_bins: int) -> dict:
 # Level primitives
 # --------------------------------------------------------------------------
 
+def _split_bf16(x):
+    """``x`` (float32) as three bfloat16 pieces whose float32 sum is
+    ``x`` again: each piece takes the next eight significand bits, so a
+    one-pass bfloat16 matmul against an exact 0/1 operand, accumulated
+    in float32, gives what a float32 matmul gives.
+
+    A piece is cut by clearing the low sixteen bits of the float32
+    pattern, not by a round trip through bfloat16: inside a fused
+    program the TPU keeps a bfloat16 intermediate in float32 registers,
+    so ``x - x.astype(bfloat16)`` reads zero there and the two lower
+    pieces with it (PERF.md §6, PR 35: the chip's gradient sums then
+    carried eight bits). The masked values are bfloat16 values exactly,
+    so the casts at the end round nothing. A piece under the smallest
+    normal float32 (1.2e-38: a subnormal value, or the low piece of a
+    value under 2e-31) may be flushed to zero, as the hardware flushes
+    any such float32."""
+
+    def top(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        return jax.lax.bitcast_convert_type(
+            bits & jnp.uint32(0xFFFF0000), jnp.float32
+        )
+
+    hi = top(x)
+    rest = x - hi
+    mid = top(rest)
+    lo = rest - mid
+    return tuple(piece.astype(jnp.bfloat16) for piece in (hi, mid, lo))
+
+
 def _level_histograms(bins, node, channels, n_nodes: int, max_bins: int):
     """Accumulate per-row channel vectors into ``(node, feature, bin, K)``.
 
-    The histogram-build hot loop: O(rows × features) accumulation, the
+    The histogram-build hot loop: O(rows x features) accumulation, the
     tree analogue of the reference's distributed MLlib fit iterations
     (model_builder.py:199).
 
     MXU formulation: the scatter-add is algebraically
-    ``one_hot(bin).T @ (one_hot(node) ⊗ channels)`` — two dense
-    matmuls, which the systolic array executes at full tilt where a
-    batched scatter (under the forest's tree-vmap) serializes. Measured
-    on v5e at 1M×16, 20 trees: 0.26 s/level vs 2.55 s/level for the
-    scatter — 10×. f32 operands keep the sums within 1e-4 of exact
-    (matmul reassociation only). The scatter fallback guards the wide
-    case (many classes at deep levels) where the ``(rows, nodes·K)``
-    intermediate would not fit.
+    ``one_hot(bin).T @ (one_hot(node) ⊗ channels)``, which the systolic
+    array executes where a batched scatter (under the forest's
+    tree-vmap) serializes. It runs as ONE wide contraction per row
+    tile (:func:`hist_block_plan`): the rows are walked in tiles, each
+    tile's bin indicator is built in bfloat16 for a whole block of
+    columns (0 and 1 are exact, and a tile is small whatever the
+    table's height, so a block holds many columns), contracted over the
+    tile's rows against the channel operand, and added into a float32
+    accumulator. The channel operand, the only side with float32
+    values, goes in as three bfloat16 pieces side by side
+    (:func:`_split_bf16`), so every product is exact and every sum is
+    float32: counts are exact whatever the order of the tiles, gradient
+    sums differ from a plain float32 sum by reassociation only.
+
+    The rows are cut into ``_HIST_ROW_GROUPS`` contiguous groups that
+    accumulate side by side and are summed once the tiles are walked.
+    On a mesh whose ``data`` axis divides the groups each group lies
+    inside one row shard: the tile loop runs shard-local and that one
+    sum is the only reduction over ``data``, once a feature block (a
+    loop over the sharded row axis itself would gather the matrix).
+
+    The scatter fallback guards the wide case (many classes at deep
+    levels) where the ``(rows, nodes·K)`` operand would not fit.
     """
     num_channels = channels.shape[1]
     num_features = bins.shape[1]
     rows = bins.shape[0]
+    width = n_nodes * num_channels
 
-    if n_nodes * num_channels <= 64:
-        node_oh = jax.nn.one_hot(node, n_nodes, dtype=jnp.float32)
-        fused = (node_oh[:, :, None] * channels[:, None, :]).reshape(
-            channels.shape[0], n_nodes * num_channels
+    if width <= 64:
+        plan = hist_block_plan(rows, num_features, max_bins)
+        block, blocks = plan["hist_block_features"], plan["hist_blocks"]
+        groups = _HIST_ROW_GROUPS
+        tile = plan["hist_tile_rows"] // groups
+        steps = -(-rows // plan["hist_tile_rows"])
+        pad = steps * plan["hist_tile_rows"] - rows
+        if pad:  # weightless rows: zero channels add nothing
+            bins = jnp.pad(bins, ((0, pad), (0, 0)))
+            node = jnp.pad(node, (0, pad))
+            channels = jnp.pad(channels, ((0, pad), (0, 0)))
+
+        def by_step(a):
+            # (step, group, tile row, ...): a step takes one tile of
+            # every group
+            return jnp.swapaxes(
+                a.reshape(groups, steps, tile, *a.shape[1:]), 0, 1
+            )
+
+        node_t, channels_t = by_step(node), by_step(channels)
+        # (block, step, group, tile row, column of the block)
+        bins_t = jnp.moveaxis(
+            by_step(bins).reshape(steps, groups, tile, blocks, block), 3, 0
         )
+        bin_ids = jnp.arange(max_bins, dtype=jnp.int32)
+        node_ids = jnp.arange(n_nodes, dtype=jnp.int32)
 
-        # Feature-BLOCKED contraction: one per-feature dot re-reads the
-        # (rows, nodes*K) fused matrix from HBM once per feature — 16
-        # features × 5 levels × 20 vmapped trees ≈ 400 GB of redundant
-        # traffic per forest fit at 1M rows. Contracting a block of
-        # features in ONE dot_general reads fused once per block; the
-        # bin indicator is built in (block, rows, bins) layout and
-        # contracted over rows directly (no transpose materializes).
-        # Block size is HBM-capped: the indicator transient is
-        # rows*block*max_bins*4 bytes (~2 GB cap).
-        block = hist_block_plan(rows, num_features, max_bins)[
-            "hist_block_features"
-        ]
-        blocked = bins.T.reshape(num_features // block, block, rows)
-        iota = jnp.arange(max_bins, dtype=jnp.int32)
-
-        def per_block_mm(bins_fb):
-            # (block, rows, bins) exact 0/1 indicator
-            indicator = (bins_fb[:, :, None] == iota).astype(jnp.float32)
-            # HIGHEST: `fused` carries arbitrary f32 gradients on the
-            # boosting path; the TPU's default bf16 matmul would shift
-            # near-tie split gains (indicator operands alone are
-            # bf16-exact, the channel side is not)
-            return jax.lax.dot_general(
+        def add_tile(acc, tile_of):
+            bins_b, node_b, channels_b = tile_of
+            # exact 0/1, never a float32 array
+            indicator = (bins_b[..., None] == bin_ids).astype(jnp.bfloat16)
+            pieces = jnp.stack(_split_bf16(channels_b), axis=-1)  # (g,t,K,3)
+            fused = jnp.where(
+                (node_b[..., None] == node_ids)[..., None, None],
+                pieces[:, :, None],
+                0,
+            ).reshape(groups, tile, width * 3)
+            # one bfloat16 pass (whatever the process-wide default
+            # precision says), float32 sums: every product is exact
+            return acc + jax.lax.dot_general(
                 indicator,
                 fused,
-                (((1,), (0,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-            )                                    # (block, bins, nodes*K)
+                (((1,), (1,)), ((0,), (0,))),
+                precision=jax.lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32,
+            ), None                      # (g, block, bins, nodes*K*3)
 
-        hist = jax.lax.map(per_block_mm, blocked)  # (F/blk, blk, B, n*K)
+        def per_block(bins_block):
+            acc, _ = jax.lax.scan(
+                add_tile,
+                jnp.zeros((groups, block, max_bins, width * 3), jnp.float32),
+                (bins_block, node_t, channels_t),
+            )
+            hi, mid, lo = jnp.moveaxis(
+                acc.sum(axis=0).reshape(block, max_bins, width, 3), 3, 0
+            )
+            return hi + (mid + lo)               # (block, bins, nodes*K)
+
+        hist = jax.lax.map(per_block, bins_t)    # (F/blk, blk, B, n*K)
         return hist.reshape(
             num_features, max_bins, n_nodes, num_channels
         ).transpose(2, 0, 1, 3)
@@ -474,14 +561,15 @@ def _rf_chunk(
 
 
 # Per-program budget in row*trees: one bootstrap tree costs about one
-# boosting round (~0.3-0.7 s at 1M rows) — ~4 trees at 10M rows keeps a
-# segment under the execution watchdog (see base.segment_steps).
+# boosting round — ~4 trees at 10M rows keeps a segment short (see
+# base.segment_steps; PERF.md §5 has a program's seconds in each cell).
 _RF_ROW_TREES_BUDGET = 40e6
 
-# HBM cap on the vmap width: a chunk's level-histogram transients are
-# (chunk*rows_per_device, lanes) one-hots padded to the 128-lane tile
-# (~512 B/row at f32) — 20M row*trees per device ≈ 10 GB transient,
-# inside a 16 GB v5e alongside the binned matrix.
+# HBM cap on the vmap width: a chunk's per-tree row operands (the
+# weighted class one-hots, padded to the 128-lane tile: ~512 B/row at
+# f32) — 20M row*trees per device ≈ 10 GB, inside a 16 GB v5e alongside
+# the binned matrix. The level histograms themselves hold one row tile
+# at a time (hist_block_plan), whatever the vmap width.
 _RF_ROW_TREES_PER_DEVICE_HBM = 20e6
 
 
@@ -595,8 +683,8 @@ def _gbt_rounds_runner():
 
 
 # Per-program budget in row*rounds: one boosting round builds a whole
-# depth-5 tree (~0.3-0.6 s at 1M rows), so ~4 rounds at 10M rows keeps
-# a segment under the execution watchdog (see base.segment_steps).
+# depth-5 tree, so ~4 rounds at 10M rows keeps a segment short (see
+# base.segment_steps; PERF.md §5 has a program's seconds in each cell).
 _GB_ROW_ROUNDS_BUDGET = 40e6
 
 

@@ -2,6 +2,7 @@
 
 import json
 import os
+import re
 import time
 import warnings
 
@@ -579,25 +580,42 @@ def _scatter_histogram(bins, node, channels, n_nodes, max_bins):
     return hist
 
 
+def _set_hist_geometry(patch, tile_rows, block, max_bins=32):
+    """Shrink the tile loop of ``_level_histograms`` to ``tile_rows`` a
+    step and ``block`` columns a feature block (the cap on the one
+    bfloat16 indicator alive at a time, in bytes)."""
+    patch.setattr(trees, "_HIST_TILE_ROWS", tile_rows)
+    patch.setattr(trees, "_HIST_INDICATOR_BYTES", tile_rows * block * max_bins * 2)
+
+
 class TestBlockedHistograms:
-    """``_level_histograms`` with more than one feature a block and more
-    than one block a level - the path a wide table takes (PERF.md §4:
-    163,840 x 2,000 runs 25 blocks of 80): the same sums as a plain
-    scatter-add and as the one-block contraction."""
+    """``_level_histograms`` with more than one row tile AND more than
+    one feature block of several columns a level - the path every table
+    of the benchmark takes (PERF.md §3: 8,388,608 x 28 walks 512 tiles
+    of one block, 163,840 x 2,000 ten tiles of sixteen blocks): the
+    same sums as a plain scatter-add and as the one-tile, one-block
+    contraction."""
 
-    ROWS, FEATURES, BLOCK, BINS = 2048, 400, 80, 32
+    ROWS, FEATURES, BLOCK, BINS, TILE = 2048, 400, 80, 32, 512
 
-    def _inputs(self, rng, kind, n_nodes):
-        bins = rng.integers(0, self.BINS, (self.ROWS, self.FEATURES)).astype(np.int8)
-        node = rng.integers(0, n_nodes, self.ROWS).astype(np.int32)
+    def _inputs(self, rng, kind, n_nodes, rows=None):
+        rows = rows or self.ROWS
+        bins = rng.integers(0, self.BINS, (rows, self.FEATURES)).astype(np.int8)
+        node = rng.integers(0, n_nodes, rows).astype(np.int32)
         if kind == "counts":  # class one-hots times a bootstrap weight
-            label = rng.integers(0, 2, self.ROWS)
-            weight = rng.poisson(1.0, self.ROWS)
+            label = rng.integers(0, 2, rows)
+            weight = rng.poisson(1.0, rows)
             channels = np.eye(2)[label] * weight[:, None]
         else:  # Newton (g, h) pairs
-            p = rng.random(self.ROWS)
-            channels = np.stack([p - rng.integers(0, 2, self.ROWS), p * (1 - p)], 1)
+            p = rng.random(rows)
+            channels = np.stack([p - rng.integers(0, 2, rows), p * (1 - p)], 1)
         return bins, node, channels.astype(np.float32)
+
+    def _histograms(self, bins, node, channels, n_nodes):
+        return np.asarray(trees._level_histograms(
+            jnp.asarray(bins), jnp.asarray(node), jnp.asarray(channels),
+            n_nodes, self.BINS,
+        ))
 
     @pytest.mark.parametrize("n_nodes", [1, 8])
     @pytest.mark.parametrize("kind", ["counts", "gradients"])
@@ -607,25 +625,151 @@ class TestBlockedHistograms:
         bins, node, channels = self._inputs(rng, kind, n_nodes)
         want = _scatter_histogram(bins, node, channels, n_nodes, self.BINS)
 
-        def histograms(row_features):
-            monkeypatch.setattr(trees, "_HIST_BLOCK_ROW_FEATURES", row_features)
-            return np.asarray(trees._level_histograms(
-                jnp.asarray(bins), jnp.asarray(node), jnp.asarray(channels),
-                n_nodes, self.BINS,
-            ))
-
-        monkeypatch.setattr(trees, "_HIST_BLOCK_ROW_FEATURES", self.ROWS * self.BLOCK)
+        _set_hist_geometry(monkeypatch, self.TILE, self.BLOCK)
         plan = trees.hist_block_plan(self.ROWS, self.FEATURES, self.BINS)
         assert (plan["hist_block_features"], plan["hist_blocks"]) == (self.BLOCK, 5)
-        blocked = histograms(self.ROWS * self.BLOCK)
-        whole = histograms(1e12)
+        assert plan["hist_tile_rows"] == self.TILE  # four tiles
+        blocked = self._histograms(bins, node, channels, n_nodes)
+        _set_hist_geometry(monkeypatch, self.ROWS, self.FEATURES)
+        assert trees.hist_block_plan(self.ROWS, self.FEATURES, self.BINS)[
+            "hist_blocks"
+        ] == 1
+        whole = self._histograms(bins, node, channels, n_nodes)
         assert blocked.shape == want.shape == whole.shape
         if kind == "counts":  # small integers: float32 sums are exact
             assert np.array_equal(blocked, want)
             assert np.array_equal(blocked, whole)
         else:
-            np.testing.assert_allclose(blocked, want, atol=1e-4, rtol=0)
-            np.testing.assert_allclose(blocked, whole, atol=1e-4, rtol=0)
+            np.testing.assert_allclose(blocked, want, atol=1e-5, rtol=0)
+            np.testing.assert_allclose(blocked, whole, atol=1e-5, rtol=0)
+
+    @pytest.mark.parametrize("kind", ["counts", "gradients"])
+    def test_rows_the_tiles_do_not_divide_are_padded_with_weightless_rows(
+        self, rng, monkeypatch, kind
+    ):
+        """2,000 rows in steps of 512: four tiles of 504 hold 2,016, and
+        the sixteen rows of padding add nothing to any bin."""
+        bins, node, channels = self._inputs(rng, kind, 4, rows=2000)
+        _set_hist_geometry(monkeypatch, self.TILE, self.BLOCK)
+        assert trees.hist_block_plan(2000, self.FEATURES, self.BINS)[
+            "hist_tile_rows"
+        ] == 504
+        got = self._histograms(bins, node, channels, 4)
+        want = _scatter_histogram(bins, node, channels, 4, self.BINS)
+        if kind == "counts":
+            assert np.array_equal(got, want)
+        else:
+            np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+
+    @pytest.mark.parametrize("kind", ["counts", "gradients"])
+    def test_same_under_vmap_over_two_trees(self, rng, monkeypatch, kind):
+        """The forest's shape: one binned matrix, a node index and a
+        channel operand a tree."""
+        bins, node, channels = self._inputs(rng, kind, 8)
+        _, node_2, channels_2 = self._inputs(rng, kind, 8)
+        _set_hist_geometry(monkeypatch, self.TILE, self.BLOCK)
+        both = np.asarray(jax.vmap(
+            lambda n, c: trees._level_histograms(
+                jnp.asarray(bins), n, c, 8, self.BINS
+            )
+        )(jnp.stack([node, node_2]), jnp.stack([channels, channels_2])))
+        for got, (n, c) in zip(both, [(node, channels), (node_2, channels_2)]):
+            alone = self._histograms(bins, n, c, 8)
+            want = _scatter_histogram(bins, n, c, 8, self.BINS)
+            if kind == "counts":
+                assert np.array_equal(got, want)
+                assert np.array_equal(got, alone)
+            else:
+                np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+                np.testing.assert_allclose(got, alone, atol=1e-5, rtol=0)
+
+    @pytest.mark.parametrize("data, model", [(8, 1), (4, 2)])
+    @pytest.mark.parametrize("kind", ["counts", "gradients"])
+    def test_same_on_a_row_sharded_mesh_with_one_reduction_a_block(
+        self, rng, monkeypatch, kind, data, model
+    ):
+        """Rows sharded over ``data``: the tiles are cut inside each row
+        shard (no gather of the matrix, the node index or the channels)
+        and the partial histograms meet in one all-reduce a feature
+        block, after the tile loop."""
+        from learningorchestra_tpu.parallel.mesh import make_mesh
+        from learningorchestra_tpu.parallel.sharding import row_sharded
+
+        bins, node, channels = self._inputs(rng, kind, 4)
+        want = _scatter_histogram(bins, node, channels, 4, self.BINS)
+        _set_hist_geometry(monkeypatch, self.TILE, self.BLOCK)
+        sharding = row_sharded(make_mesh(data=data, model=model))
+        placed = [jax.device_put(a, sharding) for a in (bins, node, channels)]
+        program = jax.jit(
+            lambda b, n, c: trees._level_histograms(b, n, c, 4, self.BINS)
+        )
+        text = program.lower(*placed).compile().as_text()
+        assert "all-gather" not in text and "all-to-all" not in text
+        assert "collective-permute" not in text
+        assert len(re.findall(r" all-reduce(?:-start)?\(", text)) == 1
+        got = np.asarray(program(*placed))
+        if kind == "counts":
+            assert np.array_equal(got, want)
+        else:
+            np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+
+
+class TestSplitBf16:
+    """The channel operand as three bfloat16 pieces: their float32 sum
+    is the float32 value again, so a one-pass bfloat16 contraction
+    against a 0/1 indicator loses nothing."""
+
+    PLANTED = np.array(
+        [
+            1e-6, -1e-6,            # the floor of the booster's h
+            1e30, -1e30, 3.0e38,
+            0.0, -0.0, 1.0, -1.0, 1.0 / 3.0, np.pi, 0.1, 16777215.0,
+            1.0 + 2.0**-23, 1.0 - 2.0**-24, 255.99998, 2.0**-100,
+            1.17549435e-38,         # the smallest normal float32
+        ],
+        dtype=np.float32,
+    )
+
+    def _sum(self, x):
+        pieces = trees._split_bf16(jnp.asarray(x))
+        assert all(piece.dtype == jnp.bfloat16 for piece in pieces)
+        hi, mid, lo = (np.asarray(piece.astype(jnp.float32)) for piece in pieces)
+        return (hi + mid) + lo
+
+    def test_planted_values_come_back_exactly(self):
+        back = self._sum(self.PLANTED)
+        assert back.dtype == np.float32
+        assert np.array_equal(back, self.PLANTED)
+
+    def test_random_values_of_every_magnitude_come_back_exactly(self, rng):
+        x = (
+            rng.standard_normal(20000) * 10.0 ** rng.uniform(-24, 30, 20000)
+        ).astype(np.float32)
+        assert np.abs(x).min() > 2.0**-100
+        assert np.array_equal(self._sum(x), x)
+
+    def test_the_pieces_are_cut_from_the_bits_not_by_a_cast_and_back(self):
+        """Inside a fused TPU program a bfloat16 intermediate lives in a
+        float32 register, so ``x - x.astype(bfloat16)`` is zero there
+        and a split made that way carries eight bits on the chip while
+        passing every test on the CPU (PERF.md §6, PR 35). The pieces
+        are masked out of the float32 pattern, and each cast at the end
+        is of a value bfloat16 holds exactly."""
+        text = str(jax.make_jaxpr(trees._split_bf16)(jnp.asarray(self.PLANTED)))
+        assert text.count(" and ") == 2 and "bitcast_convert_type" in text
+        # no float32 value is made from a bfloat16 one
+        assert "convert_element_type[new_dtype=float32" not in text
+
+    def test_subnormals_lose_no_more_than_the_smallest_normal(self):
+        """A piece under the smallest normal float32 (2^-126) may be
+        flushed to zero, on the chip as on the CPU: a subnormal value,
+        or the low piece of a value under 2^-102, comes back to within
+        that, which no float32 sum of sound gradients can see."""
+        x = np.array(
+            [1e-40, -3e-39, 1.4e-45, 5.9e-39, 2.1941396e-33, 3.3e-33],
+            np.float32,
+        )
+        assert np.all(np.abs(self._sum(x) - x) <= 2.0**-126)
 
 
 def _spans_of(work):
@@ -649,25 +793,42 @@ def _enqueue_spans(work):
 
 
 class TestHistBlockPlan:
-    """The block plan the fits stamp on ``fit:enqueue`` is the one the
-    contraction runs by, at the two benchmark cells' padded shapes."""
+    """The tile and block plan the fits stamp on ``fit:enqueue`` is the
+    one the contraction runs by, at the three benchmark cells' padded
+    shapes."""
 
     @pytest.mark.parametrize(
-        "rows, features, block, blocks, indicator_bytes",
+        "rows, features, tile, block, blocks, indicator_bytes",
         [
-            (163840, 2000, 80, 25, 1677721600),    # epsilon-500k
-            (8388608, 28, 1, 28, 1073741824),      # higgs-11m
-            (524288, 700, 28, 25, 1879048192),     # expo-onehot-700
+            (163840, 2000, 16384, 125, 16, 131072000),   # epsilon-500k
+            (8388608, 28, 16384, 28, 1, 29360128),       # higgs-11m
+            (524288, 700, 16384, 100, 7, 104857600),     # expo-onehot-700
         ],
     )
     def test_figures_at_the_cells_shapes(
-        self, rows, features, block, blocks, indicator_bytes
+        self, rows, features, tile, block, blocks, indicator_bytes
     ):
         assert trees.hist_block_plan(rows, features, 32) == {
+            "hist_tile_rows": tile,
             "hist_block_features": block,
             "hist_blocks": blocks,
             "hist_indicator_bytes": indicator_bytes,
         }
+        # the bucketed row counts divide into whole tiles: no padding
+        assert rows % tile == 0
+
+    @pytest.mark.parametrize(
+        "rows, tile",
+        [(1, 8), (300, 304), (3072, 3072), (16384, 16384), (16385, 8200),
+         (1000003, 16136)],
+    )
+    def test_a_tile_is_every_row_groups_equal_share_of_a_step(self, rows, tile):
+        plan = trees.hist_block_plan(rows, 28, 32)
+        assert plan["hist_tile_rows"] == tile
+        assert tile % trees._HIST_ROW_GROUPS == 0
+        steps = -(-rows // tile)
+        assert 0 <= steps * tile - rows < trees._HIST_ROW_GROUPS * steps
+        assert tile <= trees._HIST_TILE_ROWS + trees._HIST_ROW_GROUPS
 
     @pytest.mark.parametrize(
         "make",
@@ -683,11 +844,13 @@ class TestHistBlockPlan:
         y = (X[:, 3] + X[:, 31] > 0).astype(np.int32)
         classifier = make()
         padded = prepare_xy(X, y, classifier.mesh)[0].shape[0]
-        # 10 features a block, 4 blocks a level, at this small size
-        monkeypatch.setattr(trees, "_HIST_BLOCK_ROW_FEATURES", padded * 10)
+        # 10 features a block, 4 blocks a level, five tiles, at this
+        # small size
+        _set_hist_geometry(monkeypatch, padded // 5, 10)
         (span,) = _enqueue_spans(lambda: classifier.fit(X, y))
         plan = trees.hist_block_plan(padded, 40, 32)
         assert plan["hist_block_features"] == 10 and plan["hist_blocks"] == 4
+        assert plan["hist_tile_rows"] == padded // 5
         assert {k: span["meta"][k] for k in plan} == plan
         assert ("subset_k" in span["meta"]) == isinstance(
             classifier, RandomForestClassifier
@@ -757,13 +920,14 @@ def _audited_build(cell, dataset, train, test, seed, block_cap, models_dir):
     """One build of ``cell``'s classifiers through ``build_model`` -
     store, documented preprocessor, write-back, checkpoints - on
     ``train`` + ``test`` rows from the cell's maker with ``dataset``'s
-    keys changed, the level histograms cut into blocks by ``block_cap``
-    (padded rows times the columns of a block), and what was published
+    keys changed, the level histograms cut into tiles and blocks by
+    ``block_cap`` (the rows of a tile, the columns of a block), and what
+    was published
     and stored held against the benchmark's plain float64 reference."""
     patch = pytest.MonkeyPatch()
     patch.syspath_prepend(BENCH)
     patch.setenv("LO_RESUME", "0")
-    patch.setattr(trees, "_HIST_BLOCK_ROW_FEATURES", block_cap)
+    _set_hist_geometry(patch, *block_cap)
     try:
         from lib import cells, correct, system
 
@@ -821,11 +985,11 @@ class TestWideBuildAgainstThePlainReference:
 
     @pytest.fixture(scope="class")
     def audit(self, tmp_path_factory):
-        # the wide path: several blocks of several columns each
-        # (3,000 rows pad to the 3,072 bucket)
+        # the wide path: several blocks of several columns each, three
+        # row tiles (3,000 rows pad to the 3,072 bucket)
         return _audited_build(
             "epsilon-500k.build3", {"features": 400}, 3000, 1000, 2147483777,
-            3072 * 80, str(tmp_path_factory.mktemp("models")),
+            (1024, 80), str(tmp_path_factory.mktemp("models")),
         )
 
     def test_nothing_is_missing_and_the_wide_path_ran(self, audit):
@@ -869,11 +1033,11 @@ class TestOneHotBuildAgainstThePlainReference:
 
     @pytest.fixture(scope="class")
     def audit(self, tmp_path_factory):
-        # the cell's block geometry: 28 columns a block (6,000 rows pad
-        # to the 6,144 bucket)
+        # 28 columns a block, three row tiles (6,000 rows pad to the
+        # 6,144 bucket)
         found = _audited_build(
             "expo-onehot-700.build4", {"carriers": 8, "airports": 110}, 6000, 600,
-            2147483999, 6144 * 28, str(tmp_path_factory.mktemp("models")),
+            2147483999, (2048, 28), str(tmp_path_factory.mktemp("models")),
         )
         found["thresholds"] = make_thresholds(np.ascontiguousarray(found["train"]), 32)
         return found
