@@ -161,12 +161,16 @@ def enumerate_programs(
                     [("max_depth", lo_trees.MAX_DEPTH),
                      ("rounds", lo_trees.GBT_ROUNDS)])
             for rows in fit_rows:
-                add("build:lr", rows, f, c,
-                    [("iters", lr_segment_iters(rows, f))])
+                iters = lr_segment_iters(rows, f)
+                for fresh in lr_segment_kinds(iters):
+                    add("build:lr", rows, f, c,
+                        [("fresh", fresh), ("iters", iters)])
                 add("build:nb", rows, f, c)
-            add("sweep:lr", _padded_rows(min(fit_rows), mesh), f, c,
-                [("iters", lr_segment_iters(min(fit_rows), f)),
-                 ("jobs", _SWEEP_JOB_FLOOR)])
+            iters = lr_segment_iters(min(fit_rows), f)
+            for fresh in lr_segment_kinds(iters):
+                add("sweep:lr", _padded_rows(min(fit_rows), mesh), f, c,
+                    [("fresh", fresh), ("iters", iters),
+                     ("jobs", _SWEEP_JOB_FLOOR)])
     if max_programs is None:
         return specs, []
     return specs[:max_programs], specs[max_programs:]
@@ -191,6 +195,12 @@ def lr_segment_iters(
     if capped >= min(iters, 5):
         iters = capped
     return iters
+
+
+def lr_segment_kinds(iters: int, max_iter: int = 100) -> tuple:
+    """The static ``fresh`` of the segments such a fit dispatches: its
+    first seeds value and gradient, any later one carries them."""
+    return (True, False) if max_iter // iters > 1 else (True,)
 
 
 def specs_for_artifact(path: str, mesh) -> list[ProgramSpec]:
@@ -321,7 +331,8 @@ def lower_args(spec: ProgramSpec):
             (params, state, X,
              sds((rows,), jnp.int32, sharding=sharded),
              sds((rows,), f32, sharding=sharded)),
-            {"iters": statics["iters"], "l2": sds((), f32)},
+            {"iters": statics["iters"], "fresh": statics["fresh"],
+             "l2": sds((), f32)},
         )
     if spec.program == "build:nb":
         from learningorchestra_tpu.ml import naive_bayes as nb
@@ -346,6 +357,6 @@ def lower_args(spec: ProgramSpec):
             (params, state, sds((jobs, rows, f), jnp.dtype(spec.dtype)),
              sds((jobs, rows), jnp.int32), sds((jobs, rows), f32),
              sds((jobs,), f32)),
-            {"iters": statics["iters"]},
+            {"iters": statics["iters"], "fresh": statics["fresh"]},
         )
     raise ValueError(f"unknown program {spec.program!r}")

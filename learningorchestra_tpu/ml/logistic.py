@@ -5,11 +5,15 @@ microservices/model_builder_image/model_builder.py:7,152 — MLlib also
 optimizes with L-BFGS on the JVM). Defaults mirror MLlib: ``maxIter=100``,
 ``regParam=0.0``, fit-intercept, internal feature standardization.
 
-TPU shape: the whole optimization is ONE jitted program — ``lax.scan``
-over L-BFGS iterations, each iteration a fused (rows, features) ×
-(features, classes) matmul on row-sharded data; the mean-loss reduction
-is the only cross-chip collective and XLA inserts it from the sharding
-annotations (no hand-written NCCL/allreduce as in torch-style ports).
+TPU shape: the matrix is standardised on the device by one program
+(:func:`_lr_standardize`), then each segment of the optimization is ONE
+jitted program — ``lax.scan`` over L-BFGS iterations that carries the
+margins ``X·W + b`` as ``(classes, rows)``, so every iteration reads the
+row-sharded matrix exactly twice (the direction's margins, the
+gradient) and the line search's trial steps are elementwise work on the
+margins; the reductions over rows are the only cross-chip collectives
+and XLA inserts them from the sharding annotations (no hand-written
+NCCL/allreduce as in torch-style ports).
 """
 
 from __future__ import annotations
@@ -20,11 +24,13 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Var
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from learningorchestra_tpu.parallel.mesh import MODEL_AXIS, model_size
 from learningorchestra_tpu.ml import progress as _progress
 from learningorchestra_tpu.ml.base import (
+    DeviceMatrix,
     FittedModel,
     infer_num_classes,
     prepare_xy,
@@ -33,12 +39,40 @@ from learningorchestra_tpu.ml.base import (
 from learningorchestra_tpu.telemetry import tracing as _tracing
 
 
+# LR's contractions are exact float32 whatever JAX_DEFAULT_MATMUL_PRECISION
+# says (one bfloat16 pass is the TPU's default, and the fit is float32).
+# At HIGGS's 8,388,608 x 28 x 2 each reads X at 2.1-2.2 ms against the
+# 1.3 ms that reading the matrix takes (PERF.md, PR 37): no cheaper
+# formulation is worth a second code path.
+_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _margins(X, w, b):
+    """``X·w + b`` as ``(classes, rows)``: the long axis on the lanes."""
+    return jnp.einsum("fk,rf->kr", w, X, precision=_PRECISION) + b[:, None]
+
+
+def _weighted_columns(X, G):
+    """``Xᵀ·Gᵀ`` as ``(features, classes)`` for ``G`` of ``(classes, rows)``."""
+    return jnp.einsum("rf,kr->fk", X, G, precision=_PRECISION)
+
+
 def _loss_fn(params, X, y, mask, l2):
-    logits = X @ params["w"] + params["b"]
+    """The objective evaluated from the parameters: one pass over X."""
+    logits = jnp.dot(X, params["w"], precision=_PRECISION) + params["b"]
     log_probs = jax.nn.log_softmax(logits)
     nll = -jnp.take_along_axis(log_probs, y[:, None], axis=1)[:, 0]
     data_term = (nll * mask).sum() / mask.sum()
     return data_term + 0.5 * l2 * (params["w"] ** 2).sum()
+
+
+def _margin_loss(Z, hit, mask, count):
+    """Mean negative log-likelihood of margins ``Z`` (classes, rows);
+    ``hit`` is the one-hot of the labels in the same orientation.
+    Elementwise work and one reduction: no pass over X."""
+    lse = jax.nn.logsumexp(Z, axis=0)
+    picked = jnp.where(hit, Z, 0.0).sum(axis=0)
+    return ((lse - picked) * mask).sum() / count, lse
 
 
 # Hand-rolled L-BFGS (two-loop recursion, Armijo backtracking) instead
@@ -50,9 +84,12 @@ def _loss_fn(params, X, y, mask, l2):
 # strong-Wolfe zoom: 18.9 s -> ~6 s in round 3; 4 backtracking halvings
 # max, step floor 1/16: features are standardized so the unit step is
 # almost always accepted — caps 3/4/5/15 measured identical losses to
-# 5 decimals in round 4). One value_and_grad per ACCEPTED point (its
-# gradient is reused as the next iteration's), plus loss-only passes
-# for rejected trial steps. Quality is gated by the sklearn-oracle and
+# 5 decimals in round 4). The loss is linear in the parameters through
+# the margins, so a trial step t costs f(Z + t·M) on the carried
+# margins Z and the direction's margins M: two passes over X an
+# iteration (M, and the gradient at the accepted point) however many
+# trials the seed's data asks for (:func:`_matrix_passes` counts them
+# off the program). Quality is gated by the sklearn-oracle and
 # Titanic-golden accuracy tests.
 _LBFGS_MEMORY = 10
 _BACKTRACK_STEPS = 4
@@ -66,7 +103,7 @@ def _tree_dot(a, b):
     """Pytree inner product — one replicated scalar; on a sharded mesh
     XLA inserts the psums from the leaves' shardings."""
     return sum(
-        jnp.vdot(x, y)
+        jnp.vdot(x, y, precision=_PRECISION)
         for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b))
     )
 
@@ -97,12 +134,12 @@ def _lbfgs_state(params):
         "value": jnp.float32(0.0),  # f(x) at the current point
         "grad": jax.tree.map(jnp.zeros_like, params),
     }
-    # value/grad are (re)seeded at each segment's entry (_fit_segment)
-    # rather than lazily via a lax.cond inside the first iteration: the
-    # cond read nicely but under the sweep module's vmap-across-jobs a
-    # BATCHED cond executes both branches, paying a full extra
-    # value_and_grad pass every L-BFGS step; one seeding pass per
-    # segment (25+ iterations) costs ~4% instead
+    # value/grad are seeded by the first segment of a fit (``fresh``,
+    # a static argument of _fit_segment, chosen on the host) and then
+    # carried, rather than lazily via a lax.cond inside the first
+    # iteration: the cond read nicely but under the sweep module's
+    # vmap-across-jobs a BATCHED cond executes both branches, paying a
+    # full extra value_and_grad pass every L-BFGS step
 
 
 def _two_loop(state):
@@ -139,24 +176,87 @@ def _two_loop(state):
     return jax.tree.map(jnp.negative, r)
 
 
-def _fit_segment_impl(params, opt_state, X, y, mask, iters: int, l2):
+def _armijo_step(trial_loss, value, slope):
+    """Armijo backtracking as a while_loop that EXITS on acceptance:
+    ``(step, trials)``, the step 1, 1/2, 1/4 or 1/8 that first meets the
+    sufficient-decrease test, else the floor 1/16 after
+    ``_BACKTRACK_STEPS`` trials. ``trial_loss(t)`` is f at ``x + t·d``."""
+
+    def ls_cond(carry):
+        _, _, accepted, k = carry
+        return (~accepted) & (k < _BACKTRACK_STEPS)
+
+    def ls_body(carry):
+        t, best_t, _, k = carry
+        ok = trial_loss(t) <= value + _ARMIJO_C1 * t * slope
+        return (
+            t * 0.5,
+            jnp.where(ok, t, best_t),
+            ok,
+            k + 1,
+        )
+
+    _, best_t, _, trials = jax.lax.while_loop(
+        ls_cond,
+        ls_body,
+        (
+            jnp.float32(1.0),
+            jnp.float32(1.0 / (1 << _BACKTRACK_STEPS)),  # step floor
+            jnp.bool_(False),
+            jnp.int32(0),
+        ),
+    )
+    return best_t, trials
+
+
+def _fit_segment_impl(
+    params, opt_state, X, y, mask, iters: int, l2, fresh: bool = True
+):
     """``iters`` L-BFGS iterations as ONE program, optimizer state in
     and out — chained by :func:`_fit` so arbitrarily long optimizations
     never exceed a single execution's wall-clock budget while the
-    L-BFGS curvature memory carries across segment boundaries — the
-    same iteration sequence as the former single-scan program."""
-    loss = partial(_loss_fn, X=X, y=y, mask=mask, l2=l2)
-    value_and_grad = jax.value_and_grad(loss)
-    # seed (value, grad) at the segment's entry point: recomputing the
-    # carried pair is redundant-but-identical work once per segment,
-    # and it keeps every scan iteration branch-free (see _lbfgs_state)
-    value0, grad0 = value_and_grad(params)
-    opt_state = {**opt_state, "value": value0, "grad": grad0}
+    L-BFGS curvature memory, value and gradient carry across segment
+    boundaries.
+
+    The margins ``Z = X·W + b`` ride in the scan as ``(classes, rows)``,
+    the rows on the lanes, so an iteration reads X twice whatever its
+    line search does. They are not part of ``opt_state`` (a progress
+    artifact holds no row-sized array), so each segment seeds them from
+    ``X·W`` at entry, one more pass: a chain of segments follows the
+    single-program iteration sequence up to the float32 rounding of the
+    margins at each boundary. ``fresh`` (static): ``opt_state`` holds
+    no (value, grad) yet, so they are computed at entry too, the
+    gradient's pass; :func:`_fit` passes it for a fit's first segment
+    only."""
+    count = mask.sum()
+    hit = jnp.arange(params["b"].shape[-1])[:, None] == y[None, :]
+
+    def regularizer(w):
+        return 0.5 * l2 * (w ** 2).sum()
+
+    def value_and_grad(x, Z):
+        """f and its gradient at ``x`` from its margins: the gradient's
+        ``Xᵀ(softmax(Z) − Y)·mask / n`` is the one pass over X."""
+        data_term, lse = _margin_loss(Z, hit, mask, count)
+        G = (jnp.exp(Z - lse) - hit) * (mask / count)
+        grad = {
+            "w": _weighted_columns(X, G) + l2 * x["w"],
+            "b": G.sum(axis=1),
+        }
+        return data_term + regularizer(x["w"]), grad
+
+    # seed the margins at the segment's entry point, and (value, grad)
+    # where the state carries none; every scan iteration stays
+    # branch-free (see _lbfgs_state)
+    margins0 = _margins(X, params["w"], params["b"])
+    if fresh:
+        value0, grad0 = value_and_grad(params, margins0)
+        opt_state = {**opt_state, "value": value0, "grad": grad0}
 
     def step(carry, _):
-        x, state = carry
+        x, Z, state = carry
         # (value, grad) at x: seeded above for the first iteration,
-        # then carried from each accepted point's value_and_grad below
+        # then carried from each accepted point below
         value, grad = state["value"], state["grad"]
         direction = _two_loop(state)
         slope = _tree_dot(grad, direction)
@@ -167,40 +267,18 @@ def _fit_segment_impl(params, opt_state, X, y, mask, iters: int, l2):
             lambda d, g: jnp.where(descent, d, -g), direction, grad
         )
         slope = jnp.where(descent, slope, -_tree_dot(grad, grad))
+        # the direction's margins: the iteration's first pass over X
+        M = _margins(X, direction["w"], direction["b"])
 
-        # Armijo backtracking as a while_loop that EXITS on acceptance —
-        # standardized features accept the unit step almost always, so
-        # the typical iteration pays ONE loss pass here (a static unroll
-        # would pay all four trial passes every iteration), then ONE
-        # value_and_grad at the accepted point (its gradient is reused
-        # as the next iteration's).
-        def ls_cond(carry):
-            _, _, accepted, k = carry
-            return (~accepted) & (k < _BACKTRACK_STEPS)
+        def trial_loss(t):
+            data_term, _ = _margin_loss(Z + t * M, hit, mask, count)
+            return data_term + regularizer(x["w"] + t * direction["w"])
 
-        def ls_body(carry):
-            t, best_t, _, k = carry
-            trial = loss(_tree_axpy(t, direction, x))
-            ok = trial <= value + _ARMIJO_C1 * t * slope
-            return (
-                t * 0.5,
-                jnp.where(ok, t, best_t),
-                ok,
-                k + 1,
-            )
-
-        _, best_t, _, _ = jax.lax.while_loop(
-            ls_cond,
-            ls_body,
-            (
-                jnp.float32(1.0),
-                jnp.float32(1.0 / (1 << _BACKTRACK_STEPS)),  # step floor
-                jnp.bool_(False),
-                jnp.int32(0),
-            ),
-        )
+        best_t, _ = _armijo_step(trial_loss, value, slope)
         x_new = _tree_axpy(best_t, direction, x)
-        value_new, grad_new = value_and_grad(x_new)
+        Z_new = Z + best_t * M
+        # the gradient at the accepted point: the second pass over X
+        value_new, grad_new = value_and_grad(x_new, Z_new)
 
         # curvature pair; the update is skipped when s·y is not positive
         s = jax.tree.map(jnp.subtract, x_new, x)
@@ -236,26 +314,90 @@ def _fit_segment_impl(params, opt_state, X, y, mask, iters: int, l2):
             "value": value_new,
             "grad": grad_new,
         }
-        return (x_new, state), value
+        return (x_new, Z_new, state), value
 
-    (params, opt_state), losses = jax.lax.scan(
-        step, (params, opt_state), length=iters
+    (params, _, opt_state), losses = jax.lax.scan(
+        step, (params, margins0, opt_state), length=iters
     )
     return params, opt_state, losses
 
 
 # The shared, undonated program: what ml/sweep.py vmaps (donation inside
 # an outer trace would be inert) and what CPU backends run.
-_fit_segment = partial(jax.jit, static_argnames=("iters",))(_fit_segment_impl)
+_fit_segment = partial(jax.jit, static_argnames=("iters", "fresh"))(
+    _fit_segment_impl
+)
 
 
 @lru_cache(maxsize=None)
 def _donated_fit_segment():
     return jax.jit(
         _fit_segment_impl,
-        static_argnames=("iters",),
+        static_argnames=("iters", "fresh"),
         donate_argnums=(0, 1),
     )
+
+
+def _contractions_over(jaxpr, sources) -> int:
+    """How many times the program ``jaxpr`` contracts one of
+    ``sources`` (variables of ``jaxpr``): each ``dot_general`` that
+    reads one, times the trips of the loops round it — a ``scan`` its
+    ``length``, a ``while`` (the line search, the one such loop here)
+    at most ``_BACKTRACK_STEPS``, a ``cond`` its costliest branch."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        reads = [v in sources for v in eqn.invars if isinstance(v, Var)]
+        if name == "dot_general":
+            count += any(reads)
+            continue
+        if not any(reads):
+            continue
+        params = eqn.params
+        if name == "scan":
+            inner = [(params["jaxpr"], eqn.invars, params["length"])]
+        elif name == "while":
+            inner = [(
+                params["body_jaxpr"],
+                eqn.invars[params["cond_nconsts"]:],
+                _BACKTRACK_STEPS,
+            )]
+        elif name == "cond":
+            inner = [(b, eqn.invars[1:], 1) for b in params["branches"]]
+        else:  # jit and the other calls: arguments map one to one
+            inner = [
+                (v, eqn.invars, 1)
+                for v in params.values()
+                if isinstance(v, ClosedJaxpr)
+                and len(v.jaxpr.invars) == len(eqn.invars)
+            ]
+        counts = [
+            times * _contractions_over(
+                sub.jaxpr,
+                {
+                    inner_var
+                    for outer, inner_var in zip(args, sub.jaxpr.invars)
+                    if isinstance(outer, Var) and outer in sources
+                },
+            )
+            for sub, args, times in inner
+        ]
+        count += max(counts, default=0)
+    return count
+
+
+def _matrix_passes(segment, params, opt_state, X, y, mask, iters, l2, fresh):
+    """The passes over the training matrix that one call of the jitted
+    ``segment`` makes on the device, counted off its traced program
+    (the trace the call itself uses: no compile); 0 for a function that
+    is not jitted (a test's scripted segment)."""
+    if not hasattr(segment, "trace"):
+        return 0
+    closed = segment.trace(
+        params, opt_state, X, y, mask, iters, l2, fresh=fresh
+    ).jaxpr
+    at = len(jax.tree.leaves((params, opt_state)))
+    return _contractions_over(closed.jaxpr, {closed.jaxpr.invars[at]})
 
 
 def _fit_segment_runner():
@@ -342,6 +484,7 @@ def _fit(params, X, y, mask, max_iter: int, l2, tol: float = _LR_TOL):
     window = _LR_STOP_DELTAS + 1
     segment = _fit_segment_runner()
     total_segments = max_iter // iters
+    passes = 0
     # Crash resume: a sink bound by ml/builder.py means this fit should
     # persist per-segment progress and pick up any prior run's artifact.
     # The artifact must match this call's segmentation exactly (iters /
@@ -382,11 +525,16 @@ def _fit(params, X, y, mask, max_iter: int, l2, tol: float = _LR_TOL):
         # more segment here would break bit-identity
         if tol > 0 and _plateaued(history, tol, window):
             break
+        # the state carries (value, grad) but in a fit's first segment
+        fresh = index == 0
+        passes += _matrix_passes(
+            segment, params, opt_state, X, y, mask, iters, l2, fresh
+        )
         # dispatch to the host sync that fetches the losses (with tol
         # off nothing syncs, and the span holds the dispatch alone)
         with _tracing.span("fit:segment", iters=iters):
             params, opt_state, segment_losses = segment(
-                params, opt_state, X, y, mask, iters, l2
+                params, opt_state, X, y, mask, iters, l2, fresh=fresh
             )
             losses.append(segment_losses)
             if tol > 0:
@@ -412,45 +560,60 @@ def _fit(params, X, y, mask, max_iter: int, l2, tol: float = _LR_TOL):
                     "history": list(history),
                 },
             )
+    if passes:
+        _tracing.add_attr("lbfgs_matrix_passes", passes)
     with _tracing.span("fit:enqueue"):
         joined = jnp.concatenate(losses) if len(losses) > 1 else losses[0]
     return params, joined
 
 
-@jax.jit
-def _masked_stats(X, mask):
-    """Per-feature mean/scale from a row-sharded matrix + validity mask —
-    the standardization step computed ON DEVICE, so a fit can start from
-    per-host-fed shards without any host ever holding the full dataset.
-    The reductions cross the data axis; XLA inserts the psums."""
-    weights = mask.astype(X.dtype)
+def _lr_standardize(X, mask):
+    """Standardise a padded, row-sharded matrix on the device, ONE
+    program (three passes): per-feature mean and
+    scale of the valid rows (zero-variance features pinned to 1), and
+    ``(X - mean) / scale`` with the padding rows zeroed. The recipe of
+    every LR fit — solo, sharded and the sweep's members — so the paths
+    cannot drift. The reductions cross the data axis; XLA inserts the
+    psums.
+
+    The mean is corrected by the second pass (the sum of the deviations
+    from the first estimate), so a column far from zero keeps float32's
+    precision relative to its spread; a constant column (min = max)
+    takes its value as the mean exactly, and so reads scale 1."""
+    weights = mask.astype(X.dtype)[:, None]
+    valid = weights > 0
     count = weights.sum()
-    mean = (X * weights[:, None]).sum(axis=0) / count
-    var = ((X - mean) ** 2 * weights[:, None]).sum(axis=0) / count
-    std = jnp.sqrt(var)
-    return mean, jnp.where(std > 0, std, 1.0)
+    lo = jnp.where(valid, X, jnp.inf).min(axis=0)
+    hi = jnp.where(valid, X, -jnp.inf).max(axis=0)
+    guess = jnp.where(lo == hi, lo, (X * weights).sum(axis=0) / count)
+    deviation = (X - guess) * weights
+    shift = deviation.sum(axis=0) / count
+    var = (deviation ** 2).sum(axis=0) / count - shift ** 2
+    std = jnp.sqrt(jnp.maximum(var, 0.0))
+    mean = guess + shift
+    scale = jnp.where(std > 0, std, 1.0)
+    return ((X - mean) / scale) * weights, mean, scale
 
 
-@jax.jit
-def _standardize(X, mean, scale, weights):
-    return ((X - mean) / scale) * weights[:, None]
+_standardize = jax.jit(_lr_standardize)
+
+
+@lru_cache(maxsize=None)
+def _donated_standardize():
+    """The program :meth:`LogisticRegression.fit` runs on a matrix it
+    has just copied to the device itself: that copy is its own, so it
+    is DONATED and the standardised matrix takes its HBM."""
+    return jax.jit(_lr_standardize, donate_argnums=(0,))
 
 
 @jax.jit
 def _forward(params, X, mean, scale):
-    logits = ((X - mean) / scale) @ params["w"] + params["b"]
+    logits = (
+        jnp.dot((X - mean) / scale, params["w"], precision=_PRECISION)
+        + params["b"]
+    )
     probs = jax.nn.softmax(logits)
     return jnp.argmax(logits, axis=1), probs
-
-
-def scaler_stats(X: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """The host-side standardization scaler (float64 mean, std with
-    zero-variance features pinned to 1) — ONE recipe shared by
-    :meth:`LogisticRegression.fit` and the batched sweep prep
-    (ml/sweep.py), so the solo and fused paths can never drift."""
-    mean = np.asarray(X, np.float64).mean(axis=0)
-    std = np.asarray(X, np.float64).std(axis=0)
-    return mean, np.where(std > 0, std, 1.0)
 
 
 class LogisticRegressionModel(FittedModel):
@@ -482,20 +645,20 @@ class LogisticRegression:
     def fit(self, X: np.ndarray, y: np.ndarray) -> LogisticRegressionModel:
         num_classes = infer_num_classes(y)
         # Standardize for conditioning (MLlib standardizes internally
-        # too); the scaler is part of the fitted model.
-        rows, features = np.shape(X)
+        # too), on the device; the scaler is part of the fitted model.
+        # a DeviceMatrix is the caller's (shared by the build's models,
+        # or cached): scaled into a new buffer; a copy made here is
+        # scaled in place
+        shared = isinstance(X, DeviceMatrix)
+        rows, features = len(X), np.shape(X.data if shared else X)[1]
+        standardize = _standardize if shared else _donated_standardize()
+        X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
+        # the span holds the dispatch; the program's device seconds are
+        # its own (jit__lr_standardize)
         with _tracing.span("fit:standardize", rows=rows, features=features):
-            mean, scale = scaler_stats(X)
-            X_std = (np.asarray(X) - mean) / scale
-        X_dev, y_dev, mask = prepare_xy(X_std, y, self.mesh)
-        return self._fit_prepared(
-            X_dev,
-            y_dev,
-            mask,
-            num_classes,
-            jnp.asarray(mean, jnp.float32),
-            jnp.asarray(scale, jnp.float32),
-        )
+            X_std, mean, scale = standardize(X_dev, mask)
+        del X_dev  # donated where it was this fit's own copy
+        return self._fit_prepared(X_std, y_dev, mask, num_classes, mean, scale)
 
     def fit_sharded(
         self,
@@ -510,19 +673,12 @@ class LogisticRegression:
         slice and NO process ever materializes the full dataset (the
         100M-row ingestion story; reference workers instead each read
         their Mongo partitions). Standardization happens on device from
-        the shards (:func:`_masked_stats`); ``num_classes`` must be given
-        since no host can scan all labels.
+        the shards (:func:`_lr_standardize`; the caller's arrays are left
+        as they are); ``num_classes`` must be given since no host can
+        scan all labels.
         """
-        mean, scale = _masked_stats(X_dev, mask)
-        X_std = _standardize(X_dev, mean, scale, mask.astype(X_dev.dtype))
-        return self._fit_prepared(
-            X_std,
-            y_dev,
-            mask,
-            num_classes,
-            mean.astype(jnp.float32),
-            scale.astype(jnp.float32),
-        )
+        X_std, mean, scale = _standardize(X_dev, mask)
+        return self._fit_prepared(X_std, y_dev, mask, num_classes, mean, scale)
 
     def _fit_prepared(
         self, X_dev, y_dev, mask, num_classes, mean, scale

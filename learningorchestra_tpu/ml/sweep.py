@@ -56,7 +56,7 @@ from learningorchestra_tpu.ml.logistic import (
     _fit_segment,
     _forward,
     _lbfgs_state,
-    scaler_stats,
+    _lr_standardize,
 )
 from learningorchestra_tpu.ml.trees import (
     _dt_fit,
@@ -198,14 +198,7 @@ def prepare_member(
         "num_classes": num_classes,
         "max_iter": int(max_iter),
     }
-    if kind == "lr":
-        # the solo fit's scaler recipe (logistic.scaler_stats, shared
-        # so the paths cannot drift) — λ never changes it, so it is
-        # per-member, not per-point
-        mean, scale = scaler_stats(X_train)
-        payload["mean"] = mean.astype(np.float32)
-        payload["scale"] = scale.astype(np.float32)
-    else:
+    if kind != "lr":  # LR standardises on the device (_run_lr_chunk)
         payload["thresholds"] = make_thresholds(X_train, MAX_BINS).astype(
             np.float32
         )
@@ -233,16 +226,22 @@ def _mesh_signature(mesh: Mesh) -> tuple:
 # The fused programs
 # --------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("iters",))
-def _lr_fused_segment(params, states, Xs, ys, masks, l2s, iters: int):
+@partial(jax.jit, static_argnames=("iters", "fresh"))
+def _lr_fused_segment(
+    params, states, Xs, ys, masks, l2s, iters: int, fresh: bool
+):
     """``iters`` L-BFGS iterations for EVERY slice of the job axis as
     one program — the solo fit's segment (ml/logistic.py) under vmap,
-    optimizer state carried per slice across segment boundaries."""
+    optimizer state carried per slice across segment boundaries
+    (``fresh``: the first segment, which seeds value and gradient)."""
 
     def one(p, s, X, y, m, l2):
-        return _fit_segment(p, s, X, y, m, iters, l2)
+        return _fit_segment(p, s, X, y, m, iters, l2, fresh=fresh)
 
     return jax.vmap(one)(params, states, Xs, ys, masks, l2s)
+
+
+_standardize_members = jax.jit(jax.vmap(_lr_standardize))
 
 
 @partial(jax.jit, static_argnames=("num_classes",))
@@ -419,12 +418,11 @@ def _run_lr_chunk(payloads, chunk, per_point, mesh) -> None:
     ):
         Xs = _stack([payloads[m]["X"] for m in members], sharding)
         ys = _stack([payloads[m]["y"] for m in members], sharding)
-        # standardized per slice ON DEVICE from the per-member scaler
-        # (λ shares one standardization; members each carry their own)
-        means = _stack([payloads[m]["mean"] for m in members], sharding)
-        scales = _stack([payloads[m]["scale"] for m in members], sharding)
         masks = _stack([payloads[m]["mask"] for m in members], sharding)
-        Xs = (Xs - means[:, None, :]) / scales[:, None, :]
+        # standardized per slice ON DEVICE by the solo fit's recipe
+        # (logistic._lr_standardize, so the paths cannot drift): λ
+        # shares one standardization, members each get their own
+        Xs, means, scales = _standardize_members(Xs, masks)
         params = jax.device_put(
             {
                 "w": jnp.zeros((padded, features, num_classes), jnp.float32),
@@ -441,9 +439,9 @@ def _run_lr_chunk(payloads, chunk, per_point, mesh) -> None:
             max_iter, first["X"].shape[0] * padded, _LR_ROW_ITERS_BUDGET,
             features,
         )
-        for _ in range(max(1, max_iter // iters)):
+        for index in range(max(1, max_iter // iters)):
             params, states, _ = _lr_fused_segment(
-                params, states, Xs, ys, masks, l2_dev, iters
+                params, states, Xs, ys, masks, l2_dev, iters, index == 0
             )
         Xe = _stack([payloads[m]["X_eval"] for m in members], sharding)
         ye = _stack([payloads[m]["y_eval"] for m in members], sharding)
@@ -452,8 +450,11 @@ def _run_lr_chunk(payloads, chunk, per_point, mesh) -> None:
             params, Xe, means, scales, ye, we, num_classes
         )
         # ONE host transfer for the whole chunk's params + metrics
-        w_host, b_host, acc_host, f1_host = jax.device_get(
-            (params["w"], params["b"], accuracy, weighted_f1)
+        w_host, b_host, mean_host, scale_host, acc_host, f1_host = (
+            jax.device_get(
+                (params["w"], params["b"], means, scales, accuracy,
+                 weighted_f1)
+            )
         )
     for i, (member, point) in enumerate(chunk):
         per_point[(member, point)] = {
@@ -463,8 +464,8 @@ def _run_lr_chunk(payloads, chunk, per_point, mesh) -> None:
                 "kind": "lr",
                 "w": np.asarray(w_host[i]),
                 "b": np.asarray(b_host[i]),
-                "mean": payloads[member]["mean"],
-                "scale": payloads[member]["scale"],
+                "mean": np.asarray(mean_host[i]),
+                "scale": np.asarray(scale_host[i]),
             },
         }
 

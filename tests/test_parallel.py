@@ -169,6 +169,7 @@ class TestSegmentSteps:
     def test_segmented_lr_matches_single_program(self, monkeypatch):
         # 12 iterations in 3 segments == 12 in one program: the carried
         # optimizer state makes segmentation invisible to the result
+        # but for the rounding of the margins (below)
         import jax.numpy as jnp
 
         from learningorchestra_tpu.ml import logistic
@@ -183,15 +184,30 @@ class TestSegmentSteps:
             "w": jnp.zeros((4, 2), jnp.float32),
             "b": jnp.zeros((2,), jnp.float32),
         }
-        single, _ = logistic._fit(params, X_dev, y_dev, mask, 12, jnp.float32(0.0))
+        single, single_losses = logistic._fit(
+            params, X_dev, y_dev, mask, 12, jnp.float32(0.0)
+        )
         # budget that yields 4-iteration segments at 64 rows x 4 features
         monkeypatch.setattr(logistic, "_LR_ROW_ITERS_BUDGET", 64.0)
         from learningorchestra_tpu.ml.base import segment_steps
 
         assert segment_steps(12, 64, 64.0, features=4) == 4
-        segmented, _ = logistic._fit(params, X_dev, y_dev, mask, 12, jnp.float32(0.0))
+        segmented, segmented_losses = logistic._fit(
+            params, X_dev, y_dev, mask, 12, jnp.float32(0.0)
+        )
+        # the carried state holds value and gradient; the margins X·W + b
+        # are not in it (ISSUE 37: seeded at each segment's entry), so a
+        # segment's margins are X·W where one program has Z + t·M: the
+        # two differ by their float32 rounding alone (7.8e-6 at most on
+        # this separable problem, whose weights grow to 18)
         np.testing.assert_allclose(
-            np.asarray(single["w"]), np.asarray(segmented["w"]), rtol=1e-5
+            np.asarray(single_losses), np.asarray(segmented_losses), atol=1e-6
+        )
+        np.testing.assert_allclose(
+            np.asarray(single["w"]),
+            np.asarray(segmented["w"]),
+            rtol=1e-5,
+            atol=1e-5,
         )
 
     def test_lr_tol_stops_early_and_matches_quality(self):

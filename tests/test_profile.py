@@ -301,7 +301,8 @@ class TestBuildSpanTree:
             child["name"] for child in fit["children"]
             if not child["name"].startswith("compile:")
         ]
-        assert children[:3] == ["fit:standardize", "h2d:train", "fit:enqueue"]
+        # the raw float32 matrix crosses, then is standardised there
+        assert children[:3] == ["h2d:train", "fit:standardize", "fit:enqueue"]
         segments = [
             child for child in fit["children"]
             if child["name"] == "fit:segment"
@@ -310,10 +311,16 @@ class TestBuildSpanTree:
         # what is enqueued outside the segments, then the wait
         assert children[-2:] == ["fit:enqueue", "fit:device_wait"]
         # the iteration count on the fit is the segments' own
-        assert fit["meta"]["lbfgs_iterations"] == sum(
+        iterations = fit["meta"]["lbfgs_iterations"]
+        assert iterations == sum(
             segment["meta"]["iters"] for segment in segments
         )
-        assert 0 < fit["meta"]["lbfgs_iterations"] <= 100
+        assert 0 < iterations <= 100
+        # the passes over the matrix: the margins at each segment's
+        # entry, the gradient at the first's, two an iteration
+        assert fit["meta"]["lbfgs_matrix_passes"] == (
+            len(segments) + 1 + 2 * iterations
+        )
 
     @pytest.mark.parametrize("classifier", FIVE)
     def test_fit_children_never_outlast_the_fit(

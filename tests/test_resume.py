@@ -238,6 +238,55 @@ class TestLogisticResumeBitIdentity:
             ):
                 np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
+    @pytest.mark.parametrize("rows", ["separable", "offset"])
+    def test_resumed_fit_reseeds_its_margins_bit_identically(
+        self, tmp_path, rows
+    ):
+        """The margins ride in the segment program and are seeded from
+        W at each segment's entry, so a fit killed after segment 1 and
+        resumed from the artifact (params and optimizer state only)
+        lands on the uninterrupted fit's params to the bit — on rows
+        whose line search backtracks (separable labels) and on a
+        column far from zero that the device standardises."""
+        import jax
+
+        from learningorchestra_tpu.ml.logistic import LogisticRegression
+
+        rng = np.random.default_rng(13)
+        X = rng.normal(size=(200, 4))
+        if rows == "separable":
+            y = (X[:, 0] > 0).astype(np.int64)
+        else:
+            X[:, 2] += 1e4
+            y = (X[:, 0] + rng.normal(size=200) > 0).astype(np.int64)
+        make = lambda: LogisticRegression(max_iter=75, tol=0.0)  # noqa: E731
+
+        path = str(tmp_path / "m.progress")
+        aside = str(tmp_path / "segment1.progress")
+
+        def record(segment: int) -> None:
+            if segment == 1:
+                shutil.copyfile(path, aside)
+
+        with bind_sink(ProgressSink(path, dict(META), on_segment=record)):
+            uninterrupted = make().fit(X, y)
+        shutil.copyfile(aside, path)
+        skipped_before = _counter_value("lo_build_segments_skipped_total")
+        with bind_sink(ProgressSink(path, dict(META))):
+            resumed = make().fit(X, y)
+        assert (
+            _counter_value("lo_build_segments_skipped_total") - skipped_before
+            == 1
+        )
+        for got, want in zip(
+            jax.tree.leaves(resumed.params),
+            jax.tree.leaves(uninterrupted.params),
+        ):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(
+            np.asarray(resumed.mean), np.asarray(uninterrupted.mean)
+        )
+
     def test_stale_artifact_restarts_clean(self, tmp_path):
         """A rev-mismatched artifact must be deleted and the fit rerun
         from scratch — never resumed into a silently-wrong model."""
