@@ -15,12 +15,15 @@ Design notes (TPU-first):
 from __future__ import annotations
 
 import os
+import threading
+from concurrent.futures import Future
 from typing import Optional
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from learningorchestra_tpu.ml import binning
 from learningorchestra_tpu.parallel.mesh import default_mesh
 from learningorchestra_tpu.parallel.sharding import shard_rows
 from learningorchestra_tpu.telemetry import tracing as _tracing
@@ -103,7 +106,8 @@ class DeviceMatrix:
     classifier predicts against the same device buffers —
     ``prepare_xy`` passes them straight through, so N models cost one
     host→device transfer, not N (the tail the reference pays per
-    evaluator, model_builder.py:205-224)."""
+    evaluator, model_builder.py:205-224). The training matrix the N
+    models fit from is one too (:class:`TrainingInput`)."""
 
     __slots__ = ("data", "mask", "rows", "mesh")
 
@@ -143,6 +147,127 @@ def shard_labels(y: np.ndarray, mesh: Optional[Mesh] = None) -> DeviceLabels:
     y = np.asarray(y)
     y_dev, _ = shard_rows(y, mesh, dtype=np.int32)
     return DeviceLabels(y_dev, infer_num_classes(y), mesh)
+
+
+class TrainingInput:
+    """What every fit of a build derives from its training frame, made
+    once and shared by the build's fits: the host matrix and labels,
+    their class count, ONE padded, row-sharded device copy of both
+    (:meth:`device`) and the bin thresholds of that copy, one table per
+    ``max_bins`` (:meth:`thresholds`).
+
+    The device copy and each table are made by the first fit that asks
+    for them; the fits that ask meanwhile wait for that result, or its
+    error, after which nothing is kept and the next ask tries again. A
+    fit handed a numpy matrix makes an input of its own (:meth:`of`),
+    so a library call and a build run the same code. The shared arrays
+    are read-only to the fits: none donates or writes them.
+
+    Deliberately not in the content-addressed device cache
+    (core/devcache.py): each build ships its training matrix once and
+    the builder lets the copy go when its fits are done
+    (:meth:`release`). Users rebuild after changing the coding, so a
+    build's matrix is new content; hashing it would put a linear host
+    pass back on the build's path, and a kept copy would hold HBM
+    between builds."""
+
+    def __init__(self, X, y, mesh: Optional[Mesh] = None):
+        self.X = np.asarray(X)
+        self.y = np.asarray(y)
+        self.mesh = resolve_mesh(mesh)
+        self.num_classes = infer_num_classes(self.y)
+        self._lock = threading.Lock()
+        self._made: dict = {}
+
+    @classmethod
+    def of(cls, X, y, mesh: Mesh) -> "TrainingInput":
+        """The input a fit on ``mesh`` works from: ``X`` itself where it
+        is one (on another mesh, its host arrays again), else the input
+        of the host matrix ``X`` and labels ``y``."""
+        if isinstance(X, TrainingInput):
+            return X if X.mesh is mesh else cls(X.X, X.y, mesh)
+        return cls(X, y, mesh)
+
+    @property
+    def rows(self) -> int:
+        return len(self.X)
+
+    @property
+    def features(self) -> int:
+        return self.X.shape[1]
+
+    def __len__(self) -> int:
+        return self.rows
+
+    def __getitem__(self, rows) -> "TrainingInput":
+        """The input of some of these rows, with a device copy of its
+        own: for a wrapper that hands a fit part of a build's rows."""
+        return TrainingInput(self.X[rows], self.y[rows], self.mesh)
+
+    def _claim(self, key) -> tuple[Future, bool]:
+        """The future that holds ``key``'s result, and whether the
+        caller is the one to make it."""
+        with self._lock:
+            made = self._made.get(key)
+            if made is not None:
+                return made, False
+            made = self._made[key] = Future()
+            return made, True
+
+    def _settle(self, key, made: Future, make):
+        try:
+            value = make()
+        except BaseException as error:
+            with self._lock:
+                self._made.pop(key, None)
+            made.set_exception(error)
+            raise
+        made.set_result(value)
+        return value
+
+    def device(self) -> tuple[DeviceMatrix, DeviceLabels]:
+        """The device copy of the matrix and the labels. The fit that
+        makes it does so under one ``h2d:train`` span (``rows``,
+        ``copies`` 1, the bytes ``shard_rows`` stamps), which ends when
+        the arrays have landed; the others wait under ``fit:input``."""
+        made, mine = self._claim("device")
+        if not mine:
+            with _tracing.span("fit:input", rows=self.rows):
+                return made.result()
+        return self._settle("device", made, self._copy)
+
+    def _copy(self) -> tuple[DeviceMatrix, DeviceLabels]:
+        with _tracing.span("h2d:train", rows=self.rows, copies=1):
+            matrix = shard_matrix(self.X, self.mesh)
+            labels = shard_labels(self.y, self.mesh)
+            jax.block_until_ready((matrix.data, matrix.mask, labels.data))
+        return matrix, labels
+
+    def thresholds(
+        self, matrix: DeviceMatrix, max_bins: int
+    ) -> tuple[np.ndarray, int]:
+        """``(thresholds, passes)``: the quantile thresholds of
+        ``matrix``, this input's device copy, for ``max_bins`` bins
+        (read-only). The fit that runs the device pass gets ``passes``
+        1, the ones that wait for it 0."""
+
+        def run() -> np.ndarray:
+            thresholds = binning.device_thresholds(
+                matrix.data, matrix.mask, max_bins
+            )
+            thresholds.flags.writeable = False
+            return thresholds
+
+        made, mine = self._claim(("thresholds", max_bins))
+        if not mine:
+            return made.result(), 0
+        return self._settle(("thresholds", max_bins), made, run), 1
+
+    def release(self) -> None:
+        """Let the device copy go (its HBM is freed with the last
+        reference); a later :meth:`device` makes it again."""
+        with self._lock:
+            self._made.pop("device", None)
 
 
 def prepare_xy(

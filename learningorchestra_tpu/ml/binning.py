@@ -14,16 +14,12 @@ Assignment (``apply_bins``) counts, per value, its feature's thresholds
 below it: one fused elementwise program, no gather and no loop.
 
 The thresholds are made on the device too, from the float32 matrix a
-fit has put there (``device_thresholds``): exact order statistics by
+build has put there (``device_thresholds``): exact order statistics by
 rank selection, interpolated on the host as numpy does. The tree fits
-of one build share one pass (``shared_thresholds``).
+of one build share one pass (``ml/base.TrainingInput.thresholds``).
 """
 
 from __future__ import annotations
-
-import threading
-import weakref
-from concurrent.futures import Future
 
 import jax
 import jax.numpy as jnp
@@ -165,47 +161,6 @@ def make_thresholds(X: np.ndarray, max_bins: int = MAX_BINS) -> np.ndarray:
     """
     X_dev = jnp.asarray(np.asarray(X), jnp.float32)
     return device_thresholds(X_dev, jnp.ones(X_dev.shape[0], bool), max_bins)
-
-
-# Thresholds a fit has made, for the other fits of the same build: keyed
-# by the host matrix's identity, dropped when the matrix is collected.
-_shared: dict[tuple, Future] = {}
-_shared_lock = threading.Lock()
-
-
-def shared_thresholds(
-    X, X_dev: jax.Array, mask: jax.Array, max_bins: int, mesh
-) -> tuple[np.ndarray, int]:
-    """``(thresholds, passes)`` for a fit whose host matrix ``X`` is on
-    the device as ``(X_dev, mask)``. The first fit to ask for a given
-    host array, ``max_bins`` and mesh runs :func:`device_thresholds`
-    (``passes`` 1); every other one, while that array lives, waits for
-    and takes the same result (``passes`` 0), or the first one's error,
-    after which nothing is kept. An input that is no numpy array is
-    not shared. The array is taken to hold the same values for as long
-    as it is the same object.
-    """
-    if not isinstance(X, np.ndarray):
-        return device_thresholds(X_dev, mask, max_bins), 1
-    key = (id(X), max_bins, mesh)
-    with _shared_lock:
-        result = _shared.get(key)
-        first = result is None
-        if first:
-            result = _shared[key] = Future()
-            weakref.finalize(X, _shared.pop, key, None)
-    if not first:
-        return result.result(), 0
-    try:
-        thresholds = device_thresholds(X_dev, mask, max_bins)
-        thresholds.flags.writeable = False
-    except BaseException as error:
-        with _shared_lock:
-            _shared.pop(key, None)
-        result.set_exception(error)
-        raise
-    result.set_result(thresholds)
-    return thresholds, 1
 
 
 @jax.jit

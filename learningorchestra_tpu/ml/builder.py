@@ -32,7 +32,11 @@ from learningorchestra_tpu.core.table import insert_columns_batched
 from learningorchestra_tpu.frame.dataframe import DataFrame
 from learningorchestra_tpu.frame.pyspark_compat import run_preprocessor
 from learningorchestra_tpu.ml import progress as _progress
-from learningorchestra_tpu.ml.base import CLASSIFIER_NAMES, make_classifier
+from learningorchestra_tpu.ml.base import (
+    CLASSIFIER_NAMES,
+    TrainingInput,
+    make_classifier,
+)
 from learningorchestra_tpu.sched import cancel as _cancel
 from learningorchestra_tpu.sched import config as _sched_config
 from learningorchestra_tpu.sched.cancel import JobCancelledError, check_cancelled
@@ -197,7 +201,7 @@ def _prediction_columns(predicted_df: DataFrame) -> dict[str, Column]:
 def train_one(
     store: DocumentStore,
     classificator_name: str,
-    features_training: DataFrame,
+    training: TrainingInput,
     features_testing: DataFrame,
     features_evaluation: Optional[DataFrame],
     prediction_filename: str,
@@ -221,6 +225,10 @@ def train_one(
     every process of a multi-host mesh) but skips the store writes: SPMD
     worker processes pass False so the shared store sees exactly one
     writer (parallel/spmd.py).
+
+    ``training`` is the build's training matrix and labels, shared by
+    its fits: one device copy, made by the first fit that asks
+    (:class:`~learningorchestra_tpu.ml.base.TrainingInput`).
 
     ``models_dir`` (or ``LO_MODELS_DIR``) persists the fitted model as a
     checkpoint named after the prediction collection, recorded in the
@@ -249,21 +257,19 @@ def train_one(
     # mid-collective-stream poisons the dispatcher like any mid-job
     # failure, and the supervisor restarts the runtime).
     check_cancelled()
-    X_train = features_training.feature_matrix(FEATURES_COL)
-    y_train = features_training.label_vector(LABEL_COL)
-
     classifier = make_classifier(classificator_name, mesh=mesh)
     _faults.fire(
         "builder.phase", phase="fit", classificator=classificator_name
     )
     # dtype rides the phase attrs so a trace says which LO_DTYPE_POLICY
     # (f32 vs bf16 feature matrices) produced these numbers
-    with timer.phase("fit", rows=len(X_train), dtype=dtype_policy()):
+    with timer.phase("fit", rows=training.rows, dtype=dtype_policy()):
         # the rendezvous guard serializes the whole dispatch+drain on a
         # single-process CPU backend (see _CPU_RENDEZVOUS_LOCK); a
         # no-op on real accelerators and under multi-process SPMD
         with _collective_dispatch_guard(), _progress.bind_sink(sink):
-            model = classifier.fit(X_train, y_train)
+            # the labels ride along too: the (X, y) contract of every fit
+            model = classifier.fit(training, training.y)
             # drain the async dispatch queue inside the fit phase:
             # without this the device time lands on whichever later
             # call blocks first, and "evaluate"/"predict" report the
@@ -316,7 +322,7 @@ def train_one(
             from learningorchestra_tpu import compile as lo_compile
 
             lo_compile.checkpoint_published(
-                artifact, features=int(X_train.shape[1])
+                artifact, features=training.features
             )
 
     prediction = None
@@ -713,6 +719,14 @@ def _build_model_traced(
     )
     writer = PredictionWriter() if overlap else None
     resume_done = resume_done or {}
+    # the training matrix and labels once for every fit of the build:
+    # its device copy is made by the first fit that asks (none where
+    # every fit is resumed) and let go when the fits are done
+    training = TrainingInput(
+        out["features_training"].feature_matrix(FEATURES_COL),
+        out["features_training"].label_vector(LABEL_COL),
+        mesh,
+    )
 
     def run_train(name: str) -> dict:
         with _tracing.attach(context), _cancel.bind(cancel_token):
@@ -754,7 +768,7 @@ def _build_model_traced(
                 return train_one(
                     store,
                     name,
-                    out["features_training"],
+                    training,
                     out["features_testing"],
                     out["features_evaluation"],
                     test_filename,
@@ -776,6 +790,7 @@ def _build_model_traced(
             ]
             wait([future for _, future in futures])
     finally:
+        training.release()
         # End-of-job barrier: no build returns (or fails) with writes
         # still in flight; a failed write-back fails that MEMBER.
         write_failures = writer.barrier() if writer is not None else []

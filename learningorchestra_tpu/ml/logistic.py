@@ -30,9 +30,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from learningorchestra_tpu.parallel.mesh import MODEL_AXIS, model_size
 from learningorchestra_tpu.ml import progress as _progress
 from learningorchestra_tpu.ml.base import (
-    DeviceMatrix,
     FittedModel,
-    infer_num_classes,
+    TrainingInput,
     prepare_xy,
     resolve_mesh,
 )
@@ -598,14 +597,6 @@ def _lr_standardize(X, mask):
 _standardize = jax.jit(_lr_standardize)
 
 
-@lru_cache(maxsize=None)
-def _donated_standardize():
-    """The program :meth:`LogisticRegression.fit` runs on a matrix it
-    has just copied to the device itself: that copy is its own, so it
-    is DONATED and the standardised matrix takes its HBM."""
-    return jax.jit(_lr_standardize, donate_argnums=(0,))
-
-
 @jax.jit
 def _forward(params, X, mean, scale):
     logits = (
@@ -642,23 +633,22 @@ class LogisticRegression:
         self.tol = tol  # MLlib's user-settable convergence tolerance
         self.mesh = resolve_mesh(mesh)
 
-    def fit(self, X: np.ndarray, y: np.ndarray) -> LogisticRegressionModel:
-        num_classes = infer_num_classes(y)
+    def fit(self, X, y=None) -> LogisticRegressionModel:
+        data = TrainingInput.of(X, y, self.mesh)
+        matrix, labels = data.device()
         # Standardize for conditioning (MLlib standardizes internally
         # too), on the device; the scaler is part of the fitted model.
-        # a DeviceMatrix is the caller's (shared by the build's models,
-        # or cached): scaled into a new buffer; a copy made here is
-        # scaled in place
-        shared = isinstance(X, DeviceMatrix)
-        rows, features = len(X), np.shape(X.data if shared else X)[1]
-        standardize = _standardize if shared else _donated_standardize()
-        X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
-        # the span holds the dispatch; the program's device seconds are
-        # its own (jit__lr_standardize)
-        with _tracing.span("fit:standardize", rows=rows, features=features):
-            X_std, mean, scale = standardize(X_dev, mask)
-        del X_dev  # donated where it was this fit's own copy
-        return self._fit_prepared(X_std, y_dev, mask, num_classes, mean, scale)
+        # The device copy is the build's, shared with its other fits:
+        # scaled into a new buffer, never donated. The span holds the
+        # dispatch; the program's device seconds are its own
+        # (jit__lr_standardize)
+        with _tracing.span(
+            "fit:standardize", rows=data.rows, features=data.features
+        ):
+            X_std, mean, scale = _standardize(matrix.data, matrix.mask)
+        return self._fit_prepared(
+            X_std, labels.data, matrix.mask, data.num_classes, mean, scale
+        )
 
     def fit_sharded(
         self,

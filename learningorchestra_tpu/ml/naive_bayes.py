@@ -25,7 +25,7 @@ from jax.sharding import Mesh
 
 from learningorchestra_tpu.ml.base import (
     FittedModel,
-    infer_num_classes,
+    TrainingInput,
     prepare_xy,
     resolve_mesh,
 )
@@ -67,20 +67,19 @@ class NaiveBayes:
         self.smoothing = smoothing
         self.mesh = resolve_mesh(mesh)
 
-    def fit(self, X: np.ndarray, y: np.ndarray) -> NaiveBayesModel:
-        X = np.asarray(X)
-        if np.nanmin(X) < 0:
+    def fit(self, X, y=None) -> NaiveBayesModel:
+        data = TrainingInput.of(X, y, self.mesh)
+        if np.nanmin(data.X) < 0:
             raise ValueError(
                 "NaiveBayes requires non-negative features (MLlib contract)"
             )
-        num_classes = infer_num_classes(y)
-        X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
+        matrix, labels = data.device()
         with _tracing.span("fit:enqueue"):  # see ml/trees.py
             theta, prior = _fit(
-                X_dev,
-                y_dev,
-                mask.astype(jnp.float32),
-                num_classes=num_classes,
+                matrix.data,
+                labels.data,
+                matrix.mask.astype(jnp.float32),
+                num_classes=data.num_classes,
                 smoothing=jnp.float32(self.smoothing),
             )
             return NaiveBayesModel(theta, prior, self.mesh)

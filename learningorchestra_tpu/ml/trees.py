@@ -42,8 +42,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from learningorchestra_tpu.ml import progress as _progress
 from learningorchestra_tpu.ml.base import (
+    DeviceMatrix,
     FittedModel,
-    infer_num_classes,
+    TrainingInput,
     largest_divisor,
     prepare_xy,
     resolve_mesh,
@@ -52,7 +53,6 @@ from learningorchestra_tpu.ml.binning import (
     MAX_BINS,
     apply_bins,
     distinct_thresholds,
-    shared_thresholds,
 )
 from learningorchestra_tpu.parallel.mesh import MODEL_AXIS, model_size
 from learningorchestra_tpu.telemetry import tracing as _tracing
@@ -794,17 +794,19 @@ def _gbt_forward(X, f0, features_heap, thresholds_heap, leaf_values, step, max_d
 # With ``fit:device_wait`` it is the fit waiting its turn on the device.
 
 
-def _traced_thresholds(X, X_dev, mask, max_bins: int, mesh) -> np.ndarray:
+def _traced_thresholds(
+    data: TrainingInput, matrix: DeviceMatrix, max_bins: int
+) -> np.ndarray:
     """The fit's bin thresholds under a ``fit:thresholds`` span: the
-    device quantile pass over the matrix the fit has just put there
+    device quantile pass over the build's device copy of the matrix
     (``passes`` 1), or the wait for the pass another fit of the same
-    build runs on the same host matrix (``passes`` 0). The span of the
-    fit that ran the pass also says how many of its ``features x
-    (max_bins - 1)`` thresholds differ (``distinct_thresholds``)."""
+    build runs on it (``passes`` 0). The span of the fit that ran the
+    pass also says how many of its ``features x (max_bins - 1)``
+    thresholds differ (``distinct_thresholds``)."""
     with _tracing.span(
-        "fit:thresholds", rows=len(X), features=X_dev.shape[1], bins=max_bins
+        "fit:thresholds", rows=data.rows, features=data.features, bins=max_bins
     ):
-        thresholds, passes = shared_thresholds(X, X_dev, mask, max_bins, mesh)
+        thresholds, passes = data.thresholds(matrix, max_bins)
         _tracing.annotate(passes=passes)
         if passes:
             _tracing.annotate(distinct_thresholds=distinct_thresholds(thresholds))
@@ -822,21 +824,19 @@ class DecisionTreeClassifier:
         self.max_bins = max_bins
         self.mesh = resolve_mesh(mesh)
 
-    def fit(self, X: np.ndarray, y: np.ndarray) -> _TreeEnsembleModel:
-        num_classes = infer_num_classes(y)
-        X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
-        thresholds = _traced_thresholds(
-            X, X_dev, mask, self.max_bins, self.mesh
-        )
+    def fit(self, X, y=None) -> _TreeEnsembleModel:
+        data = TrainingInput.of(X, y, self.mesh)
+        matrix, labels = data.device()
+        thresholds = _traced_thresholds(data, matrix, self.max_bins)
         with _tracing.span(
-            "fit:enqueue", **hist_block_plan(*X_dev.shape, self.max_bins)
+            "fit:enqueue", **hist_block_plan(*matrix.data.shape, self.max_bins)
         ):
-            bins = apply_bins(X_dev, jnp.asarray(thresholds, jnp.float32))
+            bins = apply_bins(matrix.data, jnp.asarray(thresholds, jnp.float32))
             features_heap, bins_heap, leaf_probs = _dt_fit(
                 bins,
-                y_dev,
-                mask.astype(jnp.float32),
-                num_classes,
+                labels.data,
+                matrix.mask.astype(jnp.float32),
+                data.num_classes,
                 self.max_depth,
                 self.max_bins,
             )
@@ -867,26 +867,23 @@ class RandomForestClassifier:
         self.seed = seed
         self.mesh = resolve_mesh(mesh)
 
-    def fit(self, X: np.ndarray, y: np.ndarray) -> _TreeEnsembleModel:
-        num_classes = infer_num_classes(y)
-        num_features = np.asarray(X).shape[1]
-        subset_k = max(1, int(np.ceil(np.sqrt(num_features))))
-        X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
-        thresholds = _traced_thresholds(
-            X, X_dev, mask, self.max_bins, self.mesh
-        )
+    def fit(self, X, y=None) -> _TreeEnsembleModel:
+        data = TrainingInput.of(X, y, self.mesh)
+        subset_k = max(1, int(np.ceil(np.sqrt(data.features))))
+        matrix, labels = data.device()
+        thresholds = _traced_thresholds(data, matrix, self.max_bins)
         with _tracing.span(
             "fit:enqueue",
             subset_k=subset_k,
-            **hist_block_plan(*X_dev.shape, self.max_bins),
+            **hist_block_plan(*matrix.data.shape, self.max_bins),
         ):
-            bins = apply_bins(X_dev, jnp.asarray(thresholds, jnp.float32))
+            bins = apply_bins(matrix.data, jnp.asarray(thresholds, jnp.float32))
             features_heap, bins_heap, leaf_probs = _rf_fit(
                 bins,
-                y_dev,
-                mask.astype(jnp.float32),
+                labels.data,
+                matrix.mask.astype(jnp.float32),
                 jax.random.key(self.seed),
-                num_classes,
+                data.num_classes,
                 self.max_depth,
                 self.max_bins,
                 self.num_trees,
@@ -946,21 +943,20 @@ class GBTClassifier:
         self.max_bins = max_bins
         self.mesh = resolve_mesh(mesh)
 
-    def fit(self, X: np.ndarray, y: np.ndarray) -> GBTModel:
-        if infer_num_classes(y) > 2:
+    def fit(self, X, y=None) -> GBTModel:
+        data = TrainingInput.of(X, y, self.mesh)
+        if data.num_classes > 2:
             raise ValueError("GBTClassifier supports binary labels only (MLlib contract)")
-        X_dev, y_dev, mask = prepare_xy(X, y, self.mesh)
-        thresholds = _traced_thresholds(
-            X, X_dev, mask, self.max_bins, self.mesh
-        )
+        matrix, labels = data.device()
+        thresholds = _traced_thresholds(data, matrix, self.max_bins)
         with _tracing.span(
-            "fit:enqueue", **hist_block_plan(*X_dev.shape, self.max_bins)
+            "fit:enqueue", **hist_block_plan(*matrix.data.shape, self.max_bins)
         ):
-            bins = apply_bins(X_dev, jnp.asarray(thresholds, jnp.float32))
+            bins = apply_bins(matrix.data, jnp.asarray(thresholds, jnp.float32))
             f0, features_heap, bins_heap, leaf_values = _gbt_fit(
                 bins,
-                y_dev,
-                mask.astype(jnp.float32),
+                labels.data,
+                matrix.mask.astype(jnp.float32),
                 self.max_depth,
                 self.max_bins,
                 self.rounds,

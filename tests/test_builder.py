@@ -267,9 +267,9 @@ class TestFusedEvaluatePredict:
         assert _alias_if_equal(None, testing) is None
 
 
-def _threshold_spans(work):
-    """``[(span, path)]`` of the ``fit:thresholds`` spans ``work``
-    leaves in an active trace."""
+def _traced_spans(work):
+    """``[(span, path)]`` of every span ``work`` leaves in an active
+    trace."""
     from learningorchestra_tpu.telemetry import tracing
 
     def walk(span, path):
@@ -281,12 +281,145 @@ def _threshold_spans(work):
     trace = tracing.Trace(name="build")
     with tracing.activate(trace):
         work()
+    return [pair for root in trace.as_dict()["spans"] for pair in walk(root, ())]
+
+
+def _threshold_spans(work):
+    """``[(span, path)]`` of the ``fit:thresholds`` spans ``work``
+    leaves in an active trace."""
     return [
-        pair
-        for root in trace.as_dict()["spans"]
-        for pair in walk(root, ())
-        if pair[0]["name"] == "fit:thresholds"
+        pair for pair in _traced_spans(work) if pair[0]["name"] == "fit:thresholds"
     ]
+
+
+FIVE = ["lr", "dt", "rf", "gb", "nb"]
+TREES = ["dt", "rf", "gb"]
+
+
+class TestOneTrainingCopyABuild:
+    """A build's training matrix and labels cross to the device once,
+    whatever the number of fits: the first fit to ask makes the copy
+    under ``h2d:train``, the others wait for it under ``fit:input``."""
+
+    @pytest.mark.parametrize("classifiers", [FIVE, TREES])
+    def test_one_copy_serves_every_fit(self, titanic_store, classifiers):
+        from learningorchestra_tpu.parallel.mesh import data_size, default_mesh
+        from learningorchestra_tpu.parallel.sharding import padded_row_count
+        from learningorchestra_tpu.telemetry import profile
+
+        def build():
+            build_model(
+                titanic_store,
+                "titanic_train",
+                "titanic_test",
+                DOCUMENTED_PREPROCESSOR,
+                classifiers,
+            )
+
+        build()  # from here on the test split is in the device cache
+        before = profile.flow_totals()["h2d_bytes"]
+        spans = _traced_spans(build)
+        grown = profile.flow_totals()["h2d_bytes"] - before
+        (copy,) = [span for span, _ in spans if span["name"] == "h2d:train"]
+        assert copy["meta"]["copies"] == 1
+        # the one copy is all the build moved, to the byte: the padded
+        # float32 matrix, int32 labels and a mask with each
+        assert copy["meta"]["h2d_bytes"] == grown
+        features = {
+            span["meta"]["features"]
+            for span, _ in spans
+            if span["name"] == "fit:thresholds"
+        }
+        padded = padded_row_count(copy["meta"]["rows"], data_size(default_mesh()))
+        assert grown == padded * (features.pop() * 4 + 1 + 4 + 1)
+        for name in classifiers:
+            (fit,) = [
+                span for span, path in spans
+                if span["name"] == "phase:fit" and f"train:{name}" in path
+            ]
+            children = [child["name"] for child in fit["children"]]
+            assert children.count("h2d:train") + children.count("fit:input") == 1
+
+
+class TestSharedTrainingInput:
+    """The fits of a build work from one :class:`TrainingInput`: the
+    same models as from the host arrays, and the shared arrays left as
+    they were."""
+
+    @pytest.fixture()
+    def data(self, rng):
+        X = rng.random((300, 5))  # non-negative, for naive Bayes
+        return X, (X[:, 0] + X[:, 1] > 1).astype(np.int32)
+
+    @pytest.mark.parametrize("name", FIVE)
+    def test_a_fit_from_the_shared_input_is_the_fit_from_numpy(self, data, name):
+        from learningorchestra_tpu.ml.base import TrainingInput, make_classifier
+
+        X, y = data
+        shared = make_classifier(name).fit(TrainingInput(X, y), y)
+        alone = make_classifier(name).fit(X, y)
+        state, want = shared.device_state(), alone.device_state()
+        assert len(state) == len(want) > 0
+        for array, expected in zip(state, want):
+            np.testing.assert_array_equal(np.asarray(array), np.asarray(expected))
+        np.testing.assert_array_equal(shared.predict_proba(X), alone.predict_proba(X))
+
+    def test_the_shared_copy_is_left_as_it_was(self, data):
+        from learningorchestra_tpu.ml.base import TrainingInput, make_classifier
+
+        X, y = data
+        shared = TrainingInput(X, y)
+        matrix, labels = shared.device()
+        arrays = (matrix.data, matrix.mask, labels.data)
+        before = [np.asarray(array) for array in arrays]
+        for name in FIVE:
+            make_classifier(name).fit(shared, y)
+        assert shared.device() == (matrix, labels)  # no second copy
+        for array, was in zip(arrays, before):
+            assert not array.is_deleted()
+            np.testing.assert_array_equal(np.asarray(array), was)
+
+    def test_many_fits_asking_at_once_get_one_copy_and_one_pass(
+        self, data, monkeypatch
+    ):
+        import sys
+        import threading
+
+        from learningorchestra_tpu.ml import base, binning
+
+        X, y = data
+        shared = base.TrainingInput(X, y)
+        made = {"copies": 0, "passes": 0}
+
+        def counted(name, real):
+            def run(*args, **kwargs):
+                made[name] += 1
+                return real(*args, **kwargs)
+
+            return run
+
+        monkeypatch.setattr(base, "shard_matrix", counted("copies", base.shard_matrix))
+        monkeypatch.setattr(
+            binning, "device_thresholds", counted("passes", binning.device_thresholds)
+        )
+        got = []
+
+        def ask():
+            matrix, labels = shared.device()
+            got.append((matrix, labels) + shared.thresholds(matrix, 32))
+
+        threads = [threading.Thread(target=ask) for _ in range(32)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            _run_side_by_side(threads)
+        finally:
+            sys.setswitchinterval(interval)
+        assert made == {"copies": 1, "passes": 1}
+        assert len(got) == 32
+        assert sum(passes for *_, passes in got) == 1
+        for part in range(3):  # the matrix, the labels, the thresholds
+            assert len({id(answer[part]) for answer in got}) == 1
 
 
 class TestThresholdsOnceABuild:
@@ -294,18 +427,16 @@ class TestThresholdsOnceABuild:
     to have its matrix on the device runs the pass, the other two take
     its result (``passes`` on each fit's ``fit:thresholds`` span)."""
 
-    TREES = ["dt", "rf", "gb"]
-
     def _build(self, store, train="titanic_train"):
         return build_model(
-            store, train, "titanic_test", DOCUMENTED_PREPROCESSOR, self.TREES
+            store, train, "titanic_test", DOCUMENTED_PREPROCESSOR, TREES
         )
 
     def test_three_spans_one_pass(self, titanic_store):
         found = _threshold_spans(lambda: self._build(titanic_store))
         assert sorted(path[-3:] for _, path in found) == [
             (f"train:{name}", "phase:fit", "fit:thresholds")
-            for name in sorted(self.TREES)
+            for name in sorted(TREES)
         ]
         assert sum(span["meta"]["passes"] for span, _ in found) == 1
 
@@ -321,7 +452,6 @@ class TestThresholdsOnceABuild:
 
     @pytest.mark.parametrize("name", TREES)
     def test_a_lone_fit_makes_its_own(self, rng, name):
-        from learningorchestra_tpu.ml import binning
         from learningorchestra_tpu.ml.base import make_classifier
 
         X = rng.normal(size=(200, 4))
@@ -330,27 +460,25 @@ class TestThresholdsOnceABuild:
             _threshold_spans(lambda: make_classifier(name).fit(X, y))
             for _ in range(2)
         )
-        assert first[0]["meta"]["passes"] == 1
-        # the same array object, still alive: its thresholds are kept,
-        # and go when it goes
-        assert again[0]["meta"]["passes"] == 0
-        assert len(binning._shared) == 1
-        del X
-        assert not binning._shared
+        # a fit handed a host array makes its own input: nothing of the
+        # first fit's pass outlives it, even for the same array object
+        assert first[0]["meta"]["passes"] == again[0]["meta"]["passes"] == 1
 
     def test_a_failed_pass_fails_every_waiting_fit_and_is_forgotten(
         self, rng, monkeypatch
     ):
         import threading
 
-        from learningorchestra_tpu.ml import binning
-        from learningorchestra_tpu.ml.base import make_classifier
+        from learningorchestra_tpu.ml import base, binning
+        from learningorchestra_tpu.ml.base import TrainingInput, make_classifier
 
         X = rng.normal(size=(200, 4))
         y = (X[:, 0] > 0).astype(np.int32)
+        shared = TrainingInput(X, y)
+        shared.device()  # the copy is made: only the pass is waited for
         waiting = threading.Semaphore(0)
 
-        class Watched(binning.Future):
+        class Watched(base.Future):
             def result(self, timeout=None):
                 waiting.release()
                 return super().result(timeout)
@@ -367,15 +495,15 @@ class TestThresholdsOnceABuild:
 
         def fit(name):
             try:
-                make_classifier(name).fit(X, y)
+                make_classifier(name).fit(shared, y)
             except RuntimeError as error:
                 errors[name] = error
 
         with monkeypatch.context() as patch:
-            patch.setattr(binning, "Future", Watched)
+            patch.setattr(base, "Future", Watched)
             patch.setattr(binning, "device_thresholds", failing_pass)
             threads = [
-                threading.Thread(target=fit, args=(name,)) for name in self.TREES
+                threading.Thread(target=fit, args=(name,)) for name in TREES
             ]
             for thread in threads:
                 thread.start()
@@ -383,12 +511,58 @@ class TestThresholdsOnceABuild:
                 thread.join(timeout=120)
             assert not any(thread.is_alive() for thread in threads)
         assert len(passes) == 1
-        assert sorted(errors) == sorted(self.TREES)
+        assert sorted(errors) == sorted(TREES)
         assert len({id(error) for error in errors.values()}) == 1
         assert str(errors["dt"]) == "the pass broke"
-        assert not binning._shared
-        # the next build on the same array starts afresh
+        # the next fit on the same input starts afresh
         ((span, _),) = _threshold_spans(
-            lambda: make_classifier("dt").fit(X, y)
+            lambda: make_classifier("dt").fit(shared, y)
         )
         assert span["meta"]["passes"] == 1
+
+    def test_concurrent_builds_keep_their_own_thresholds(self, rng):
+        """Two builds' inputs on different data, their six tree fits
+        side by side: each input runs its own pass, and each fit bins by
+        its own data's thresholds."""
+        import threading
+
+        from learningorchestra_tpu.ml.base import TrainingInput, make_classifier
+        from learningorchestra_tpu.telemetry import tracing
+
+        inputs = []
+        for scale in (1.0, 10.0):
+            X = rng.normal(size=(300, 4)) * scale
+            inputs.append(TrainingInput(X, (X[:, 0] > 0).astype(np.int32)))
+        fitted = {}
+
+        def side_by_side():
+            context = tracing.capture()
+
+            def fit(index, name):
+                with tracing.attach(context):
+                    fitted[index, name] = make_classifier(name).fit(inputs[index])
+
+            _run_side_by_side([
+                threading.Thread(target=fit, args=(index, name))
+                for index in range(2)
+                for name in TREES
+            ])
+
+        found = _threshold_spans(side_by_side)
+        assert len(found) == 6 and len(fitted) == 6
+        assert sum(span["meta"]["passes"] for span, _ in found) == 2
+        for index, data in enumerate(inputs):
+            for name in TREES:
+                alone = make_classifier(name).fit(data.X, data.y)
+                np.testing.assert_array_equal(
+                    np.asarray(fitted[index, name].thresholds_heap),
+                    np.asarray(alone.thresholds_heap),
+                )
+
+
+def _run_side_by_side(threads) -> None:
+    for thread in threads:
+        thread.start()
+    for thread in threads:
+        thread.join(timeout=300)
+    assert not any(thread.is_alive() for thread in threads)

@@ -349,31 +349,37 @@ class TestDeviceStandardization:
             logistic.LogisticRegression(max_iter=10, tol=0.0).fit(X, y)
         (fit,) = trace.as_dict()["spans"]
         names = [child["name"] for child in fit["children"]]
+        # a lone fit makes its own input: one copy, then standardised
+        # into a buffer of its own (the copy is not donated: it is the
+        # input's, as a build's is shared)
         assert names.index("h2d:train") < names.index("fit:standardize")
+        (copy,) = [c for c in fit["children"] if c["name"] == "h2d:train"]
+        assert copy["meta"]["copies"] == 1
         # one segment: its seed's margins and gradient, then ten
         # iterations of two passes each
         assert fit["meta"]["lbfgs_matrix_passes"] == 2 + 2 * 10
 
     def test_fit_leaves_a_device_matrix_it_was_given(self):
-        """A DeviceMatrix is the caller's (the build shares one among
+        """The device copy of a TrainingInput is the build's (shared by
         its models): the fit scales it into a new buffer and never
         donates it."""
         from learningorchestra_tpu.ml import logistic
-        from learningorchestra_tpu.ml.base import shard_matrix
+        from learningorchestra_tpu.ml.base import TrainingInput
 
         X, y = _planted_problem(rows=300)
-        shared = shard_matrix(X)
-        before = np.asarray(shared.data)
+        shared = TrainingInput(X, y)
+        matrix, _ = shared.device()
+        before = np.asarray(matrix.data)
         first = logistic.LogisticRegression(max_iter=10).fit(shared, y)
-        assert not shared.data.is_deleted()
-        np.testing.assert_array_equal(np.asarray(shared.data), before)
+        assert not matrix.data.is_deleted()
+        np.testing.assert_array_equal(np.asarray(matrix.data), before)
         # and it serves again: a second fit and a predict from it
         second = logistic.LogisticRegression(max_iter=10).fit(shared, y)
         np.testing.assert_array_equal(
             np.asarray(first.params["w"]), np.asarray(second.params["w"])
         )
         np.testing.assert_array_equal(
-            second.predict(shared), second.predict(X)
+            second.predict(matrix), second.predict(X)
         )
 
 

@@ -248,6 +248,14 @@ def build_spans(built_client):
     ]
 
 
+def _input_span(names):
+    """Of a fit's children, the one that stands for its training
+    input: ``h2d:train`` where the fit made the build's device copy,
+    ``fit:input`` where it waited for it."""
+    (found,) = [name for name in names if name in ("h2d:train", "fit:input")]
+    return found
+
+
 def _named(spans, name, under=None):
     return [
         (span, path)
@@ -273,12 +281,13 @@ class TestBuildSpanTree:
         assert span["meta"]["features"] >= 1
         assert span["meta"]["bins"] == 32
         assert span["meta"]["passes"] in (0, 1)
-        # the pass reads the matrix the fit has put on the device
+        # the pass reads the build's device copy of the matrix, which
+        # this fit made or waited for
         (fit, _), = _named(build_spans, "phase:fit", f"train:{classifier}")
         names = [child["name"] for child in fit["children"]]
-        assert names.index("h2d:train") < names.index("fit:thresholds") < (
-            names.index("fit:enqueue")
-        )
+        assert names.index(_input_span(names)) < names.index(
+            "fit:thresholds"
+        ) < names.index("fit:enqueue")
 
     def test_one_threshold_pass_serves_the_three_tree_fits(self, build_spans):
         found = _named(build_spans, "fit:thresholds")
@@ -301,8 +310,11 @@ class TestBuildSpanTree:
             child["name"] for child in fit["children"]
             if not child["name"].startswith("compile:")
         ]
-        # the raw float32 matrix crosses, then is standardised there
-        assert children[:3] == ["h2d:train", "fit:standardize", "fit:enqueue"]
+        # the build's float32 copy crosses (or is waited for), then is
+        # standardised into a buffer of LR's own
+        assert children[:3] == [
+            _input_span(children), "fit:standardize", "fit:enqueue"
+        ]
         segments = [
             child for child in fit["children"]
             if child["name"] == "fit:segment"
@@ -328,10 +340,10 @@ class TestBuildSpanTree:
     ):
         (fit, _), = _named(build_spans, "phase:fit", f"train:{classifier}")
         names = [child["name"] for child in fit["children"]]
-        assert "h2d:train" in names and names.count("fit:device_wait") == 1
-        assert names.index("h2d:train") < names.index("fit:enqueue") < (
-            names.index("fit:device_wait")
-        )
+        assert names.count("fit:device_wait") == 1
+        assert names.index(_input_span(names)) < names.index(
+            "fit:enqueue"
+        ) < names.index("fit:device_wait")
         # one thread, one after the other: the children's seconds are
         # part of the fit's, never more (compile spans are recorded
         # after the fact and may overlap, so they are left out)
@@ -350,9 +362,19 @@ class TestBuildSpanTree:
 
     @pytest.mark.parametrize("classifier", FIVE)
     def test_fit_h2d_span_carries_the_bytes(self, build_spans, classifier):
-        (span, _), = _named(build_spans, "h2d:train", f"train:{classifier}")
+        # one copy a build, made by the first fit to ask (lr: the
+        # fan-out is serialised here); every other fit waits for it
+        (span, path), = _named(build_spans, "h2d:train")
+        assert span["meta"]["copies"] == 1
         # at least the float32 matrix of the 7 usable rows
         assert span["meta"]["h2d_bytes"] >= span["meta"]["rows"] * 4
+        waits = _named(build_spans, "fit:input", f"train:{classifier}")
+        if f"train:{classifier}" in path:
+            assert not waits
+        else:
+            (wait, _), = waits
+            assert wait["meta"]["rows"] == span["meta"]["rows"]
+            assert "h2d_bytes" not in wait["meta"]
         (fit, _), = _named(build_spans, "phase:fit", f"train:{classifier}")
         assert "h2d_bytes" not in fit.get("meta", {})
 
