@@ -172,13 +172,19 @@ class DeviceCache:
     def get(
         self, scope: str, collection: str, subkey: tuple, rev: int
     ) -> Optional[Any]:
+        entry = self._get_entry(scope, collection, subkey, rev)
+        return None if entry is None else entry.value
+
+    def _get_entry(
+        self, scope: str, collection: str, subkey: tuple, rev: int
+    ) -> Optional[_Entry]:
         key = (scope, collection, subkey)
         with self._lock:
             entry = self._entries.get(key)
             if entry is not None and rev >= 0 and entry.rev == rev:
                 self._entries.move_to_end(key)
                 self.hits += 1
-                return entry.value
+                return entry
             if entry is not None:
                 # stale (a write bumped the rev, or the collection is
                 # gone and the probe answered -1): evict now — rev-keyed
@@ -197,6 +203,20 @@ class DeviceCache:
         value: Any,
         nbytes: int,
     ) -> Any:
+        self._put(scope, collection, subkey, rev, value, nbytes)
+        return value
+
+    def _put(
+        self,
+        scope: str,
+        collection: str,
+        subkey: tuple,
+        rev: int,
+        value: Any,
+        nbytes: int,
+    ) -> tuple[int, int]:
+        """Cache ``value``; returns ``(entries, bytes)`` the LRU dropped
+        to make room for it."""
         nbytes = max(int(nbytes), 0)
         if (
             self.capacity <= 0
@@ -204,17 +224,58 @@ class DeviceCache:
             or not scope
             or nbytes > self.capacity
         ):
-            return value  # uncacheable: hand the value through
+            return 0, 0  # uncacheable: hand the value through
         key = (scope, collection, subkey)
+        evicted = evicted_bytes = 0
         with self._lock:
             if key in self._entries:
                 self._drop_locked(key)
             while self.bytes + nbytes > self.capacity and self._entries:
                 oldest = next(iter(self._entries))
+                evicted += 1
+                evicted_bytes += self._entries[oldest].nbytes
                 self._drop_locked(oldest)
-                self.evictions += 1
+            self.evictions += evicted
             self._entries[key] = _Entry(value, nbytes, rev)
             self.bytes += nbytes
+        return evicted, evicted_bytes
+
+    def _answer(
+        self,
+        scope: str,
+        collection: str,
+        subkey: tuple,
+        rev: int,
+        loader: Callable[[], Any],
+        nbytes_fn: Callable[[Any], int],
+        keep: Callable[[], bool] = lambda: True,
+    ) -> Any:
+        """The entry, or ``loader``'s value cached where ``keep()``
+        still holds after the load; the answer is stamped on the
+        enclosing ``devcache:lookup`` span: ``hits`` and ``misses`` (one
+        of them 1), ``bytes`` (the entry's), ``evicted`` and
+        ``evicted_bytes`` (what the LRU dropped for this put; 0 on a
+        hit)."""
+        from learningorchestra_tpu.telemetry import annotate
+
+        entry = self._get_entry(scope, collection, subkey, rev)
+        if entry is not None:
+            annotate(
+                hits=1, misses=0, bytes=entry.nbytes,
+                evicted=0, evicted_bytes=0,
+            )
+            return entry.value
+        value = loader()
+        nbytes = nbytes_fn(value)
+        evicted, evicted_bytes = (
+            self._put(scope, collection, subkey, rev, value, nbytes)
+            if keep()
+            else (0, 0)
+        )
+        annotate(
+            hits=0, misses=1, bytes=int(nbytes),
+            evicted=evicted, evicted_bytes=evicted_bytes,
+        )
         return value
 
     def _drop_locked(self, key: tuple) -> None:
@@ -254,16 +315,18 @@ class DeviceCache:
     ) -> Any:
         """Rev-probed lookup; on miss run ``loader`` and cache the result
         — but only when the rev is unchanged after the load (a write
-        landing mid-read must not be cached under the pre-write rev)."""
-        scope = store_token(store)
-        rev = store_rev(store, collection)
-        cached = self.get(scope, collection, subkey, rev)
-        if cached is not None:
-            return cached
-        value = loader()
-        if rev >= 0 and store_rev(store, collection) == rev:
-            self.put(scope, collection, subkey, rev, value, nbytes_fn(value))
-        return value
+        landing mid-read must not be cached under the pre-write rev).
+        One ``devcache:lookup`` span (``kind``: the subkey's first
+        element) covers it, the loader's spans inside."""
+        from learningorchestra_tpu.telemetry import span
+
+        with span("devcache:lookup", kind=subkey[0]):
+            scope = store_token(store)
+            rev = store_rev(store, collection)
+            return self._answer(
+                scope, collection, subkey, rev, loader, nbytes_fn,
+                keep=lambda: rev >= 0 and store_rev(store, collection) == rev,
+            )
 
     def stats(self) -> dict:
         with self._lock:
@@ -448,9 +511,28 @@ def dataset_embedding_inputs(store, collection: str, mesh=None, cache=None):
 
 
 def _content_digest(array: np.ndarray) -> tuple:
+    """The content key of a host array, under a ``devcache:digest``
+    span (``bytes``: the bytes hashed)."""
+    from learningorchestra_tpu.telemetry import span
+
     array = np.ascontiguousarray(array)
-    digest = hashlib.blake2b(array.view(np.uint8), digest_size=16)
+    with span("devcache:digest", bytes=int(array.nbytes)):
+        digest = hashlib.blake2b(array.view(np.uint8), digest_size=16)
     return (str(array.dtype), array.shape, digest.hexdigest())
+
+
+def _content_lookup(kind: str, array: np.ndarray, tail: tuple, load, nbytes_fn):
+    """The content-addressed entry of ``array`` (subkey ``(kind,
+    digest) + tail``), made by ``load`` on a miss: digest, lookup and
+    load under one ``devcache:lookup`` span (see ``DeviceCache._answer``
+    for what it carries)."""
+    from learningorchestra_tpu.telemetry import span
+
+    with span("devcache:lookup", kind=kind):
+        subkey = (kind, _content_digest(array)) + tail
+        return global_devcache()._answer(
+            CONTENT, CONTENT, subkey, 0, load, nbytes_fn
+        )
 
 
 def content_device_matrix(X: np.ndarray, mesh):
@@ -459,23 +541,22 @@ def content_device_matrix(X: np.ndarray, mesh):
     stale-proof (a different matrix is a different key), so arbitrary
     ``preprocessor_code`` output can ride the cache safely: the second
     build over the same collection hashes the recomputed host matrix,
-    hits, and skips the H2D. The digest costs one linear pass over host
-    bytes — microseconds per MB next to the host-to-device transfer it
-    saves."""
+    hits, and skips the H2D. The digest is one linear pass over the
+    host bytes (``devcache:digest``), paid on a hit and a miss alike.
+
+    The copy is not waited for: the miss's ``h2d:matrix`` span, and the
+    ``h2d:put`` inside it, time the dispatch and the host-side copy jax
+    makes, not the transfer's landing."""
     from learningorchestra_tpu.ml.base import shard_matrix
     from learningorchestra_tpu.telemetry import span
 
-    cache = global_devcache()
-    subkey = (
-        "devmat", _content_digest(X), mesh_signature(mesh), dtype_policy()
-    )
-    cached = cache.get(CONTENT, CONTENT, subkey, rev=0)
-    if cached is not None:
-        return cached
-    with span("h2d:matrix", rows=len(X), dtype=dtype_policy()):
-        dm = shard_matrix(X, mesh)
-    return cache.put(
-        CONTENT, CONTENT, subkey, 0, dm, _device_matrix_nbytes(dm)
+    def load():
+        with span("h2d:matrix", rows=len(X), dtype=dtype_policy()):
+            return shard_matrix(X, mesh)
+
+    return _content_lookup(
+        "devmat", X, (mesh_signature(mesh), dtype_policy()),
+        load, _device_matrix_nbytes,
     )
 
 
@@ -484,11 +565,11 @@ def content_device_labels(y: np.ndarray, mesh):
     from learningorchestra_tpu.ml.base import shard_labels
     from learningorchestra_tpu.telemetry import span
 
-    cache = global_devcache()
-    subkey = ("devlab", _content_digest(y), mesh_signature(mesh), "i32")
-    cached = cache.get(CONTENT, CONTENT, subkey, rev=0)
-    if cached is not None:
-        return cached
-    with span("h2d:labels", rows=len(y), dtype="i32"):
-        dl = shard_labels(y, mesh)
-    return cache.put(CONTENT, CONTENT, subkey, 0, dl, int(dl.data.nbytes))
+    def load():
+        with span("h2d:labels", rows=len(y), dtype="i32"):
+            return shard_labels(y, mesh)
+
+    return _content_lookup(
+        "devlab", y, (mesh_signature(mesh), "i32"),
+        load, lambda dl: int(dl.data.nbytes),
+    )

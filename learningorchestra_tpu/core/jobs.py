@@ -532,6 +532,17 @@ class JobManager:
                 attempt=task.attempt,
                 queue_wait_s=round(task.wait_s, 6),
             ):
+                # the wait that led into the job, on the wall clock: it
+                # ends as the job starts, and is the job's first child
+                # though it starts before it, because readers of a job
+                # trace take its roots to be job:* spans
+                _tracing.record_span(
+                    "sched:queue",
+                    task.wait_s,
+                    behind_s=round(task.behind_s, 6),
+                    behind=task.behind,
+                    behind_kinds=list(task.behind_kinds),
+                )
                 fn(*args, **kwargs)
         except BaseException as caught:  # noqa: BLE001 — classified below
             error = caught

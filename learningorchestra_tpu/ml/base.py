@@ -229,7 +229,10 @@ class TrainingInput:
         """The device copy of the matrix and the labels. The fit that
         makes it does so under one ``h2d:train`` span (``rows``,
         ``copies`` 1, the bytes ``shard_rows`` stamps), which ends when
-        the arrays have landed; the others wait under ``fit:input``."""
+        the arrays have landed: inside it ``shard_rows``' ``h2d:stage``
+        and ``h2d:put`` for the matrix, the same for the labels, then
+        ``h2d:land``, the wait for the transfer. The others wait under
+        ``fit:input``."""
         made, mine = self._claim("device")
         if not mine:
             with _tracing.span("fit:input", rows=self.rows):
@@ -240,7 +243,9 @@ class TrainingInput:
         with _tracing.span("h2d:train", rows=self.rows, copies=1):
             matrix = shard_matrix(self.X, self.mesh)
             labels = shard_labels(self.y, self.mesh)
-            jax.block_until_ready((matrix.data, matrix.mask, labels.data))
+            _tracing.device_wait(
+                "h2d:land", (matrix.data, matrix.mask, labels.data)
+            )
         return matrix, labels
 
     def thresholds(
@@ -275,19 +280,21 @@ def prepare_xy(
 ) -> tuple[jax.Array, Optional[jax.Array], jax.Array]:
     """Pad + row-shard features (float32), labels (int32) and the
     validity mask over the mesh's data axis. A :class:`DeviceMatrix`
-    sharded on the same mesh passes through without any transfer."""
+    sharded on the same mesh passes through without any transfer.
+    A forward's input (a serve batch, a warm-up's rows, PCA's matrix)
+    comes this way; a fit's comes through :class:`TrainingInput`."""
     if isinstance(X, DeviceMatrix):
         if X.mesh is mesh:
             y_dev = None
             if y is not None:
-                with _tracing.span("h2d:train", rows=len(y)):
+                with _tracing.span("h2d:input", rows=len(y)):
                     y_dev, _ = shard_rows(np.asarray(y), mesh, dtype=np.int32)
             return X.data, y_dev, X.mask
         # mesh mismatch: fall back through host memory
         X = np.asarray(jax.device_get(X.data))[: X.rows]
-    # one span where a fit's own copy of the matrix and labels crosses
-    # to the device: shard_rows' account_h2d stamps the bytes on it
-    with _tracing.span("h2d:train", rows=len(X)):
+    # one span where the input crosses to the device: shard_rows'
+    # account_h2d stamps the bytes on it
+    with _tracing.span("h2d:input", rows=len(X)):
         X_dev, mask = shard_rows(np.asarray(X), mesh, dtype=np.float32)
         y_dev = None
         if y is not None:

@@ -27,6 +27,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from learningorchestra_tpu.parallel.mesh import DATA_AXIS
+from learningorchestra_tpu.telemetry import tracing as _tracing
 from learningorchestra_tpu.utils.dtypepolicy import dtype_policy
 from learningorchestra_tpu.utils.shapegrid import bucket_count, grid_size
 
@@ -100,23 +101,39 @@ def shard_rows(
     flow through :func:`policy_dtype`, so ``LO_DTYPE_POLICY=bf16``
     halves every feature-matrix transfer at THE H2D funnel without any
     caller opting in per site.
+
+    In the active trace the host's half is an ``h2d:stage`` span (the
+    pad and the cast; ``bytes``, the host bytes they wrote) and the
+    hand-off an ``h2d:put`` span, both under the caller's span. The put
+    returns before the arrays land: it times the dispatch and the host
+    copy jax makes, not the transfer, which only a caller that waits
+    for the arrays can time (``ml/base.TrainingInput``).
     """
+    from learningorchestra_tpu.telemetry import profile
+
     n_shards = mesh.shape[DATA_AXIS]
-    padded, mask = pad_rows(np.asarray(array), n_shards)
-    if dtype is not None:
-        padded = padded.astype(policy_dtype(dtype))
+    with _tracing.span("h2d:stage"):
+        source = np.asarray(array)
+        padded, mask = pad_rows(source, n_shards)
+        written = int(mask.nbytes)
+        if padded is not source:
+            written += int(padded.nbytes)
+        if dtype is not None:
+            padded = padded.astype(policy_dtype(dtype))
+            written += int(padded.nbytes)
+        _tracing.annotate(bytes=written)
     sharding = row_sharded(mesh)
     # Flight-recorder byte accounting at THE H2D funnel (every matrix/
     # label transfer in the product path comes through here): counts
-    # into lo_h2d_bytes_total and the ambient span. Host-side only —
-    # identical on every process, no collective, SPMD-safe.
-    from learningorchestra_tpu.telemetry import profile
-
+    # into lo_h2d_bytes_total and the caller's span, outside the two
+    # above. Host-side only — identical on every process, no
+    # collective, SPMD-safe.
     profile.account_h2d(int(padded.nbytes) + int(mask.nbytes))
-    return (
-        jax.device_put(padded, sharding),
-        jax.device_put(mask, sharding),
-    )
+    with _tracing.span("h2d:put"):
+        return (
+            jax.device_put(padded, sharding),
+            jax.device_put(mask, sharding),
+        )
 
 
 def put_replicated(value, mesh: Mesh) -> jax.Array:

@@ -28,6 +28,7 @@ job manager.
 
 from __future__ import annotations
 
+import collections
 import heapq
 import itertools
 import math
@@ -67,7 +68,11 @@ class Task:
     ``None`` when the job reached a terminal state, or a delay in
     seconds to re-enqueue after (a transient failure within budget).
     ``wait_s`` is stamped by the worker at dequeue so ``run`` can
-    record queue time."""
+    record queue time, and with it what the wait was spent behind:
+    ``behind_s``, the seconds of the wait during which the class's
+    workers ran other tasks, ``behind``, how many such tasks, and
+    ``behind_kinds``, their name prefixes up to the first ``:``
+    (sorted, each once)."""
 
     __slots__ = (
         "name",
@@ -78,6 +83,9 @@ class Task:
         "attempt",
         "enqueued_at",
         "wait_s",
+        "behind_s",
+        "behind",
+        "behind_kinds",
     )
 
     def __init__(
@@ -96,10 +104,20 @@ class Task:
         self.attempt = 1
         self.enqueued_at = 0.0
         self.wait_s = 0.0
+        self.behind_s = 0.0
+        self.behind = 0
+        self.behind_kinds: tuple[str, ...] = ()
+
+
+# Runs a class remembers for what a dequeued task waited behind: more
+# than any queue wait spans at the widths in use, and bounded.
+_RAN_LOG = 256
 
 
 class _ClassQueue:
-    """One concurrency class: heap + worker pool + throughput EWMA."""
+    """One concurrency class: heap + worker pool + throughput EWMA, and
+    a bounded log of the runs its workers made (``[name, start, end]``
+    on the monotonic clock, ``end`` None while running)."""
 
     def __init__(self, name: str, width: int, cap: int):
         self.name = name
@@ -114,6 +132,26 @@ class _ClassQueue:
         # EWMA of execution seconds, seeding Retry-After estimates
         # before any job has completed
         self.avg_run_s = 1.0
+        self.ran: collections.deque = collections.deque(maxlen=_RAN_LOG)
+
+    def behind_locked(
+        self, since: float, now: float
+    ) -> tuple[float, int, tuple[str, ...]]:
+        """``(seconds, runs, kinds)``: how much of ``[since, now]`` the
+        logged runs cover (their union), how many overlap it, and
+        their name prefixes."""
+        cut = sorted(
+            (max(start, since), min(now if end is None else end, now), name)
+            for name, start, end in self.ran
+        )
+        cut = [(lo, hi, name) for lo, hi, name in cut if hi > lo]
+        covered, cursor = 0.0, since
+        for lo, hi, _ in cut:
+            if hi > cursor:
+                covered += hi - max(lo, cursor)
+                cursor = hi
+        kinds = tuple(sorted({name.split(":", 1)[0] for _, _, name in cut}))
+        return covered, len(cut), kinds
 
 
 class Scheduler:
@@ -251,9 +289,14 @@ class Scheduler:
                 self._depth_gauge.labels(cls.name).set(len(cls.heap))
                 cls.running += 1
                 self._running_gauge.labels(cls.name).set(cls.running)
-            task.wait_s = time.monotonic() - task.enqueued_at
+                started = time.monotonic()
+                task.wait_s = started - task.enqueued_at
+                task.behind_s, task.behind, task.behind_kinds = (
+                    cls.behind_locked(task.enqueued_at, started)
+                )
+                run_log = [task.name, started, None]
+                cls.ran.append(run_log)
             self._wait_seconds.labels(cls.name).observe(task.wait_s)
-            started = time.monotonic()
             try:
                 retry_delay = task.run(task)
             except Exception:  # noqa: BLE001 — run() owns job errors;
@@ -265,6 +308,7 @@ class Scheduler:
                 retry_delay = None
             finally:
                 with cls.cond:
+                    run_log[2] = time.monotonic()
                     cls.running -= 1
                     self._running_gauge.labels(cls.name).set(cls.running)
                     cls.avg_run_s = (

@@ -340,6 +340,62 @@ class TestOneTrainingCopyABuild:
             children = [child["name"] for child in fit["children"]]
             assert children.count("h2d:train") + children.count("fit:input") == 1
 
+    def test_the_copy_is_staged_put_and_landed(self, titanic_store):
+        """Under ``h2d:train``: the host's pad and cast and the hand-off
+        of the matrix, the same of the labels, then the wait for both
+        to land; the bytes and the copy count stay on the parent."""
+
+        def build():
+            build_model(
+                titanic_store,
+                "titanic_train",
+                "titanic_test",
+                DOCUMENTED_PREPROCESSOR,
+                FIVE,
+            )
+
+        (copy,) = [
+            span for span, _ in _traced_spans(build) if span["name"] == "h2d:train"
+        ]
+        children = copy["children"]
+        assert [child["name"] for child in children] == [
+            "h2d:stage", "h2d:put", "h2d:stage", "h2d:put", "h2d:land"
+        ]
+        assert sum(child["duration_s"] for child in children) <= copy[
+            "duration_s"
+        ]
+        ends = [child["start_ts"] + child["duration_s"] for child in children]
+        for earlier_end, later in zip(ends, children[1:]):
+            assert later["start_ts"] >= earlier_end - 1e-3
+        for child in children:
+            meta = child.get("meta", {})
+            assert "h2d_bytes" not in meta and "copies" not in meta
+        matrix_stage, labels_stage = children[0], children[2]
+        # at least the float32 matrix, and the int32 labels, written
+        assert matrix_stage["meta"]["bytes"] >= copy["meta"]["rows"] * 4
+        assert labels_stage["meta"]["bytes"] >= copy["meta"]["rows"] * 4
+        assert copy["meta"]["copies"] == 1
+        assert copy["meta"]["h2d_bytes"] > 0
+
+    @pytest.mark.parametrize("name", FIVE)
+    def test_a_forwards_input_is_not_a_training_copy(self, rng, name):
+        """A predict on host rows ships them under ``h2d:input``, with
+        its own stage and put and no landing; no ``h2d:train``."""
+        from learningorchestra_tpu.ml.base import make_classifier
+
+        X = np.abs(rng.normal(size=(64, 3)))
+        y = (X[:, 0] > X[:, 1]).astype(np.int32)
+        model = make_classifier(name).fit(X, y)
+        spans = _traced_spans(lambda: model.predict(X[:16]))
+        names = [span["name"] for span, _ in spans]
+        assert "h2d:train" not in names and "h2d:land" not in names
+        (batch,) = [span for span, _ in spans if span["name"] == "h2d:input"]
+        assert batch["meta"]["rows"] == 16
+        assert batch["meta"]["h2d_bytes"] >= 16 * 3 * 4
+        assert [child["name"] for child in batch["children"]] == [
+            "h2d:stage", "h2d:put"
+        ]
+
 
 class TestSharedTrainingInput:
     """The fits of a build work from one :class:`TrainingInput`: the

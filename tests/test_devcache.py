@@ -400,6 +400,99 @@ class TestContentAddressedDeviceCache:
         assert reloaded[0].columns["a"].tolist() == [7.0, 7.0, 7.0]
 
 
+def _lookups(work):
+    """The ``devcache:lookup`` spans ``work()`` opens in a trace."""
+    from learningorchestra_tpu.telemetry import tracing
+
+    trace = tracing.Trace(name="lookups")
+    with tracing.activate(trace), tracing.span("phase:evaluate"):
+        work()
+    (root,) = trace.as_dict()["spans"]
+    return [c for c in root["children"] if c["name"] == "devcache:lookup"]
+
+
+class TestLookupSpans:
+    """Every lookup is one ``devcache:lookup`` span that says how the
+    cache answered: a hit or a miss, the entry's bytes, and what the
+    LRU dropped for it."""
+
+    def test_a_content_lookup_misses_then_hits(self):
+        from learningorchestra_tpu.parallel import default_mesh
+
+        X = np.arange(48, dtype=np.float64).reshape(12, 4)
+        mesh = default_mesh()
+        first, second = _lookups(
+            lambda: [
+                devcache.content_device_matrix(X.copy(), mesh)
+                for _ in range(2)
+            ]
+        )
+        assert first["meta"]["kind"] == "devmat"
+        assert (first["meta"]["hits"], first["meta"]["misses"]) == (0, 1)
+        assert (second["meta"]["hits"], second["meta"]["misses"]) == (1, 0)
+        assert first["meta"]["bytes"] == second["meta"]["bytes"] > X.size * 4
+        for lookup in (first, second):
+            assert lookup["meta"]["evicted"] == 0
+            assert lookup["meta"]["evicted_bytes"] == 0
+        # the copy nests under the miss's lookup, after its digest
+        assert [c["name"] for c in first["children"]] == [
+            "devcache:digest", "h2d:matrix"
+        ]
+        assert [c["name"] for c in second["children"]] == ["devcache:digest"]
+
+    def test_the_digest_carries_the_bytes_hashed(self):
+        from learningorchestra_tpu.parallel import default_mesh
+
+        X = np.arange(60, dtype=np.float64).reshape(15, 4)
+        y = np.array([0, 1, 1, 0, 1], dtype=np.int32)
+        mesh = default_mesh()
+        matrix, labels = _lookups(
+            lambda: (
+                devcache.content_device_matrix(X, mesh),
+                devcache.content_device_labels(y, mesh),
+            )
+        )
+        assert labels["meta"]["kind"] == "devlab"
+        for lookup, array in ((matrix, X), (labels, y)):
+            digest = lookup["children"][0]
+            assert digest["name"] == "devcache:digest"
+            assert digest["meta"]["bytes"] == array.nbytes
+
+    def test_the_put_that_drops_an_entry_says_so(self, monkeypatch):
+        from learningorchestra_tpu.parallel import default_mesh
+
+        mesh = default_mesh()
+        X = np.arange(48, dtype=np.float64).reshape(12, 4)
+        (first,) = _lookups(lambda: devcache.content_device_matrix(X, mesh))
+        size = first["meta"]["bytes"]
+        # room for one entry of this size, not two
+        monkeypatch.setattr(
+            devcache.global_devcache(), "capacity", size + size // 2
+        )
+        (second,) = _lookups(
+            lambda: devcache.content_device_matrix(X + 1.0, mesh)
+        )
+        assert second["meta"]["misses"] == 1
+        assert second["meta"]["evicted"] == 1
+        assert second["meta"]["evicted_bytes"] == size
+
+    def test_a_table_lookup_nests_the_read_under_its_miss(self):
+        store = InMemoryStore()
+        seed_dataset(store)
+        cache = DeviceCache(capacity=10_000_000)
+        miss, hit = _lookups(
+            lambda: [
+                devcache.dataset_table(store, "ds", cache=cache)
+                for _ in range(2)
+            ]
+        )
+        assert miss["meta"]["kind"] == hit["meta"]["kind"] == "table"
+        assert (miss["meta"]["misses"], hit["meta"]["hits"]) == (1, 1)
+        assert [c["name"] for c in miss["children"]] == ["store:read"]
+        assert hit["children"] == []
+        assert miss["meta"]["bytes"] == hit["meta"]["bytes"] > 0
+
+
 class TestEmbeddingDeviceInputs:
     def test_pca_accepts_device_matrix(self):
         from learningorchestra_tpu.ml.base import shard_matrix

@@ -146,6 +146,102 @@ class TestAdmissionControl:
         assert order == ["high", "mid", "low"]
 
 
+def _queue_span(manager, name):
+    """The job's ``sched:queue`` span and its ``job:*`` span, as
+    ``GET /jobs/<name>/trace`` serves them."""
+    (job,) = manager.get(name).trace_dict()["trace"]["spans"]
+    assert job["name"] == f"job:{name}"
+    (queue,) = [c for c in job["children"] if c["name"] == "sched:queue"]
+    assert job["children"][0] is queue
+    return queue, job
+
+
+class TestWhatAQueuedJobWaitedBehind:
+    """``sched:queue``: the wait from admission to start, and the runs
+    of the class's workers that it was spent behind."""
+
+    def test_a_job_admitted_behind_a_running_one_waited_its_remaining_run(
+        self,
+    ):
+        manager = make_manager(device_width=1, queue_cap=8)
+        manager.submit("slow:a", time.sleep, 0.3, job_class=DEVICE_CLASS)
+        deadline = time.time() + 5
+        while manager.get("slow:a").state == "pending":
+            assert time.time() < deadline
+            time.sleep(0.005)
+        admitted = time.monotonic()
+        manager.submit("build:b", lambda: None, job_class=DEVICE_CLASS)
+        manager.wait("build:b", timeout=10)
+        remaining = manager.get("slow:a").ended_at - (
+            time.time() - (time.monotonic() - admitted)
+        )
+        queue, job = _queue_span(manager, "build:b")
+        meta = queue["meta"]
+        assert meta["behind_s"] == pytest.approx(remaining, abs=0.1)
+        assert 0.1 < meta["behind_s"] <= job["meta"]["queue_wait_s"] + 1e-6
+        assert meta["behind"] == 1
+        assert meta["behind_kinds"] == ["slow"]
+        assert queue["duration_s"] == pytest.approx(
+            job["meta"]["queue_wait_s"], abs=1e-5
+        )
+        # it ends where the job starts, on the wall clock
+        assert queue["start_ts"] + queue["duration_s"] == pytest.approx(
+            job["start_ts"], abs=0.05
+        )
+
+    def test_on_an_idle_worker_it_waited_behind_nothing(self):
+        manager = make_manager(device_width=1, queue_cap=8)
+        manager.submit("first", lambda: None, job_class=DEVICE_CLASS)
+        manager.wait("first", timeout=10)
+        manager.submit("second", lambda: None, job_class=DEVICE_CLASS)
+        manager.wait("second", timeout=10)
+        for name in ("first", "second"):
+            queue, job = _queue_span(manager, name)
+            assert queue["meta"]["behind_s"] == 0
+            assert queue["meta"]["behind"] == 0
+            assert queue["meta"]["behind_kinds"] == []
+            assert queue["start_ts"] + queue["duration_s"] == pytest.approx(
+                job["start_ts"], abs=0.05
+            )
+
+    def test_behind_is_the_union_of_the_runs_and_names_each_kind_once(
+        self,
+    ):
+        # two workers run three tasks, two of them at once: the wait
+        # is spent behind their union, never more than the wait
+        manager = make_manager(host_width=2, queue_cap=8)
+        gate = threading.Event()
+        manager.submit("warmup:a", gate.wait)
+        manager.submit("warmup:b", gate.wait)
+        deadline = time.time() + 5
+        while manager.get("warmup:b").state == "pending":
+            assert time.time() < deadline
+            time.sleep(0.005)
+        manager.submit("late", lambda: None)
+        time.sleep(0.2)
+        gate.set()
+        manager.wait("late", timeout=10)
+        queue, job = _queue_span(manager, "late")
+        meta = queue["meta"]
+        assert meta["behind"] == 2
+        assert meta["behind_kinds"] == ["warmup"]
+        assert 0.15 < meta["behind_s"] <= job["meta"]["queue_wait_s"] + 1e-6
+
+    def test_the_log_of_runs_is_bounded(self):
+        from learningorchestra_tpu.sched import scheduler as sched
+
+        cls = sched._ClassQueue("host", 1, 8)
+        for index in range(sched._RAN_LOG + 10):
+            cls.ran.append([f"job{index}:x", float(index), index + 0.5])
+        assert len(cls.ran) == sched._RAN_LOG
+        seconds, runs, kinds = cls.behind_locked(
+            sched._RAN_LOG + 8.0, sched._RAN_LOG + 10.0
+        )
+        assert seconds == pytest.approx(1.0)
+        assert runs == 2
+        assert kinds == (f"job{sched._RAN_LOG + 8}", f"job{sched._RAN_LOG + 9}")
+
+
 # --------------------------------------------------------------------
 # Retry policy
 # --------------------------------------------------------------------
