@@ -32,7 +32,10 @@ side masks, never as boxed sentinels in the data.
 Concurrency: columns are copy-on-write. ``snapshot()`` marks buffers
 shared; readers work outside the store lock while writers copy before
 the first in-place mutation. Appends never copy — they land beyond any
-snapshot's recorded ``size``.
+snapshot's recorded ``size``. An append into an empty column takes a
+read-only numeric payload by reference instead of copying it
+(``adopts``): both sides are then shared, and the first append to
+either reallocates, since the taken buffers end at ``size``.
 
 The same buffers serialize three ways with zero per-cell work: the
 binary HTTP wire (core/wire.py), base64 WAL records (crash recovery /
@@ -94,6 +97,8 @@ VEC = "vec"
 OBJ = "obj"
 
 _NUMERIC_KINDS = frozenset((F8, I8, NUM))
+# Kinds held wholly in fixed-width numpy buffers (no offsets, no edits)
+_ADOPTABLE_KINDS = frozenset((F8, I8, NUM, BOOL, VEC))
 _DTYPES = {F8: np.float64, I8: np.int64, NUM: np.float64, BOOL: np.bool_,
            VEC: np.float64}
 
@@ -513,6 +518,40 @@ class Column:
                 setattr(self, slot, mask.copy())
         self._shared = False
 
+    def adopts(self, other: "Column") -> bool:
+        """Whether :meth:`append_column` takes ``other``'s buffers by
+        reference instead of copying them: this column is empty, and
+        ``other`` is a fixed-width numeric kind whose data is read-only
+        and already copy-on-write — a buffer nobody writes in place,
+        such as :meth:`to_float64`'s view of another stored column. A
+        wire-frame view (``owner``) would pin its request's frame and a
+        spilled payload is a mapping of another column's file, so both
+        are copied, as is every writable input: its caller may reuse
+        it."""
+        return (
+            self.size == 0
+            and self.kind in (EMPTY, other.kind)
+            and other.kind in _ADOPTABLE_KINDS
+            and other.size > 0
+            and other._shared
+            and other.owner is None
+            and not isinstance(other.data, np.memmap)
+            and not other.data.flags.writeable
+        )
+
+    def _adopted(self) -> "Column":
+        """A column sharing this one's live prefix, cut to exactly
+        ``size`` rows (data and masks), so that the first append to
+        either side reallocates instead of writing into a tail both
+        hold; both sides copy before any in-place write."""
+        clone = self.snapshot()
+        clone.data = self.data[: self.size]
+        for slot in ("none", "miss", "intm"):
+            mask = getattr(self, slot)
+            if mask is not None:
+                setattr(clone, slot, mask[: self.size])
+        return clone
+
     # --- mask helpers ---------------------------------------------------------
     def _row_capacity(self) -> int:
         if self.kind == OBJ:
@@ -573,6 +612,8 @@ class Column:
             # read-only zero-copy wire views (the paged read loop
             # appends the terminal empty chunk through here).
             return self
+        if self.adopts(other):
+            return other._adopted()
         if other.kind == EMPTY and self.kind not in (EMPTY, NUM):
             other = other._as_kind(self.kind, width=self._vec_width())
         merged = merge_kind(self.kind, other.kind)

@@ -46,6 +46,7 @@ import numpy as np
 
 from learningorchestra_tpu.core.columns import MISSING as _MISSING
 from learningorchestra_tpu.core.columns import Column
+from learningorchestra_tpu.telemetry import tracing as _tracing
 
 ROW_ID = "_id"
 METADATA_ID = 0
@@ -582,7 +583,9 @@ class _Collection:
 
     def append_columns(
         self, columns: dict[str, Column], start_id: int
-    ) -> None:
+    ) -> tuple[int, int]:
+        """Append ``columns`` as rows ``start_id..``; returns the payload
+        bytes taken by reference (``Column.adopts``) and those copied."""
         num_new = len(next(iter(columns.values()))) if columns else 0
         if self.block_columns:
             if start_id != self.block_stop:
@@ -607,13 +610,19 @@ class _Collection:
                 raise KeyError(f"duplicate _id {doc_id!r}")
         for field in columns:
             self.ensure_block_field(field)
+        adopted = copied = 0
         for field in list(self.block_columns):
             column = self.block_columns[field]
             incoming = columns.get(field)
             if incoming is not None:
+                if column.adopts(incoming):
+                    adopted += incoming.nbytes()
+                else:
+                    copied += incoming.nbytes()
                 self.block_columns[field] = column.append_column(incoming)
             else:
                 self.block_columns[field] = column.append_pads(num_new)
+        return adopted, copied
 
 
 class InMemoryStore(DocumentStore):
@@ -1083,14 +1092,15 @@ class InMemoryStore(DocumentStore):
         collection: str,
         columns: dict[str, Column],
         start_id: int,
-    ) -> None:
+    ) -> tuple[int, int]:
         col = self._collections.setdefault(collection, _Collection())
-        col.append_columns(columns, start_id)
+        appended = col.append_columns(columns, start_id)
         col.rev = next(self._rev_seq)
         try:
             self._maybe_spill_locked()
         except OSError as error:
             self._disable_spill_locked(error)
+        return appended
 
     def _disable_spill_locked(self, error: OSError) -> None:
         """Spilling is an optimization; an unwritable/full spill disk
@@ -1390,12 +1400,17 @@ class InMemoryStore(DocumentStore):
         lengths = {len(values) for values in typed.values()}
         if len(lengths) > 1:
             raise ValueError("ragged columns")
-        with self._lock:
+        rows = lengths.pop() if lengths else 0
+        span = _tracing.span("store:append", rows=rows, fields=len(typed))
+        with span, self._lock:
             col = self._collections.get(collection) or _Collection()
             if start_id is None:
                 start_id = col.block_stop if col.block_columns else 1
             # append_columns validates contiguity + overlay collisions
-            self._apply_insert_columns_locked(collection, typed, start_id)
+            adopted, copied = self._apply_insert_columns_locked(
+                collection, typed, start_id
+            )
+            _tracing.annotate(adopted_bytes=adopted, copied_bytes=copied)
             if self._wal_enabled_locked():  # base64 encode only when a log exists
                 self._log_locked(
                     {

@@ -169,7 +169,37 @@ class PredictionWriter:
         return failures
 
 
-def _prediction_columns(predicted_df: DataFrame) -> dict[str, Column]:
+_OUTPUT_COLS = ("prediction", "probability")
+
+
+def _store_column(column: np.ndarray) -> Column:
+    if column.ndim == 1 and column.dtype == object:
+        return Column.from_values(column.tolist())
+    return Column.from_numpy(np.asarray(column, dtype=np.float64))
+
+
+def _input_columns(frame: DataFrame) -> dict[str, Column]:
+    """The test frame's input fields as typed columns, built once a
+    frame and shared by every classifier's prediction collection. A
+    numeric field read from the store is a read-only view of the test
+    dataset's own buffer, so each collection's append takes it by
+    reference (``Column.adopts``) instead of copying it."""
+    lock = frame.__dict__.setdefault("_input_columns_lock", threading.Lock())
+    with lock:
+        columns = frame.__dict__.get("_input_columns")
+        if columns is None:
+            columns = {
+                name: _store_column(frame._column(name))
+                for name in frame.columns
+                if name != FEATURES_COL and name not in _OUTPUT_COLS
+            }
+            frame.__dict__["_input_columns"] = columns
+    return columns
+
+
+def _prediction_columns(
+    features_testing: DataFrame, predicted_df: DataFrame
+) -> dict[str, Column]:
     """Column-major view of a prediction frame as typed columns: every
     column except the assembled ``features`` vector (the reference also
     deletes ``rawPrediction``, which we never materialize),
@@ -179,23 +209,18 @@ def _prediction_columns(predicted_df: DataFrame) -> dict[str, Column]:
     model_builder.py:232-247 boxes it per row at driver collect time).
     Numeric columns hand their buffers to the store directly — no
     per-value float()/isnan loops (the tail the reference never fixed,
-    model_builder.py:237-247)."""
-    out: dict[str, Column] = {}
-    for name in predicted_df.columns:
-        if name == FEATURES_COL:
-            continue
-        column = predicted_df._column(name)
-        if column.ndim > 1:
-            out[name] = Column.from_numpy(
-                np.asarray(column, dtype=np.float64)
-            )
-        elif column.dtype == object:
-            out[name] = Column.from_values(column.tolist())
-        else:
-            out[name] = Column.from_numpy(
-                np.asarray(column, dtype=np.float64)
-            )
-    return out
+    model_builder.py:237-247); the input fields are the test frame's
+    once-built columns (:func:`_input_columns`)."""
+    inputs = _input_columns(features_testing)
+    return {
+        name: (
+            _store_column(predicted_df._column(name))
+            if name in _OUTPUT_COLS
+            else inputs[name]
+        )
+        for name in predicted_df.columns
+        if name != FEATURES_COL
+    }
 
 
 def train_one(
@@ -423,10 +448,11 @@ def _predict_and_write(
         return metadata
 
     write_rows = predicted_df.count()
-    # the prediction frame carries every input field: on a wide table
-    # the typed-column copies and the write follow ``fields``, not rows
+    # the prediction frame carries every input field: the first
+    # classifier of a build types them (NaN scans on a wide table), the
+    # others find them built, and the store takes them by reference
     with _tracing.span("write:columns", rows=write_rows):
-        columns = _prediction_columns(predicted_df)
+        columns = _prediction_columns(features_testing, predicted_df)
         _tracing.annotate(fields=len(columns))
     write_bytes = sum(
         int(column.resident_nbytes()) for column in columns.values()

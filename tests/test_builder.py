@@ -1,11 +1,19 @@
 """End-to-end model builder: Titanic-like data through the documented
 preprocessor into all five classifiers."""
 
+import os
+import sys
+import threading
+import time
+
 import numpy as np
 import pytest
 
+from learningorchestra_tpu.core.columns import Column
 from learningorchestra_tpu.core.ingest import ingest_csv, write_ingest_metadata
 from learningorchestra_tpu.core.store import ROW_ID
+from learningorchestra_tpu.frame.dataframe import DataFrame
+from learningorchestra_tpu.ml import builder
 from learningorchestra_tpu.ml.builder import build_model
 from learningorchestra_tpu.ops.dtype import convert_field_types
 from tests.test_frame import DOCUMENTED_PREPROCESSOR
@@ -129,6 +137,112 @@ class TestBuildModel:
             titanic_store, "titanic_train", "titanic_test", code, ["nb"]
         )
         assert "F1" not in results[0]
+
+
+NUMERIC_PREPROCESSOR = (
+    "from pyspark.ml.feature import VectorAssembler\n"
+    "feature_cols = [c for c in training_df.schema.names if c != 'label']\n"
+    "assembler = VectorAssembler(inputCols=feature_cols, outputCol='features')\n"
+    "features_training = assembler.transform(training_df)\n"
+    "features_testing = assembler.transform(testing_df)\n"
+    "features_evaluation = assembler.transform(testing_df)\n"
+)
+
+
+class TestPredictionCollectionsShareTheTestColumns:
+    """Every prediction collection of a build holds the test dataset's
+    own input buffers, taken by reference; what it stores reads as it
+    did when each collection copied them."""
+
+    FIELDS = ["f0", "f1", "f2", "f3"]
+    CLASSIFIERS = ["lr", "nb", "dt"]
+
+    @pytest.fixture()
+    def numeric_store(self, store, rng):
+        for name, rows in (("num_train", 240), ("num_test", 60)):
+            X = rng.random((rows, len(self.FIELDS)))
+            columns = {field: X[:, i].copy() for i, field in enumerate(self.FIELDS)}
+            columns["label"] = (X[:, 0] + X[:, 1] > 1.0).astype(np.float64)
+            store.insert_one(name, {ROW_ID: 0, "filename": name, "finished": True,
+                                    "fields": self.FIELDS + ["label"]})
+            store.insert_columns(name, columns)
+        return store
+
+    def _build(self, store):
+        results = build_model(store, "num_train", "num_test", NUMERIC_PREPROCESSOR,
+                              self.CLASSIFIERS)
+        return {r["classificator"]: r["filename"] for r in results}
+
+    def _contents(self, store, names):
+        out = {}
+        for clf, name in names.items():
+            meta = store.find_one(name, {ROW_ID: 0})
+            rows = [d for d in store.find(name) if d[ROW_ID] != 0]
+            out[clf] = (rows, {k: v for k, v in meta.items()
+                               if k not in ("fit_time", "timings")})
+        return out
+
+    def test_the_input_columns_are_the_test_datasets_own(self, numeric_store, monkeypatch):
+        with monkeypatch.context() as patch:
+            patch.setattr(Column, "adopts", lambda self, other: False)
+            copied = self._contents(numeric_store, self._build(numeric_store))
+        names = self._build(numeric_store)
+        assert self._contents(numeric_store, names) == copied
+        test = numeric_store.read_column_arrays("num_test")
+        for name in names.values():
+            stored = numeric_store.read_column_arrays(name)
+            assert list(stored) == self.FIELDS + ["label", "prediction", "probability"]
+            for field in self.FIELDS + ["label"]:
+                assert np.shares_memory(stored[field].data, test[field].data), (name, field)
+            for field in ("prediction", "probability"):
+                assert not np.shares_memory(stored[field].data, test["f0"].data)
+
+        numeric_store.set_column("num_test", "f0", np.zeros(60))
+        numeric_store.update_one("num_test", {ROW_ID: 2}, {"f1": -1.0})
+        assert self._contents(numeric_store, names) == copied
+
+    def test_threads_asking_at_once_share_one_set_of_columns(self, rng):
+        """More threads than cores ask for a frame's input columns at
+        once, with a short switch interval: every one gets the same
+        columns, typed once."""
+        frame = DataFrame({f"f{i}": rng.random(500) for i in range(16)})
+        threads = (os.cpu_count() or 4) + 4
+        start = threading.Barrier(threads)
+        typed = []
+        store_column = builder._store_column
+
+        def counted(column):
+            typed.append(column)
+            time.sleep(0.001)  # the typing outlasts the other threads' start
+            return store_column(column)
+
+        def ask():
+            start.wait(timeout=60)
+            got.append(builder._input_columns(frame))
+
+        got = []
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            with pytest.MonkeyPatch.context() as patch:
+                patch.setattr(builder, "_store_column", counted)
+                _run_side_by_side([threading.Thread(target=ask) for _ in range(threads)])
+        finally:
+            sys.setswitchinterval(interval)
+        assert len(got) == threads
+        assert all(columns is got[0] for columns in got)
+        assert len(typed) == 16 and all(isinstance(c, Column) for c in got[0].values())
+
+    def test_each_build_writes_its_collections_with_one_append_each(self, numeric_store):
+        spans = [span for span, path in _traced_spans(lambda: self._build(numeric_store))
+                 if span["name"] == "store:append" and "phase:write" in path]
+        assert len(spans) == len(self.CLASSIFIERS)
+        for span in spans:
+            meta = span["meta"]
+            assert meta["rows"] == 60 and meta["fields"] == len(self.FIELDS) + 3
+            assert meta["adopted_bytes"] == 60 * 8 * (len(self.FIELDS) + 1)
+            # the prediction (8 B a row) and the two-class probability (16)
+            assert meta["copied_bytes"] == 60 * 24
 
 
 class TestBuildTraceSpans:
