@@ -13,6 +13,8 @@ import importlib.util
 import json
 import os
 
+from . import correct
+
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 
@@ -52,6 +54,8 @@ class Cell:
             c for c in spec["configs"] if c["name"] == self.workload["config"]
         )
         self.config = read_json(os.path.join(root, entry["file"]))
+        # refuses a label it cannot check, and a booster of K classes
+        self.classes = correct.label_classes(self.config)
         self.mix = read_json(
             os.path.join(bench_dir, "traffic", f"{self.workload['traffic']}.json")
         )

@@ -11,6 +11,12 @@ alone. Which
 numbers are compared follows from the configuration's ``classifiers``
 (``NUMBERS``); each has its limit in the configuration's ``limits``,
 set from readings that PERF.md gives.
+
+A configuration's ``label`` is ``"binary"`` or ``{"classes": K}`` with K
+at least 3 (:func:`label_classes`). A binary configuration is compared by
+the expressions it always was; at K classes a stored probability row is
+K wide, and a probability compared is every column but class 0's, which
+is one less the others.
 """
 
 from __future__ import annotations
@@ -33,6 +39,32 @@ TREES = ("dt", "rf", "gb")
 # a file's time comes from the kernel's coarse clock, which may lag the
 # instant the build was posted at by a tick
 MTIME_SLACK_S = 0.05
+
+
+def label_classes(config: dict) -> int:
+    """The number of classes a configuration's labels hold: two where
+    ``label`` is ``"binary"`` or absent (what every configuration meant
+    before the key was read). Refuses a ``label`` that is neither that
+    nor ``{"classes": K}`` with K at least 3, and a K-class configuration
+    that lists ``gb``."""
+    label = config.get("label", "binary")
+    if label == "binary":
+        return 2
+    if not (
+        isinstance(label, dict) and set(label) == {"classes"}
+        and type(label["classes"]) is int and label["classes"] >= 3
+    ):
+        raise ValueError(
+            f"configuration {config.get('name')!r}: label is {label!r}; it must be "
+            '"binary" or {"classes": K} with K >= 3'
+        )
+    if "gb" in config["classifiers"]:
+        raise ValueError(
+            f"configuration {config.get('name')!r} has {label['classes']} classes and "
+            "lists gb: the program's gradient-boosted trees are binary only, as "
+            "MLlib's GBTClassifier is (learningorchestra_tpu/ml/trees.py, GBTClassifier.fit)"
+        )
+    return label["classes"]
 
 
 def expected_numbers(classifiers: list[str]) -> list[str]:
@@ -61,8 +93,9 @@ def read_build(system, ref, config: dict, names: dict, models_dir: str,
                since: float, y_test: np.ndarray, sample: np.ndarray):
     """What the build posted at ``since`` left behind, per classifier:
     the metadata row, the published model, the stored probabilities on
-    the sampled rows and the stored answers' log-loss. Returns
-    ``(outputs, violations)``."""
+    the sampled rows, ``(sample, classes)``, and the stored answers'
+    log-loss. Returns ``(outputs, violations)``."""
+    classes = label_classes(config)
     outputs, violations = {}, []
     for clf in config["classifiers"]:
         name = f"{names['test']}_prediction_{clf}"
@@ -85,12 +118,26 @@ def read_build(system, ref, config: dict, names: dict, models_dir: str,
             continue
         labels = stored["prediction"].astype(np.int64)
         proba = stored["probability"]
+        if proba.ndim != 2 or proba.shape[1] != classes:
+            violations.append(
+                f"{clf}: stored probabilities of shape {proba.shape}, "
+                f"{classes} classes in the labels"
+            )
+            continue
         if not np.array_equal(stored["label"].astype(np.int64), y_test):
             violations.append(f"{clf}: stored rows are not the test rows in order")
-        decided = np.abs(proba[:, 1] - 0.5) > 1e-6
-        if np.any((labels != (proba[:, 1] > 0.5))[decided]):
+        if np.any(np.abs(proba.sum(axis=1) - 1.0) > 1e-5):
+            violations.append(f"{clf}: a stored probability row does not sum to 1")
+        if classes == 2:
+            decided = np.abs(proba[:, 1] - 0.5) > 1e-6
+            chosen = proba[:, 1] > 0.5
+        else:  # the top class, where the top two differ
+            top_two = np.partition(proba, -2, axis=1)[:, -2:]
+            decided = top_two[:, 1] - top_two[:, 0] > 1e-6
+            chosen = proba.argmax(axis=1)
+        if np.any((labels != chosen)[decided]):
             violations.append(f"{clf}: a stored label is not its probability's")
-        accuracy, f1 = ref.accuracy_f1(labels, y_test)
+        accuracy, f1 = ref.accuracy_f1(labels, y_test, classes)
         if (
             abs(accuracy - float(meta["accuracy"])) > 1e-5
             or abs(f1 - float(meta["F1"])) > 1e-5
@@ -101,8 +148,8 @@ def read_build(system, ref, config: dict, names: dict, models_dir: str,
             )
         outputs[clf] = {
             "model": read_checkpoint(path),
-            "proba": np.asarray(proba[sample, 1], dtype=np.float64),
-            "logloss": ref.logloss(proba[:, 1], y_test),
+            "proba": np.asarray(proba[sample], dtype=np.float64),
+            "logloss": ref.logloss_k(proba, y_test),
             "accuracy": accuracy,
         }
     return outputs, violations
@@ -144,6 +191,7 @@ class Comparison:
         self.config = config
         self.hyper = config["hyper"]
         self.seed = int(seed)
+        self.classes = label_classes(config)
         self.X_train, self.y_train = X_train, y_train
         self.X_test, self.y_test = X_test, y_test
         self.sample = sample_rows(seed, len(X_test), int(config["correct"]["sample_rows"]))
@@ -154,17 +202,20 @@ class Comparison:
         self.details: dict = {}
         classifiers = config["classifiers"]
         if "lr" in classifiers:
-            self.fits["lr"] = ref.lr_fit(X_train, y_train)
+            self.fits["lr"] = ref.lr_fit(X_train, y_train, classes=self.classes)
         if "nb" in classifiers:
-            self.fits["nb"] = ref.nb_fit(X_train, y_train, self.hyper["nb_smoothing"])
+            self.fits["nb"] = ref.nb_fit(
+                X_train, y_train, self.hyper["nb_smoothing"], self.classes
+            )
         if any(clf in classifiers for clf in TREES):
             bins = int(self.hyper["max_bins"])
             self.thresholds = ref.quantile_thresholds(X_train, bins)
             self.bins = ref.bin_matrix(X_train, self.thresholds)
             tree = ref.grow_model(
-                self.bins, self.thresholds, y_train, bins, int(self.hyper["max_depth"])
+                self.bins, self.thresholds, y_train, bins, int(self.hyper["max_depth"]),
+                classes=self.classes,
             )
-            self.own_tree_loss = ref.logloss(model_proba(ref, tree, X_test)[:, 1], y_test)
+            self.own_tree_loss = ref.logloss_k(model_proba(ref, tree, X_test), y_test)
 
     def widest(self, name: str, value: float) -> None:
         value = float(value)
@@ -186,8 +237,8 @@ class Comparison:
 
     # --- audits of a model in the published layout ----------------------
     def audit_lr(self, proba_sample: np.ndarray) -> dict:
-        want = self.ref.lr_proba(self.fits["lr"], self.X_sample)[:, 1]
-        return {"lr_prob_gap": np.abs(proba_sample - want).max()}
+        want = self.ref.lr_proba(self.fits["lr"], self.X_sample)
+        return {"lr_prob_gap": np.abs(proba_sample[:, 1:] - want[:, 1:]).max()}
 
     def audit_nb(self, model: dict) -> dict:
         theta, prior = self.fits["nb"]
@@ -211,7 +262,7 @@ class Comparison:
         the gains. Yields ``(t, leaf_sums, gains)``, ``gains`` being
         ``(best, own, chosen, weight)`` per node or None."""
         depth, bins = int(model["max_depth"]), int(self.hyper["max_bins"])
-        channels = self.ref.class_channels(self.y_train)
+        channels = self.ref.class_channels(self.y_train, classes=self.classes)
         for t in range(len(model["features_heap"])):
             leaf = self.ref.route_blocks(
                 self.X_train, model["features_heap"][t], model["thresholds_heap"][t], depth
@@ -235,9 +286,10 @@ class Comparison:
 
     def audit_rf(self, model: dict) -> dict:
         """A forest's leaves carry its own bootstrap, so a leaf's
-        published share is held against the share of all rows in units
-        of the bootstrap's standard error, ``sqrt(p (1 - p) / n)``: the
-        root mean square over all well-filled leaves is 1 for a Poisson
+        published share of each class but class 0 is held against the
+        share of all rows in units of the bootstrap's standard error,
+        ``sqrt(p (1 - p) / n)``: the root mean square over all
+        well-filled leaves and those classes is 1 for a Poisson
         bootstrap of all the rows. Gains on trees drawn from the seed:
         a node's feature is the best of the fitter's random subset, so
         the published threshold is held against the best *of its
@@ -247,10 +299,14 @@ class Comparison:
         z_squares, split = [], 0.0
         for t, sums, gains in self.audit_classification_trees(model, checked):
             shares, count = self.ref.leaf_shares(sums)
-            keep = self.big(count) & (shares[:, 1] > 0) & (shares[:, 1] < 1)
-            error = np.sqrt(shares[keep, 1] * shares[keep, 0] / count[keep])
-            z = (model["leaf_probs"][t][keep, 1].astype(np.float64) - shares[keep, 1]) / error
-            z_squares.append(z**2)
+            published = model["leaf_probs"][t].astype(np.float64)
+            for k in range(1, shares.shape[1]):
+                share = shares[:, k]
+                rest = np.delete(shares, k, axis=1).sum(axis=1)  # 1 - p
+                keep = self.big(count) & (share > 0) & (share < 1)
+                error = np.sqrt(share[keep] * rest[keep] / count[keep])
+                z = (published[keep, k] - share[keep]) / error
+                z_squares.append(z**2)
             if gains is not None:
                 best, own, chosen, _ = gains
                 split = max(split, self.split_gap(best, own, chosen))
@@ -300,16 +356,21 @@ class Comparison:
         return (logloss - self.own_tree_loss) / self.own_tree_loss
 
     # --- the build ------------------------------------------------------
+    def stored_gap(self, clf: str, model: dict, proba: np.ndarray) -> dict:
+        """Stored probabilities of the sampled rows against the published
+        model followed in float64 (LR's: against the reference's fit)."""
+        if clf == "lr":
+            return self.audit_lr(proba)
+        followed = model_proba(self.ref, model, self.X_sample)
+        pred = "nb_pred_gap" if clf == "nb" else "tree_pred_gap"
+        return {pred: np.abs(proba[:, 1:] - followed[:, 1:]).max()}
+
     def compare(self, outputs: dict) -> None:
         for clf, out in outputs.items():
             model = out["model"]
             self.details[clf] = {"logloss": out["logloss"], "accuracy": out["accuracy"]}
-            if clf == "lr":
-                found = self.audit_lr(out["proba"])
-            else:
-                followed = model_proba(self.ref, model, self.X_sample)[:, 1]
-                pred = "nb_pred_gap" if clf == "nb" else "tree_pred_gap"
-                found = {pred: np.abs(out["proba"] - followed).max()}
+            found = self.stored_gap(clf, model, out["proba"])
+            if clf != "lr":
                 found.update(getattr(self, f"audit_{clf}")(model))
                 if clf == "dt":
                     found["dt_loss_gap"] = abs(self.loss_gap(out["logloss"]))
@@ -338,20 +399,20 @@ def control_numbers(comparison: Comparison, outputs: dict) -> dict:
     configuration states. Its models, in the published layout, go through
     the same audits; each reading is what the same number shows then."""
     c, ref = comparison, comparison.ref
-    hyper, classifiers = c.hyper, c.config["classifiers"]
+    hyper, classifiers, classes = c.hyper, c.config["classifiers"], c.classes
     low = ref.to_bfloat16
     X_low, sample_low, test_low = low(c.X_train), low(c.X_sample), low(c.X_test)
     out = {}
     if "lr" in classifiers:
-        fit = ref.lr_fit(X_low, c.y_train)
-        out.update(c.audit_lr(ref.lr_proba(fit, sample_low)[:, 1]))
+        fit = ref.lr_fit(X_low, c.y_train, classes=classes)
+        out.update(c.audit_lr(ref.lr_proba(fit, sample_low)))
     if "nb" in classifiers:
-        theta, prior = ref.nb_fit(X_low, c.y_train, hyper["nb_smoothing"])
+        theta, prior = ref.nb_fit(X_low, c.y_train, hyper["nb_smoothing"], classes)
         out.update(c.audit_nb({"theta": theta, "prior": prior}))
     gaps = [
         np.abs(
-            model_proba(ref, entry["model"], sample_low)[:, 1]
-            - model_proba(ref, entry["model"], c.X_sample)[:, 1]
+            model_proba(ref, entry["model"], sample_low)[:, 1:]
+            - model_proba(ref, entry["model"], c.X_sample)[:, 1:]
         ).max()
         for clf, entry in outputs.items() if clf != "lr"
     ]
@@ -364,17 +425,18 @@ def control_numbers(comparison: Comparison, outputs: dict) -> dict:
     cuts = ref.quantile_thresholds(X_low, bins)
     binned = ref.bin_matrix(X_low, cuts)
     if "dt" in classifiers:
-        tree = ref.grow_model(binned, cuts, c.y_train, bins, depth)
+        tree = ref.grow_model(binned, cuts, c.y_train, bins, depth, classes=classes)
         out.update(c.audit_dt(tree))
-        loss = ref.logloss(model_proba(ref, tree, test_low)[:, 1], c.y_test)
+        loss = ref.logloss_k(model_proba(ref, tree, test_low), c.y_test)
         out["dt_loss_gap"] = abs(c.loss_gap(loss))
     if "rf" in classifiers:
         forest = ref.grow_forest(
             binned, cuts, c.y_train, bins, depth,
             trees=int(c.config["correct"]["rf_trees_checked"]), seed=c.seed,
+            classes=classes,
         )
         out.update(c.audit_rf(forest))
-        loss = ref.logloss(model_proba(ref, forest, test_low)[:, 1], c.y_test)
+        loss = ref.logloss_k(model_proba(ref, forest, test_low), c.y_test)
         out["ensemble_loss_gap"] = c.loss_gap(loss)
     if "gb" in classifiers:
         rounds = int(c.config["correct"]["gbt_rounds_first"]) + 1
@@ -386,6 +448,15 @@ def control_numbers(comparison: Comparison, outputs: dict) -> dict:
     return {name: float(value) for name, value in out.items()}
 
 
+def widened(model: dict, classes: int) -> dict:
+    """A two-class tree model in the layout of ``classes``: the folded
+    class's share in column 1, nothing in the columns after it."""
+    probs = model["leaf_probs"]
+    wide = np.zeros(probs.shape[:-1] + (classes,), dtype=probs.dtype)
+    wide[..., :2] = probs
+    return dict(model, leaf_probs=wide)
+
+
 def fault_numbers(comparison: Comparison, outputs: dict) -> dict:
     """The reference put in the program's place with a fault planted, at
     the cell's own size: ``half_batch`` (every fit sees the first half of
@@ -393,41 +464,51 @@ def fault_numbers(comparison: Comparison, outputs: dict) -> dict:
     every tree may split on the first column alone), ``wrong_split``
     (naive Bayes fitted to the test rows) and, for the published forest,
     ``random_threshold`` (every node keeps its feature and takes another
-    of its candidates at random). ``{fault: readings}``."""
+    of its candidates at random). At more than two classes also
+    ``binary`` (every fit on the labels folded to 0 and 1 or more, its
+    model laid out as K classes with nothing after class 1; naive Bayes'
+    class rows 1 and on all the folded class's) and ``swapped`` (the
+    build's stored probabilities of classes 1 and 2 exchanged).
+    ``{fault: readings}``."""
     c, ref = comparison, comparison.ref
     hyper, classifiers, options = c.hyper, c.config["classifiers"], c.config["correct"]
+    classes = c.classes
     bins, depth = int(hyper["max_bins"]), int(hyper["max_depth"])
-    trees, rounds = int(options["rf_trees_checked"]), int(options["gbt_rounds_first"]) + 1
-    boost = (hyper["gbt_step"], hyper["gbt_lambda"], hyper["gbt_hessian_floor"])
     half = len(c.X_train) // 2
     X, y = c.X_train[:half], c.y_train[:half]
     out = {"half_batch": {}, "first_feature": {}, "wrong_split": {}, "random_threshold": {}}
     if "nb" in classifiers:
-        theta, prior = ref.nb_fit(X, y, hyper["nb_smoothing"])
+        theta, prior = ref.nb_fit(X, y, hyper["nb_smoothing"], classes)
         out["half_batch"].update(c.audit_nb({"theta": theta, "prior": prior}))
-        theta, prior = ref.nb_fit(c.X_test, c.y_test, hyper["nb_smoothing"])
+        theta, prior = ref.nb_fit(c.X_test, c.y_test, hyper["nb_smoothing"], classes)
         out["wrong_split"].update(c.audit_nb({"theta": theta, "prior": prior}))
     if any(clf in classifiers for clf in TREES):
         cuts = ref.quantile_thresholds(X, bins)
         binned = ref.bin_matrix(X, cuts)
 
-        def first_only(level):
-            mask = np.zeros((1 << level, c.bins.shape[1]), dtype=bool)
-            mask[:, 0] = True
-            return mask
+    def first_only(level):
+        mask = np.zeros((1 << level, c.bins.shape[1]), dtype=bool)
+        mask[:, 0] = True
+        return mask
 
-        def loss(model):
-            return ref.logloss(model_proba(ref, model, c.X_test)[:, 1], c.y_test)
+    def loss(model):
+        return ref.logloss_k(model_proba(ref, model, c.X_test), c.y_test)
 
     if "dt" in classifiers:
-        out["half_batch"].update(c.audit_dt(ref.grow_model(binned, cuts, y, bins, depth)))
-        poor = ref.grow_model(c.bins, c.thresholds, c.y_train, bins, depth, allowed=first_only)
+        out["half_batch"].update(
+            c.audit_dt(ref.grow_model(binned, cuts, y, bins, depth, classes=classes))
+        )
+        poor = ref.grow_model(
+            c.bins, c.thresholds, c.y_train, bins, depth, allowed=first_only, classes=classes
+        )
         out["first_feature"].update(c.audit_dt(poor), dt_loss_gap=abs(c.loss_gap(loss(poor))))
     if "rf" in classifiers:
-        forest = ref.grow_forest(binned, cuts, y, bins, depth, trees, c.seed)
+        trees = int(options["rf_trees_checked"])
+        forest = ref.grow_forest(binned, cuts, y, bins, depth, trees, c.seed, classes=classes)
         out["half_batch"].update(c.audit_rf(forest))
         poor = ref.grow_forest(
-            c.bins, c.thresholds, c.y_train, bins, depth, trees, c.seed, allowed=first_only
+            c.bins, c.thresholds, c.y_train, bins, depth, trees, c.seed, allowed=first_only,
+            classes=classes,
         )
         out["first_feature"]["ensemble_loss_gap"] = c.loss_gap(loss(poor))
         spoiled = dict(outputs["rf"]["model"])
@@ -438,13 +519,61 @@ def fault_numbers(comparison: Comparison, outputs: dict) -> dict:
         ]
         out["random_threshold"]["rf_split_gap"] = c.audit_rf(spoiled)["rf_split_gap"]
     if "gb" in classifiers:
+        rounds = int(options["gbt_rounds_first"]) + 1
+        boost = (hyper["gbt_step"], hyper["gbt_lambda"], hyper["gbt_hessian_floor"])
         boosted = ref.grow_boosted(binned, cuts, y, bins, depth, rounds, *boost)
         out["half_batch"].update(c.audit_gb(boosted))
         poor = ref.grow_boosted(
             c.bins, c.thresholds, c.y_train, bins, depth, rounds, *boost, allowed=first_only
         )
         out["first_feature"]["gb_split_gap"] = c.audit_gb(poor)["gb_split_gap"]
+    if classes > 2:
+        out["binary"] = binary_numbers(c, loss)
+        out["swapped"] = swapped_numbers(c, outputs)
     return {
         fault: {name: float(value) for name, value in readings.items()}
         for fault, readings in out.items()
     }
+
+
+def binary_numbers(c: Comparison, loss) -> dict:
+    """``binary``: every fit of the reference on the training rows with
+    the labels folded to 0 and 1 or more, laid out as K classes."""
+    ref, hyper, classifiers = c.ref, c.hyper, c.config["classifiers"]
+    folded = np.minimum(c.y_train, 1)
+    out = {}
+    if "lr" in classifiers:
+        two = ref.lr_proba(ref.lr_fit(c.X_train, folded), c.X_sample)
+        wide = np.zeros((len(two), c.classes))
+        wide[:, :2] = two
+        out.update(c.audit_lr(wide))
+    if "nb" in classifiers:
+        theta, prior = ref.nb_fit(c.X_train, folded, hyper["nb_smoothing"])
+        rows = np.minimum(np.arange(c.classes), 1)
+        out.update(c.audit_nb({"theta": theta[rows], "prior": prior[rows]}))
+    bins, depth = int(hyper["max_bins"]), int(hyper["max_depth"])
+    if "dt" in classifiers:
+        tree = widened(ref.grow_model(c.bins, c.thresholds, folded, bins, depth), c.classes)
+        out.update(c.audit_dt(tree), dt_loss_gap=abs(c.loss_gap(loss(tree))))
+    if "rf" in classifiers:
+        forest = widened(
+            ref.grow_forest(
+                c.bins, c.thresholds, folded, bins, depth,
+                int(c.config["correct"]["rf_trees_checked"]), c.seed,
+            ),
+            c.classes,
+        )
+        out.update(c.audit_rf(forest), ensemble_loss_gap=c.loss_gap(loss(forest)))
+    return out
+
+
+def swapped_numbers(c: Comparison, outputs: dict) -> dict:
+    """``swapped``: the build's stored probabilities with the columns of
+    classes 1 and 2 exchanged."""
+    out = {}
+    for clf, entry in outputs.items():
+        proba = entry["proba"].copy()
+        proba[:, [1, 2]] = proba[:, [2, 1]]
+        for name, gap in c.stored_gap(clf, entry["model"], proba).items():
+            out[name] = max(out.get(name, 0.0), float(gap))
+    return out

@@ -9,6 +9,10 @@ Two kinds of function live here.
   penalty (convex, so Newton's method finds the optimum that L-BFGS is
   converging to, whatever its path), and the greedy histogram decision
   tree (quantile candidates, best gini gain at every node).
+- Labels are class indices ``0..classes-1``. At two classes every
+  routine takes the code it always had; ``classes`` above two takes the
+  K-class code beside it (naive Bayes' class rows from a one-hot, the
+  multinomial logistic regression Hessian-free, K gini channels).
 - An ensemble's fit has no single right answer: near-tied gains flip on
   the last bit of a sum, and the forest's bootstrap is the fitter's own
   random stream. So every published tree is *audited* from the data
@@ -40,18 +44,21 @@ def as_matrix(columns: list[np.ndarray], dtype=np.float64) -> np.ndarray:
 
 # --- naive Bayes (multinomial, smoothing 1) -------------------------------
 
-def _blocks(n: int):
-    return [(start, min(start + BLOCK, n)) for start in range(0, n, BLOCK)]
+def _blocks(n: int, size: int = BLOCK):
+    return [(start, min(start + size, n)) for start in range(0, n, size)]
 
 
-def _over_blocks(function, n: int):
+def _over_blocks(function, n: int, size: int = BLOCK):
     """``function(start, stop)`` over row blocks on a few threads (numpy
     releases the lock inside its loops); the results in block order."""
     with ThreadPoolExecutor(max_workers=THREADS) as pool:
-        return list(pool.map(lambda span: function(*span), _blocks(n)))
+        return list(pool.map(lambda span: function(*span), _blocks(n, size)))
 
 
-def nb_fit(X: np.ndarray, y: np.ndarray, smoothing: float = 1.0):
+def _binary_sums(X: np.ndarray, y: np.ndarray):
+    """Naive Bayes' per-class feature sums and counts of a row block, two
+    classes: class 0's as the total less class 1's."""
+
     def sums(start, stop):
         block = X[start:stop].astype(np.float64)
         positive = y[start:stop].astype(np.float64)
@@ -61,6 +68,21 @@ def nb_fit(X: np.ndarray, y: np.ndarray, smoothing: float = 1.0):
             [len(positive) - positive.sum(), positive.sum()]
         )
 
+    return sums
+
+
+def _class_sums(X: np.ndarray, y: np.ndarray, classes: int):
+    """The same for ``classes`` classes, from a one-hot of the labels."""
+
+    def sums(start, stop):
+        onehot = (y[start:stop, None] == np.arange(classes)).astype(np.float64)
+        return onehot.T @ X[start:stop].astype(np.float64), onehot.sum(axis=0)
+
+    return sums
+
+
+def nb_fit(X: np.ndarray, y: np.ndarray, smoothing: float = 1.0, classes: int = 2):
+    sums = _binary_sums(X, y) if classes == 2 else _class_sums(X, y, classes)
     parts = _over_blocks(sums, len(X))
     feature_sums = sum(p[0] for p in parts)
     counts = sum(p[1] for p in parts)
@@ -80,10 +102,13 @@ def nb_proba(model, X: np.ndarray) -> np.ndarray:
 
 # --- logistic regression (no penalty, intercept) --------------------------
 
-def lr_fit(X: np.ndarray, y: np.ndarray, iterations: int = 12, tol: float = 1e-9):
+def lr_fit(X: np.ndarray, y: np.ndarray, iterations: int = 12, tol: float = 1e-9,
+           classes: int = 2):
     """Newton's method in float64 on the raw columns with an intercept:
     without a penalty the optimum is the one that L-BFGS on standardised
-    columns converges to."""
+    columns converges to. Above two classes :func:`softmax_fit`."""
+    if classes > 2:
+        return softmax_fit(X, y, classes)
     n, d = X.shape
     w = np.zeros(d + 1)
 
@@ -109,8 +134,167 @@ def lr_fit(X: np.ndarray, y: np.ndarray, iterations: int = 12, tol: float = 1e-9
 
 def lr_proba(model, X: np.ndarray) -> np.ndarray:
     coef, intercept = model
+    if coef.ndim == 2:  # the multinomial's classes 1..K-1
+        return pinned_softmax(X.astype(np.float64) @ coef + intercept)
     p = 1.0 / (1.0 + np.exp(-(X.astype(np.float64) @ coef + intercept)))
     return np.stack([1 - p, p], axis=1)
+
+
+# --- multinomial logistic regression (no penalty, intercept) --------------
+#
+# Class 0's margin is pinned at zero, so the parameters are ``W`` of
+# ``(features + 1, classes - 1)``, the last row the intercepts. MLlib
+# fits all K rows and centres them; the probabilities, which are all
+# that is compared, are the same at the optimum. The Hessian has
+# ((features + 1)(classes - 1))^2 entries, too many to fill at hundreds
+# of columns, so Newton's step is solved by conjugate gradients from
+# Hessian-vector products, each one pass over the rows.
+
+# rows a pass takes at a time: at hundreds of columns a float64 block of
+# BLOCK rows would be gigabytes, one on each of THREADS threads
+SOFTMAX_BLOCK = 1 << 14
+
+
+def pinned_softmax(margins: np.ndarray) -> np.ndarray:
+    """Class probabilities ``(rows, K)`` from the margins of classes
+    ``1..K-1``, class 0's being zero."""
+    full = np.concatenate([np.zeros((len(margins), 1)), margins], axis=1)
+    full -= full.max(axis=1, keepdims=True)
+    e = np.exp(full)
+    return e / e.sum(axis=1, keepdims=True)
+
+
+def _margins(block: np.ndarray, W: np.ndarray) -> np.ndarray:
+    return block @ W[:-1] + W[-1]
+
+
+def softmax_state(X: np.ndarray, y: np.ndarray, W: np.ndarray) -> dict:
+    """One pass over the rows at ``W``: the summed loss, its gradient,
+    the probabilities of classes ``1..K-1`` by row, and the mean over the
+    rows of their covariance ``diag(p) - p p^T``."""
+
+    def one(start, stop):
+        block = X[start:stop].astype(np.float64)
+        margins = _margins(block, W)
+        top = np.maximum(margins.max(axis=1), 0.0)
+        log_total = top + np.log(np.exp(-top) + np.exp(margins - top[:, None]).sum(axis=1))
+        label = y[start:stop]
+        rows = np.arange(stop - start)
+        own = np.where(label > 0, margins[rows, np.maximum(label, 1) - 1], 0.0)
+        P = np.exp(margins - log_total[:, None])
+        residual = P.copy()
+        residual[rows[label > 0], label[label > 0] - 1] -= 1.0
+        gradient = np.vstack([block.T @ residual, residual.sum(axis=0)])
+        return (log_total - own).sum(), gradient, P, np.diag(P.sum(axis=0)) - P.T @ P
+
+    parts = _over_blocks(one, len(X), SOFTMAX_BLOCK)
+    return {
+        "loss": sum(p[0] for p in parts),
+        "gradient": sum(p[1] for p in parts),
+        "P": np.concatenate([p[2] for p in parts]),
+        "covariance": sum(p[3] for p in parts) / len(X),
+    }
+
+
+def softmax_hvp(X: np.ndarray, P: np.ndarray, V: np.ndarray) -> np.ndarray:
+    """The loss's Hessian at the probabilities ``P`` (classes ``1..K-1``)
+    times a direction ``V`` shaped as ``W``: per row the margins' change
+    ``a = x V``, then ``p * (a - p.a)`` back through the rows."""
+
+    def one(start, stop):
+        block = X[start:stop].astype(np.float64)
+        a = _margins(block, V)
+        p = P[start:stop]
+        d = p * (a - (p * a).sum(axis=1, keepdims=True))
+        return np.vstack([block.T @ d, d.sum(axis=0)])
+
+    return sum(_over_blocks(one, len(X), SOFTMAX_BLOCK))
+
+
+def _gram(X: np.ndarray) -> np.ndarray:
+    """``Z^T Z`` of the rows with the intercept's column of ones."""
+
+    def one(start, stop):
+        block = X[start:stop].astype(np.float64)
+        Z = np.empty((stop - start, block.shape[1] + 1))
+        Z[:, :-1], Z[:, -1] = block, 1.0
+        return Z.T @ Z
+
+    return sum(_over_blocks(one, len(X), SOFTMAX_BLOCK))
+
+
+def _inverse(A: np.ndarray, ridge: float) -> np.ndarray:
+    """The inverse of a symmetric positive semi-definite matrix with a
+    ridge of ``ridge`` times its mean diagonal, so that columns that are
+    constant or sum to others leave it defined."""
+    lift = ridge * max(float(np.mean(np.diag(A))), 1e-300)
+    return np.linalg.inv(A + lift * np.eye(len(A)))
+
+
+def softmax_fit(X: np.ndarray, y: np.ndarray, classes: int, tol: float = 1e-10,
+                iterations: int = 60, cg_steps: int = 250):
+    """Newton-CG in float64 on the raw columns: stops once the gradient's
+    norm has fallen to ``tol`` of its norm at zero. Conjugate gradients
+    are preconditioned by the Kronecker product of the mean class
+    covariance and the rows' Gram matrix, which takes out the columns'
+    scales and correlations; every step is taken only where it lowers the
+    loss (halved until it does)."""
+    d = X.shape[1]
+    W = np.zeros((d + 1, classes - 1))
+    gram_inv = _inverse(_gram(X), 1e-10)
+    state = softmax_state(X, y, W)
+    first = np.linalg.norm(state["gradient"])
+    for _ in range(iterations):
+        g = state["gradient"]
+        norm = np.linalg.norm(g)
+        if norm <= tol * first:
+            break
+        class_inv = _inverse(state["covariance"], 1e-10)
+        step = _conjugate_gradients(
+            lambda V: softmax_hvp(X, state["P"], V),
+            lambda R: gram_inv @ R @ class_inv,
+            g, min(0.5, np.sqrt(norm / first)) * norm, cg_steps,
+        )
+        slope = float((g * step).sum())
+        t = 1.0
+        while t > 1e-8:
+            trial = softmax_state(X, y, W - t * step)
+            # near the optimum the loss's fall is under the rounding of
+            # its sum, and a lower gradient is what says the step is sound
+            if trial["loss"] <= state["loss"] - 1e-4 * t * slope or (
+                t == 1.0 and np.linalg.norm(trial["gradient"]) < norm
+            ):
+                break
+            t /= 2
+        else:
+            break  # no step lowers the loss: as near the optimum as float64 sees
+        W -= t * step
+        state = trial
+    return W[:-1], W[-1]
+
+
+def _conjugate_gradients(hvp, precondition, g, within, steps):
+    """Solves ``H s = g`` by preconditioned conjugate gradients to a
+    residual norm of ``within``."""
+    s = np.zeros_like(g)
+    r = g.copy()
+    z = precondition(r)
+    p = z.copy()
+    rz = float((r * z).sum())
+    for _ in range(steps):
+        Hp = hvp(p)
+        curvature = float((p * Hp).sum())
+        if curvature <= 0:
+            break
+        alpha = rz / curvature
+        s += alpha * p
+        r -= alpha * Hp
+        if np.linalg.norm(r) <= within:
+            break
+        z = precondition(r)
+        rz, previous = float((r * z).sum()), rz
+        p = z + (rz / previous) * p
+    return s
 
 
 # --- published trees, followed --------------------------------------------
@@ -335,9 +519,10 @@ def leaf_sums(leaf: np.ndarray, channels: list, depth: int) -> np.ndarray:
     )
 
 
-def grow_model(bins, thresholds, y, max_bins: int, depth: int, weight=None, allowed=None):
+def grow_model(bins, thresholds, y, max_bins: int, depth: int, weight=None, allowed=None,
+               classes: int = 2):
     """The reference's own decision tree, in the published layout."""
-    channels = class_channels(y, weight)
+    channels = class_channels(y, weight, classes)
     features, cuts, leaf = grow_tree(
         bins, thresholds, channels, "gini", depth, max_bins, allowed=allowed
     )
@@ -350,7 +535,7 @@ def grow_model(bins, thresholds, y, max_bins: int, depth: int, weight=None, allo
 
 
 def grow_forest(bins, thresholds, y, max_bins: int, depth: int, trees: int, seed: int,
-                allowed=None):
+                allowed=None, classes: int = 2):
     """A forest as MLlib's defaults make it: a Poisson(1) bootstrap per
     tree and, at every node, the best of a random sqrt-sized subset of
     the features (or of ``allowed``, where a planted fault says so). The
@@ -367,7 +552,9 @@ def grow_forest(bins, thresholds, y, max_bins: int, depth: int, trees: int, seed
             return order < subset
 
         grown.append(
-            grow_model(bins, thresholds, y, max_bins, depth, weight, allowed or subsets)
+            grow_model(
+                bins, thresholds, y, max_bins, depth, weight, allowed or subsets, classes
+            )
         )
     return {
         "kind": "tree_ensemble", "max_depth": depth,
@@ -407,6 +594,17 @@ def grow_boosted(bins, thresholds, y, max_bins, depth, rounds, step, lam, hessia
 def logloss(p1: np.ndarray, y: np.ndarray) -> float:
     p = np.clip(p1.astype(np.float64), 1e-7, 1 - 1e-7)
     return float(-(y * np.log(p) + (1 - y) * np.log(1 - p)).mean())
+
+
+def logloss_k(P: np.ndarray, y: np.ndarray) -> float:
+    """Log-loss of class probabilities ``(rows, classes)``: at two
+    classes :func:`logloss` of column 1, as it always was; above, minus
+    the mean log of each row's probability of its own class, clipped as
+    there."""
+    if P.shape[1] == 2:
+        return logloss(P[:, 1], y)
+    own = P[np.arange(len(y)), y].astype(np.float64)
+    return float(-np.log(np.clip(own, 1e-7, 1 - 1e-7)).mean())
 
 
 def to_bfloat16(X: np.ndarray) -> np.ndarray:

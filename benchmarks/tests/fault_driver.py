@@ -41,8 +41,8 @@ def answer_altered():
 
     columns_of = builder._prediction_columns
 
-    def altered(frame):
-        columns = columns_of(frame)
+    def altered(*args):
+        columns = columns_of(*args)
         proba = np.array(columns["probability"].data[: columns["probability"].size])
         proba[::1000] = proba[::1000] * 0.9 + 0.05
         columns["probability"] = Column.from_numpy(proba)

@@ -165,6 +165,7 @@ class TestConfiguration:
         mine = {m["name"] for m in cell.per_layer}
         assert higgs - mine == {
             "build.fit_s.lr", "device_s.lr", "build.standardize_s", "build.lr_iterations",
+            "build.lr_matrix_passes", "device_s.lr_standardize",
         }
         assert mine - epsilon == {"build.fit_s.nb", "device_s.nb"}
         assert "build.distinct_thresholds" in mine & higgs & epsilon

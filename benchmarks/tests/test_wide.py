@@ -134,8 +134,9 @@ class TestConfiguration:
         higgs = {m["name"] for m in cells.Cell("higgs-11m.build5").per_layer}
         mine = {m["name"] for m in cell.per_layer}
         assert higgs - mine == {
-            "build.fit_s.lr", "build.fit_s.nb", "device_s.lr",
-            "build.standardize_s", "build.lr_iterations",
+            "build.fit_s.lr", "build.fit_s.nb", "device_s.lr", "device_s.nb",
+            "build.standardize_s", "build.lr_iterations", "build.lr_matrix_passes",
+            "device_s.lr_standardize",
         }
         assert {"build.hist_blocks", "build.hist_indicator_bytes"} <= mine & higgs
         assert cell.end_to_end == ["build_rows_per_s", "setup_s"]
