@@ -7,7 +7,6 @@ feature assembly feeding the classifiers.
 
 from __future__ import annotations
 
-from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 import numpy as np
@@ -15,6 +14,7 @@ import numpy as np
 from learningorchestra_tpu.frame.dataframe import DataFrame
 from learningorchestra_tpu.frame.expressions import _is_null_array
 from learningorchestra_tpu.telemetry import tracing as _tracing
+from learningorchestra_tpu.utils.hostfill import TILE_BYTES, fill_row_tiles
 
 ERROR = "error"
 SKIP = "skip"
@@ -87,14 +87,12 @@ class StringIndexer:
 
 
 # One tile of the matrix and the staging block it is filled from are
-# each at most _TILE_BYTES, so both stay in a core's cache while a tile
+# each at most TILE_BYTES, so both stay in a core's cache while a tile
 # is written. A tile is at most _TILE_MAX_COLUMNS wide and as tall as
 # the bytes allow: every copy into the staging block is then one long
 # contiguous run, made by numpy outside the interpreter lock, which is
-# what lets _FILL_THREADS fill side by side.
-_TILE_BYTES = 1 << 20
+# what lets the fill's threads (utils/hostfill.py) work side by side.
 _TILE_MAX_COLUMNS = 32
-_FILL_THREADS = 4
 
 
 def _tile_shape(rows: int, columns: int) -> tuple[int, int]:
@@ -104,7 +102,7 @@ def _tile_shape(rows: int, columns: int) -> tuple[int, int]:
     ``_TILE_MAX_COLUMNS`` columns with as many rows as the byte budget
     leaves."""
     tile_columns = max(1, min(columns, _TILE_MAX_COLUMNS))
-    tile_rows = max(1, min(rows, _TILE_BYTES // (8 * tile_columns)))
+    tile_rows = max(1, min(rows, TILE_BYTES // (8 * tile_columns)))
     return tile_rows, tile_columns
 
 
@@ -114,9 +112,9 @@ def _fill(sources: list[np.ndarray], rows: int) -> np.ndarray:
     source is read in contiguous runs into a small staging block, and
     the block is written out transposed, so no store crosses the whole
     matrix at a stride of a row. A matrix of many row tiles is shared
-    out among ``_FILL_THREADS`` threads by rows: most of what is left
-    of the time is the first touch of the matrix's fresh pages, which
-    threads take side by side."""
+    out among threads by rows (``fill_row_tiles``): most of what is
+    left of the time is the first touch of the matrix's fresh pages,
+    which threads take side by side."""
     matrix = np.empty((rows, len(sources)), dtype=np.float64)
     tile_rows, tile_columns = _tile_shape(*matrix.shape)
 
@@ -131,19 +129,7 @@ def _fill(sources: list[np.ndarray], rows: int) -> np.ndarray:
                     block[i] = source[start:stop]
                 matrix[start:stop, first:first + len(group)] = block.T
 
-    tiles = -(-rows // tile_rows)
-    threads = min(_FILL_THREADS, tiles // _FILL_THREADS)  # several tiles each
-    if threads <= 1:
-        fill_rows(0, rows)
-        return matrix
-    share = -(-tiles // threads) * tile_rows
-    with ThreadPoolExecutor(threads) as pool:
-        shares = [
-            pool.submit(fill_rows, first_row, min(rows, first_row + share))
-            for first_row in range(0, rows, share)
-        ]
-        for done in shares:
-            done.result()
+    fill_row_tiles(rows, tile_rows, fill_rows)
     return matrix
 
 

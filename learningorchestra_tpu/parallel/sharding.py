@@ -21,6 +21,8 @@ kernels that are memory-bound anyway; masks keep the math exact.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from learningorchestra_tpu.parallel.mesh import DATA_AXIS
 from learningorchestra_tpu.telemetry import tracing as _tracing
 from learningorchestra_tpu.utils.dtypepolicy import dtype_policy
+from learningorchestra_tpu.utils.hostfill import TILE_BYTES, fill_row_tiles
 from learningorchestra_tpu.utils.shapegrid import bucket_count, grid_size
 
 
@@ -55,12 +58,17 @@ def padded_row_count(n: int, multiple: int) -> int:
     return ((target + multiple - 1) // multiple) * multiple
 
 
+def _validity_mask(n: int, padded_n: int) -> np.ndarray:
+    mask = np.zeros(padded_n, dtype=bool)
+    mask[:n] = True
+    return mask
+
+
 def pad_rows(array: np.ndarray, multiple: int) -> tuple[np.ndarray, np.ndarray]:
     """Pad axis 0 to the bucketed grid; returns (padded, validity mask)."""
     n = array.shape[0]
     padded_n = padded_row_count(n, multiple)
-    mask = np.zeros(padded_n, dtype=bool)
-    mask[:n] = True
+    mask = _validity_mask(n, padded_n)
     if padded_n == n:
         return array, mask
     pad_width = [(0, padded_n - n)] + [(0, 0)] * (array.ndim - 1)
@@ -90,6 +98,32 @@ def policy_dtype(dtype):
     return dtype
 
 
+def _stage(
+    source: np.ndarray, padded_n: int, dtype
+) -> tuple[np.ndarray, int, int]:
+    """The array ``shard_rows`` ships: ``source``'s rows cast to
+    ``dtype`` and zero rows up to ``padded_n``,
+    written once into one fresh array, so no padded copy in the
+    source's dtype is ever made. The rows go in tiles of about
+    ``TILE_BYTES`` and, where there are many, on several threads
+    (``fill_row_tiles``): a serve batch stays on this one. Returns
+    ``(array, threads, tiles)``."""
+    n = source.shape[0]
+    out = np.empty((padded_n,) + source.shape[1:], dtype=dtype)
+    row_bytes = max(source.itemsize, out.itemsize) * math.prod(out.shape[1:])
+    tile_rows = max(1, TILE_BYTES // max(1, row_bytes))
+
+    def fill_rows(first_row: int, last_row: int) -> None:
+        for start in range(first_row, last_row, tile_rows):
+            stop = min(last_row, start + tile_rows)
+            # assignment casts as astype does ("unsafe"): the same bits
+            out[start:min(stop, n)] = source[start:stop]
+            out[max(start, n):stop] = 0
+
+    threads, tiles = fill_row_tiles(padded_n, tile_rows, fill_rows)
+    return out, threads, tiles
+
+
 def shard_rows(
     array: np.ndarray, mesh: Mesh, dtype=None
 ) -> tuple[jax.Array, jax.Array]:
@@ -100,28 +134,38 @@ def shard_rows(
     reductions stay local until the final psum. Float ``dtype`` requests
     flow through :func:`policy_dtype`, so ``LO_DTYPE_POLICY=bf16``
     halves every feature-matrix transfer at THE H2D funnel without any
-    caller opting in per site.
+    caller opting in per site. Where ``dtype`` is given the shipped
+    array is always a fresh one (on the CPU backend ``device_put`` may
+    alias a host buffer, and the caller's must never be handed over);
+    where it is None and no row needs padding, ``array`` ships as-is.
 
     In the active trace the host's half is an ``h2d:stage`` span (the
-    pad and the cast; ``bytes``, the host bytes they wrote) and the
-    hand-off an ``h2d:put`` span, both under the caller's span. The put
-    returns before the arrays land: it times the dispatch and the host
-    copy jax makes, not the transfer, which only a caller that waits
-    for the arrays can time (``ml/base.TrainingInput``).
+    one write of the shipped array, padded in place: ``bytes``, the
+    host bytes written, with the mask; ``threads`` and ``tiles``, how
+    many filled it and the row tiles they wrote, 0 and 0 where nothing
+    was) and the hand-off an ``h2d:put`` span, both under the caller's
+    span. The put returns before the arrays land: it times the dispatch
+    and the host copy jax makes, not the transfer, which only a caller
+    that waits for the arrays can time (``ml/base.TrainingInput``).
     """
     from learningorchestra_tpu.telemetry import profile
 
     n_shards = mesh.shape[DATA_AXIS]
     with _tracing.span("h2d:stage"):
         source = np.asarray(array)
-        padded, mask = pad_rows(source, n_shards)
-        written = int(mask.nbytes)
-        if padded is not source:
-            written += int(padded.nbytes)
-        if dtype is not None:
-            padded = padded.astype(policy_dtype(dtype))
-            written += int(padded.nbytes)
-        _tracing.annotate(bytes=written)
+        n = source.shape[0]
+        padded_n = padded_row_count(n, n_shards)
+        mask = _validity_mask(n, padded_n)
+        if dtype is None and padded_n == n:
+            padded, threads, tiles = source, 0, 0
+        else:
+            padded, threads, tiles = _stage(
+                source,
+                padded_n,
+                source.dtype if dtype is None else policy_dtype(dtype),
+            )
+        written = mask.nbytes + (0 if padded is source else padded.nbytes)
+        _tracing.annotate(bytes=int(written), threads=threads, tiles=tiles)
     sharding = row_sharded(mesh)
     # Flight-recorder byte accounting at THE H2D funnel (every matrix/
     # label transfer in the product path comes through here): counts

@@ -100,6 +100,120 @@ def test_shard_rows_masked_reduction():
     assert dev_x.addressable_shards[0].data.shape == (2, 1)
 
 
+def _plant(array: np.ndarray) -> np.ndarray:
+    """``array`` with the values a cast must carry bit for bit planted
+    in its first cells: NaN, ±inf, −0.0, a float64 denormal (0 in
+    float32) and a float32 denormal; for ints, values outside int32."""
+    flat = array.reshape(-1)
+    if array.dtype.kind == "f":
+        flat[:6] = [np.nan, np.inf, -np.inf, -0.0, 5e-324, 1e-40]
+    elif array.dtype.kind == "i":
+        flat[:3] = [2**40 + 5, -(2**35) - 3, 2**31]
+    return array
+
+
+def _shard_and_keep(monkeypatch, array, dtype):
+    """``shard_rows`` on the default mesh under a trace; returns the
+    host arrays it handed ``device_put`` and its ``h2d:stage`` span."""
+    from learningorchestra_tpu.telemetry import tracing
+
+    handed = []
+    device_put = jax.device_put
+
+    def keep(value, sharding):
+        handed.append(value)
+        return device_put(value, sharding)
+
+    monkeypatch.setattr(jax, "device_put", keep)
+    trace = tracing.Trace()
+    with tracing.activate(trace):
+        shard_rows(array, default_mesh(), dtype=dtype)
+    (stage,) = [s for s in trace.spans if s.name == "h2d:stage"]
+    return handed, stage
+
+
+class TestStage:
+    """``shard_rows`` writes the array it ships once, padded in place:
+    the same bits ``np.pad`` then ``astype`` gave, with no padded copy
+    in the source's dtype, on threads where the array is large."""
+
+    @pytest.mark.parametrize("policy", ["f32", "bf16"])
+    @pytest.mark.parametrize("rows", [37, 40])  # 37 pads to 40; 40 does not
+    @pytest.mark.parametrize(
+        "source_dtype, shape, dtype",
+        [
+            (np.float64, (3,), np.float32),
+            (np.float64, (), np.float32),
+            (np.float64, (3,), None),
+            (np.float32, (3,), np.float32),
+            (np.int64, (), np.int32),
+            (np.int64, (2,), np.int32),
+            (np.bool_, (), None),
+            (np.bool_, (3,), np.int32),
+        ],
+    )
+    def test_shipped_bits_equal_pad_then_cast(
+        self, monkeypatch, policy, rows, source_dtype, shape, dtype
+    ):
+        from learningorchestra_tpu.parallel import sharding
+        from learningorchestra_tpu.utils import dtypepolicy
+
+        monkeypatch.setattr(dtypepolicy, "_POLICY", [policy])
+        rng = np.random.default_rng(rows)
+        source = _plant(
+            (rng.normal(size=(rows,) + shape) * 1e3).astype(source_dtype)
+        )
+        original = source.copy()
+        (shipped, mask), stage = _shard_and_keep(monkeypatch, source, dtype)
+
+        expected, expected_mask = pad_rows(source, 8)
+        if dtype is not None:
+            expected = expected.astype(sharding.policy_dtype(dtype))
+        assert shipped.dtype == expected.dtype
+        assert shipped.shape == expected.shape == (40,) + shape
+        assert (
+            np.ascontiguousarray(shipped).view(np.uint8).tobytes()
+            == np.ascontiguousarray(expected).view(np.uint8).tobytes()
+        )
+        assert mask.dtype == bool
+        np.testing.assert_array_equal(mask, expected_mask)
+        assert (source.view(np.uint8) == original.view(np.uint8)).all()
+        if dtype is None and rows == 40:
+            assert shipped is source  # nothing to pad or cast: as-is
+            assert stage.meta == {"bytes": 40, "threads": 0, "tiles": 0}
+        else:
+            assert not np.shares_memory(shipped, source)
+            # the mask and the shipped array, and no float64 pad copy
+            assert stage.meta == {
+                "bytes": 40 + shipped.nbytes, "threads": 1, "tiles": 1
+            }
+
+    def test_a_large_array_is_written_by_threads(self, monkeypatch):
+        from learningorchestra_tpu.utils.hostfill import FILL_THREADS
+
+        rows = (1 << 20) - 5  # pads to 1,048,576: 16 tiles of 1 MiB
+        source = _plant(np.random.default_rng(0).normal(size=(rows, 2)))
+        (shipped, mask), stage = _shard_and_keep(
+            monkeypatch, source, np.float32
+        )
+        assert stage.meta["threads"] == FILL_THREADS > 1
+        assert stage.meta["tiles"] == 16
+        assert stage.meta["bytes"] == (1 << 20) * (2 * 4 + 1)
+        expected = np.pad(source, [(0, 5), (0, 0)]).astype(np.float32)
+        assert shipped.view(np.uint32).tobytes() == expected.view(
+            np.uint32
+        ).tobytes()
+        np.testing.assert_array_equal(mask, pad_rows(source, 8)[1])
+
+    def test_a_serve_batch_stays_on_one_thread(self, monkeypatch):
+        source = np.random.default_rng(0).normal(size=(64, 28))
+        (shipped, _), stage = _shard_and_keep(monkeypatch, source, np.float32)
+        assert stage.meta == {
+            "bytes": 64 * 28 * 4 + 64, "threads": 1, "tiles": 1
+        }
+        np.testing.assert_array_equal(shipped, source.astype(np.float32))
+
+
 def test_mesh_subset_of_devices():
     mesh = make_mesh(data=2, model=3)  # 6 of 8 devices
     assert mesh.shape == {"data": 2, "model": 3}
